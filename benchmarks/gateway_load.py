@@ -1435,7 +1435,7 @@ def bench_summary(rows: list[dict]) -> dict:
         # winners only — raw sweep timings are measurement noise and
         # would churn this committed file on every run
         "autotune": {
-            k: {"block_n": v["block_n"], "packed": v["packed"]}
+            k: {"block_n": v["block_n"]}
             for k, v in autotune.report().items()
         },
     }

@@ -82,9 +82,11 @@ def _ragged_rows(fast: bool) -> list[dict]:
         [expand_coeff_bitplanes(coef_rows[i][None, :])[0] for i in range(c)]
     )
     data = rng.integers(0, 256, (c, kk, tn), dtype=np.uint8)
+    # the megakernel takes source-major (K, C, ...) staging buffers
+    mc_k, data_k = mc.transpose(1, 0, 2), data.transpose(1, 0, 2)
     jdata = jnp.asarray(data)
     t_mega = _time(
-        lambda d: ops.gf256_ragged(mc, d, interpret=True), jdata
+        lambda _d: ops.gf256_ragged(mc_k, data_k, interpret=True), jdata
     )
     per_tile = [jnp.asarray(data[i]) for i in range(c)]
 
@@ -98,7 +100,7 @@ def _ragged_rows(fast: bool) -> list[dict]:
         ]
 
     t_split = _time(_stacked, jdata)
-    out = np.asarray(ops.gf256_ragged(mc, jdata, interpret=True))
+    out = np.asarray(ops.gf256_ragged(mc_k, data_k, interpret=True))
     match = all(
         (out[i] == np.asarray(
             ref.gf256_matmul(jnp.asarray(coef_rows[i][None, :]), per_tile[i])
@@ -130,9 +132,10 @@ def _ragged_encode_rows(fast: bool) -> list[dict]:
         [expand_coeff_bitplanes(coef_rows[i][None, :])[0] for i in range(c)]
     )
     data = rng.integers(0, 256, (c, k, tn), dtype=np.uint8)
+    mc_k, data_k = mc.transpose(1, 0, 2), data.transpose(1, 0, 2)
     jdata = jnp.asarray(data)
     t_mega = _time(
-        lambda d: ops.gf256_ragged_encode(mc, d, interpret=True), jdata
+        lambda _d: ops.gf256_ragged_encode(mc_k, data_k, interpret=True), jdata
     )
     per_tile = [jnp.asarray(data[i]) for i in range(c)]
 
@@ -144,7 +147,7 @@ def _ragged_encode_rows(fast: bool) -> list[dict]:
         ]
 
     t_split = _time(_stacked, jdata)
-    out = np.asarray(ops.gf256_ragged_encode(mc, jdata, interpret=True))
+    out = np.asarray(ops.gf256_ragged_encode(mc_k, data_k, interpret=True))
     match = all(
         (out[i] == np.asarray(
             ref.gf256_matmul(jnp.asarray(coef_rows[i][None, :]), per_tile[i])
@@ -153,7 +156,7 @@ def _ragged_encode_rows(fast: bool) -> list[dict]:
     )
     # the EV fold entry: stored parity + (old, new) delta pairs
     fold = rng.integers(0, 256, (c, 5, tn), dtype=np.uint8)
-    out_x = np.asarray(ops.xor_ragged_encode(jnp.asarray(fold), interpret=True))
+    out_x = np.asarray(ops.xor_ragged_encode(fold.transpose(1, 0, 2), interpret=True))
     match_x = all(
         (out_x[i] == np.asarray(ref.xor_parity(jnp.asarray(fold[i])))).all()
         for i in range(c)
@@ -176,9 +179,9 @@ def check(rows: list[dict]) -> list[str]:
 if __name__ == "__main__":
     import sys
 
-    from benchmarks.run import ensure_headless_backend
+    from benchmarks.run import describe_device
 
-    print(f"backend: {ensure_headless_backend()}")
+    print(f"device: {describe_device()}")
     rows = run()
     for r in rows:
         print(r)

@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import argparse
 import importlib
-import logging
 import sys
 import time
 
@@ -33,26 +32,14 @@ MODULES = [
 FAST_MODULES = ["gateway_load", "kernels"]
 
 
-def ensure_headless_backend() -> str:
-    """tests/conftest.py-style optional-dependency guard, applied to the
-    accelerator backend: the CI benchmark smoke must run cleanly on a
-    machine with no TPU/GPU attached. jax 0.4.x announces a missing
-    accelerator through its module logger ('An NVIDIA GPU may be
-    present...'), which this quiets, and a half-installed CUDA stack can
-    make the default backend error outright — in that case fall back to
-    CPU explicitly, where the Pallas kernels take the interpreter path
-    and kernels/autotune.py runs its interpret sweep
-    (kernels/backend.resolve_interpret). Returns the backend name
-    actually in use."""
-    logging.getLogger("jax._src.xla_bridge").setLevel(logging.ERROR)
+def describe_device() -> str:
+    """The device every row below ran on, as JAX reports it. No fallback:
+    the run uses JAX's default backend, and the Pallas kernels interpret
+    only on the CPU (kernels/backend.resolve_interpret)."""
     import jax
 
-    try:
-        return jax.default_backend()
-    except RuntimeError:
-        # env vars are read at import time, so flip the live config knob
-        jax.config.update("jax_platforms", "cpu")
-        return jax.default_backend()
+    d = jax.devices()[0]
+    return f"platform={d.platform} kind={d.device_kind} count={len(jax.devices())}"
 
 
 def main() -> int:
@@ -69,7 +56,10 @@ def main() -> int:
         mods = FAST_MODULES
     else:
         mods = MODULES
-    print(f"backend: {ensure_headless_backend()}")
+    from repro.kernels.backend import enable_compile_cache
+
+    print(f"device: {describe_device()}")
+    print(f"compile cache: {enable_compile_cache()}")
     all_checks: list[str] = []
     failed = False
     for name in mods:

@@ -616,18 +616,6 @@ def analyze_hlo_text(text: str) -> Cost:
     return cost_of(entry, comps, {})
 
 
-def builtin_cost_dict(compiled) -> dict:
-    """Version-compat wrapper over ``compiled.cost_analysis()``: older jax
-    returns a one-element list of dicts (per partition), newer returns the
-    dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, dict):
-        return cost
-    if isinstance(cost, (list, tuple)) and cost and isinstance(cost[0], dict):
-        return cost[0]
-    return {}
-
-
 def top_byte_ops(text: str, n: int = 20, key: str = "hbm_bytes"):
     """Debug: (bytes x trips, op, name) attribution of hbm_bytes (or
     wire_bytes with key="wire_bytes")."""

@@ -14,8 +14,6 @@ Conventions used throughout the codebase:
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,20 +99,65 @@ def mul_scalar_np(a: int, b: int) -> int:
     return int(_MUL_NP[a, b])
 
 
-@functools.partial(jax.jit, static_argnames=())
+# Byte width of one slice of the last axis in ``matmul``: it works slice
+# by slice, so its temporaries stay bounded whatever the block size.
+MATMUL_SLICE = 1 << 16
+
+
+def _xtime(x: jnp.ndarray) -> jnp.ndarray:
+    """x * 2 in GF(2^8): shift left (uint8 drops the carry), then reduce
+    by the polynomial where the carry was set."""
+    shifted = jnp.left_shift(x, jnp.uint8(1))
+    return jnp.where(x >= 0x80, jnp.bitwise_xor(shifted, jnp.uint8(_POLY & 0xFF)), shifted)
+
+
+def _matmul_slice(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """(M, K) x (..., K, W) -> (..., M, W) by shift-and-add over the bits
+    of ``a``: a*x = XOR over bits i of a of x*2^i."""
+    m, kk = a.shape
+    acc = jnp.zeros(b.shape[:-2] + (m, b.shape[-1]), jnp.uint8)
+    x = b
+    for bit in range(8):
+        # 0xFF where bit ``bit`` of a[m, k] is set, else 0
+        mask = jnp.uint8(0) - jnp.bitwise_and(jnp.right_shift(a, jnp.uint8(bit)), jnp.uint8(1))
+        for k in range(kk):
+            acc = jnp.bitwise_xor(
+                acc, jnp.bitwise_and(x[..., None, k, :], mask[:, k, None])
+            )
+        x = _xtime(x)
+    return acc
+
+
+@jax.jit
 def matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """GF(2^8) matrix multiply: C[i,j] = XOR_k a[i,k]*b[k,j].
 
     a: (M, K) uint8, b: (..., K, N) uint8 -> (..., M, N) uint8 (batched
     over b's leading dims). Pure-jnp implementation (the Pallas kernel in
-    repro.kernels is the TPU-optimized version; this is the oracle / CPU
-    fallback).
+    repro.kernels is the TPU-optimized version; this is the oracle and
+    the load/verify/repair path). N is walked in MATMUL_SLICE-byte
+    slices, so beyond its operands and result it holds
+    O(M * K * MATMUL_SLICE) bytes, never an (..., M, K, N) product.
     """
     a = a.astype(jnp.uint8)
     b = b.astype(jnp.uint8)
-    # (M, K, 1) x (..., 1, K, N) -> (..., M, K, N), XOR-reduce over K
-    prod = mul(a[:, :, None], b[..., None, :, :])
-    return _xor_reduce(prod, axis=-2)
+    n = b.shape[-1]
+    slice_n = max(1, min(MATMUL_SLICE, n))
+    full = n // slice_n
+
+    def body(i, out):
+        part = jax.lax.dynamic_slice_in_dim(b, i * slice_n, slice_n, axis=-1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _matmul_slice(a, part), i * slice_n, axis=-1
+        )
+
+    out = jnp.zeros(b.shape[:-2] + (a.shape[0], n), jnp.uint8)
+    out = jax.lax.fori_loop(0, full, body, out)
+    if n > full * slice_n:
+        out = out.at[..., full * slice_n :].set(
+            _matmul_slice(a, b[..., full * slice_n :])
+        )
+    return out
 
 
 def _xor_reduce(x: jnp.ndarray, axis: int) -> jnp.ndarray:

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def _butterfly_rounds(n: int) -> int:
     r = 0
@@ -55,7 +53,7 @@ def distributed_xor_repair(blocks: jnp.ndarray, mesh, axis: str = "data"):
             acc = jnp.bitwise_xor(acc, partner)
         return acc[None]
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=P(axis, None),

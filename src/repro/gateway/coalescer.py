@@ -10,7 +10,7 @@ per-shape launches pay per-launch overhead once per bucket plus up to 2x
 batch-ladder filler. The ragged path instead stages the WHOLE window per
 kind: every decode row (one output row of one op) is cut into fixed-
 width tiles (width autotuned, capped to the longest row), gathered into
-a preallocated staging buffer ``(C, K, TN)`` with a per-tile descriptor
+a preallocated staging buffer ``(K, C, TN)`` with a per-tile descriptor
 (op id, coefficient bit-planes, byte offset, valid length), and decoded
 by ONE descriptor-driven kernel launch whose grid walks tiles
 (kernels/ragged_decode.py). Flattening to ROWS is what removes the
@@ -132,9 +132,11 @@ class CoalescerStats:
     # accretes one int per launch.
     batch_hist: dict[int, int] = field(default_factory=dict)
     ops_by_kind: dict[str, int] = field(default_factory=dict)
+    launches_by_kind: dict[str, int] = field(default_factory=dict)
     sources_by_kind: dict[str, int] = field(default_factory=dict)
     jit_entries: int = 0  # LIVE traced kernel signatures (see below)
     jit_retraces: int = 0  # every trace ever taken (compile churn)
+    warmup_seconds: float = 0.0  # wall time of those first-sight launches
     decode_shapes: int = 0  # distinct decode shape_keys ever executed
     # write-dataplane counters (kinds "EH"/"EV"): kept separate so a
     # read-only run's decode stats stay bit-identical with or without
@@ -159,6 +161,9 @@ class CoalescerStats:
         total = self.staged_bytes + self.padded_bytes
         return self.padded_bytes / total if total else 0.0
 
+    def record_launch(self, kind: str) -> None:
+        self.launches_by_kind[kind] = self.launches_by_kind.get(kind, 0) + 1
+
     def record_batch(self, n_ops: int) -> None:
         self.batch_hist[n_ops] = self.batch_hist.get(n_ops, 0) + 1
         self.max_batch = max(self.max_batch, n_ops)
@@ -177,6 +182,7 @@ class DecodeCoalescer:
         interpret: bool | None = None,
         autotune_kernels: bool = True,
         mode: str = RAGGED,
+        tile_n: int | None = None,
     ):
         if mode not in (RAGGED, BUCKETED):
             raise ValueError(
@@ -186,6 +192,8 @@ class DecodeCoalescer:
         self.interpret = interpret
         self.autotune_kernels = autotune_kernels
         self.mode = mode
+        # pinned ragged tile width in bytes (None: autotuned)
+        self.tile_n = tile_n
         self.stats = CoalescerStats()
         self._warm: set[tuple] = set()  # traced kernel signatures
         self._best: dict[tuple, float] = {}  # per-signature fastest run
@@ -206,7 +214,9 @@ class DecodeCoalescer:
         through ``execute`` (background repair's codec)."""
         tn = self._tile_n.get(kind)
         if tn is None:
-            tn = min(_rdk.DEFAULT_TILE_N, _next_pow2(max(1, int(length))))
+            tn = min(
+                self.tile_n or _rdk.DEFAULT_TILE_N, _next_pow2(max(1, int(length)))
+            )
         return -(-int(length) // tn)
 
     def jit_entries_by_kind(self) -> dict[str, int]:
@@ -333,7 +343,7 @@ class DecodeCoalescer:
         """Stage every op of ``kind`` as descriptor tiles and decode the
         whole set in chunked megakernel launches (see module docstring
         for the staging contract)."""
-        tuned = self._tuned_for(kind)
+        tuned = None if self.tile_n is not None else self._tuned_for(kind)
         # fetch each distinct source once, straight into the gather below
         src: dict[BlockKey, np.ndarray] = {}
         # one descriptor row per OUTPUT row: (op_idx, target column,
@@ -363,7 +373,7 @@ class DecodeCoalescer:
         tn_fit = (
             tuned.block_n_for(max_len)
             if tuned is not None
-            else min(_rdk.DEFAULT_TILE_N, _next_pow2(max_len))
+            else min(self.tile_n or _rdk.DEFAULT_TILE_N, _next_pow2(max_len))
         )
         self._tile_n[kind] = max(self._tile_n.get(kind, 0), tn_fit)
         tn = self._tile_n[kind]
@@ -411,45 +421,39 @@ class DecodeCoalescer:
         """Gather one chunk of tiles into the staging buffers, run ONE
         megakernel launch, scatter outputs, and emit per-op LaunchUnits
         billed by tile share."""
-        data = self._buffer((kind, "data", c), (c, k_cap, tn))
+        # source-major staging (K, C, TN): the kernels' layout, so the
+        # launch views it as words with no host copy
+        data = self._buffer((kind, "data", c), (k_cap, c, tn))
         data.fill(0)
-        xor_kind = kind in ("V", "EV")
         mc = None
-        if not xor_kind:
-            mc = self._buffer((kind, "mc", c), (c, k_cap, 8))
+        if kind not in ("V", "EV"):
+            mc = self._buffer((kind, "mc", c), (k_cap, c, 8))
             mc.fill(0)
         useful = 0
         for slot, (ri, off, valid) in enumerate(chunk_tiles):
             _j, _col, planes, sources, _length = rows[ri]
             for k, s in enumerate(sources):
-                data[slot, k, :valid] = src[s][off : off + valid]
+                data[k, slot, :valid] = src[s][off : off + valid]
             if mc is not None:
-                mc[slot, : planes.shape[0], :] = planes
+                mc[: planes.shape[0], slot, :] = planes
             useful += valid * len(sources)
-        packed = bool(tuned.packed) if (tuned is not None and not xor_kind) else False
         interpret = self.interpret
         # encode kinds route to the separate ragged_encode jit entries,
         # keeping the encode/decode signature pools independently
         # countable (jit_entries_by_kind) and independently retraced
         if kind == "V":
-            launch = lambda: ops.xor_ragged(jnp.asarray(data), interpret=interpret)
+            launch = lambda: ops.xor_ragged(data, interpret=interpret)
         elif kind == "EV":
-            launch = lambda: ops.xor_ragged_encode(
-                jnp.asarray(data), interpret=interpret
-            )
+            launch = lambda: ops.xor_ragged_encode(data, interpret=interpret)
         elif kind == "EH":
-            launch = lambda: ops.gf256_ragged_encode(
-                mc, jnp.asarray(data), interpret=interpret, packed=packed
-            )
+            launch = lambda: ops.gf256_ragged_encode(mc, data, interpret=interpret)
         else:
-            launch = lambda: ops.gf256_ragged(
-                mc, jnp.asarray(data), interpret=interpret, packed=packed
-            )
-        # Untimed warm-up on first sight of a traced signature: chunk
+            launch = lambda: ops.gf256_ragged(mc, data, interpret=interpret)
+        # Unbilled warm-up on first sight of a traced signature: chunk
         # rung, K cap and tile width are the only jit shape keys, and
         # the one-off trace/compile cost must not be billed to the
         # window's simulated decode latency.
-        sig = (RAGGED, kind, c, k_cap, tn, packed)
+        sig = (RAGGED, kind, c, k_cap, tn)
         if sig not in self._warm:
             # a grow-only cap ratchet obsoletes this kind's previous
             # signatures — they can never be launched again, so the LIVE
@@ -471,7 +475,9 @@ class DecodeCoalescer:
                     "retired %d traced signature(s)",
                     kind, k_cap, tn, len(stale),
                 )
+            t_warm = time.perf_counter()
             jax.block_until_ready(launch())
+            self.stats.warmup_seconds += time.perf_counter() - t_warm
             self._warm.add(sig)
             self.stats.jit_entries = len(self._warm)
             self.stats.jit_retraces += 1
@@ -506,6 +512,7 @@ class DecodeCoalescer:
         else:
             self.stats.decode_calls += 1
             self.stats.compute_time += dt
+        self.stats.record_launch(kind)
         self.stats.record_batch(len(tiles_per_op))
         self.stats.staged_bytes += useful
         self.stats.padded_bytes += c * k_cap * tn - useful
@@ -536,15 +543,16 @@ class DecodeCoalescer:
             )  # (B, K, q)
             if tuned is not None:
                 kw["block_n"] = tuned.block_n_for(data.shape[-1])
-                kw["packed"] = tuned.packed
-            launch = lambda: ops.gf256_matmul_batched(coefs, jnp.asarray(data), **kw)
-        # Untimed warm-up on first sight of a traced signature: the
+            launch = lambda: ops.gf256_matmul_batched(coefs, data, **kw)
+        # Unbilled warm-up on first sight of a traced signature: the
         # padded batch size B and byte length are jit shape keys, and
         # the one-off trace/compile cost must not be billed to the
         # window's simulated decode latency.
         sig = (BUCKETED, key, b_pad, data.shape[-1])
         if sig not in self._warm:
+            t_warm = time.perf_counter()
             jax.block_until_ready(launch())
+            self.stats.warmup_seconds += time.perf_counter() - t_warm
             self._warm.add(sig)
             self.stats.jit_entries = len(self._warm)
             self.stats.jit_retraces += 1
@@ -572,6 +580,7 @@ class DecodeCoalescer:
         self.stats.padded_bytes += (b_pad - len(idxs)) * stripe
         self.stats.compute_time += dt
         self.stats.decode_calls += 1
+        self.stats.record_launch(kind)
         self.stats.decode_ops += len(idxs)
         self.stats.padded_ops += b_pad - len(idxs)
         self.stats.record_batch(len(idxs))

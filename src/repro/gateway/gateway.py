@@ -168,6 +168,13 @@ ADMIT_REJECT = "reject"
 ADMIT_DEGRADE = "degrade"
 
 
+# Ragged tile width in bytes while decode_cost_per_tile bills per tile:
+# the width the autotune sweep picks on a v5e. Pinned, so the billed
+# work does not follow the sweep of whichever backend runs (under the
+# CPU interpreter its winner varies from run to run).
+BILLED_TILE_N = 65536
+
+
 @dataclass(frozen=True)
 class GatewayConfig:
     batch_window: float = 0.002  # seconds of arrival coalescing
@@ -215,7 +222,8 @@ class GatewayConfig:
     # scale-out bench: N shards cut windows ~N ways, and per-launch
     # billing would charge the cluster N times for the same tiles).
     # Requires coalesce="ragged" (bucketed units carry no tile counts)
-    # and is mutually exclusive with decode_cost.
+    # and is mutually exclusive with decode_cost. Pins the ragged tile
+    # width at BILLED_TILE_N.
     decode_cost_per_tile: float | None = None
     # -- write dataplane -------------------------------------------------------
     # Modeled ENCODE cost per launch (same semantics as decode_cost);
@@ -821,6 +829,9 @@ class ObjectGateway:
             interpret=self.config.interpret,
             autotune_kernels=self.config.autotune,
             mode=self.config.coalesce,
+            tile_n=(
+                None if self.config.decode_cost_per_tile is None else BILLED_TILE_N
+            ),
         )
         self.fixer = BlockFixer(
             self.store,

@@ -12,7 +12,7 @@
 #     launch per kind with <= 2 traced signatures regardless of shape
 #     diversity.
 #
-# kernels/autotune.py measures block_n / tile width / packed per backend
+# kernels/autotune.py measures block_n / tile width per device kind
 # at first use and persists the winners across processes.
 from repro.kernels import autotune, ops, ragged_decode, ref
 from repro.kernels.ops import (

@@ -6,9 +6,6 @@ The kernels expose knobs whose best setting is backend-dependent:
     per-step grid/DMA overhead on these bandwidth-bound kernels; under
     the CPU interpreter, each grid step is a Python execution of the
     kernel body, so the trade-off inverts at small N.
-  * ``packed``  — the u32 mask-spread GF multiply (K2): structurally
-    ~2x fewer VPU lane-ops on TPU, slower under the interpreter
-    (bitcast overhead).
   * the ragged megakernel's TILE WIDTH (kernels/ragged_decode.py) — the
     same grid-overhead-vs-padding trade-off, but per descriptor tile:
     fat tiles mean fewer grid steps and launches, narrow tiles mean less
@@ -23,13 +20,15 @@ stays off the request path because results are cached.
 
 Winners also persist ACROSS processes (ROADMAP: run the sweep on real
 hardware once, keep it): an atomic JSON cache keyed by
-``backend/kernel/variant`` lives at ``default_cache_path()`` — override
+``device_kind/kernel/variant`` lives at ``default_cache_path()``, inside
+the checkout (kernels/backend.CACHE_DIR) — override
 with ``set_cache_path()`` or the ``REPRO_AUTOTUNE_CACHE`` env var (set
 it to ``off`` to disable persistence) — and is consulted before any
 sweep runs. Entries whose ``block_n`` no longer matches the current
 candidate set are ignored (a stale cache must not pin a retired
 configuration), and ``clear_cache()`` drops the disk file along with the
-in-process winners.
+in-process winners. A candidate that fails to compile raises: the sweep
+never skips one.
 
 The probe shapes are deliberately tiny (a few batched stripes over the
 candidates' least common multiple of bytes): the point is ranking the
@@ -51,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.backend import resolve_interpret
+from repro.kernels.backend import CACHE_DIR, resolve_interpret
 
 GF_BLOCK_CANDIDATES = (2048, 8192, 32768)
 XOR_BLOCK_CANDIDATES = (8192, 65536)
@@ -66,7 +65,6 @@ _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 @dataclass(frozen=True)
 class TunedKernel:
     block_n: int
-    packed: bool
     elapsed: float  # best measured seconds for the winning config
 
     def block_n_for(self, n: int) -> int:
@@ -96,13 +94,13 @@ def cache_stats() -> dict[str, int]:
 
 
 def default_cache_path() -> pathlib.Path:
-    return pathlib.Path.home() / ".cache" / "repro" / "autotune.json"
+    return CACHE_DIR / "autotune.json"
 
 
 def cache_path() -> pathlib.Path | None:
     """Active disk-cache location: explicit set_cache_path() wins, then
     the REPRO_AUTOTUNE_CACHE env var (value "off"/"0"/"" disables), then
-    the per-user default."""
+    the checkout's default."""
     if _cache_path_set:
         return _cache_path_override
     env = os.environ.get(_CACHE_ENV)
@@ -122,7 +120,7 @@ def set_cache_path(path: str | os.PathLike | None) -> None:
 
 def _disk_key(kind: str, interpret: bool) -> str:
     variant = "interpret" if interpret else "compiled"
-    return f"{jax.default_backend()}/{kind}/{variant}"
+    return f"{jax.devices()[0].device_kind}/{kind}/{variant}"
 
 
 def _load_disk() -> dict[str, dict]:
@@ -149,7 +147,6 @@ def _save_disk(kind: str, interpret: bool, tuned: TunedKernel) -> None:
         entries = _load_disk()
         entries[_disk_key(kind, interpret)] = {
             "block_n": tuned.block_n,
-            "packed": tuned.packed,
             "elapsed": tuned.elapsed,
         }
         fd, tmp = tempfile.mkstemp(
@@ -179,13 +176,13 @@ def _load_persisted(
     if not isinstance(entry, dict):
         return None
     try:
-        block_n, packed = int(entry["block_n"]), bool(entry["packed"])
+        block_n = int(entry["block_n"])
         elapsed = float(entry.get("elapsed", 0.0))
     except (KeyError, TypeError, ValueError):
         return None
     if block_n not in candidates:
         return None  # stale entry from a retired candidate set
-    return TunedKernel(block_n=block_n, packed=packed, elapsed=elapsed)
+    return TunedKernel(block_n=block_n, elapsed=elapsed)
 
 
 def clear_cache() -> None:
@@ -206,14 +203,13 @@ def report() -> dict[str, dict]:
     return {
         f"{kind}/{'interpret' if interp else 'compiled'}": {
             "block_n": t.block_n,
-            "packed": t.packed,
             "elapsed": t.elapsed,
         }
         for (kind, interp), t in _CACHE.items()
     }
 
 
-def _best(candidates: list[tuple[tuple[int, bool], "callable"]]) -> tuple[int, bool, float]:
+def _best(candidates: list[tuple[int, "callable"]]) -> tuple[int, float]:
     best_key, best_dt = None, float("inf")
     for key, launch in candidates:
         jax.block_until_ready(launch())  # untimed warm-up: trace + compile
@@ -224,14 +220,14 @@ def _best(candidates: list[tuple[tuple[int, bool], "callable"]]) -> tuple[int, b
             dt = min(dt, time.perf_counter() - t0)
         if dt < best_dt:
             best_key, best_dt = key, dt
-    return best_key[0], best_key[1], best_dt
+    return best_key, best_dt
 
 
 def _tuned(
     kind: str,
     interpret: bool,
     candidates: tuple[int, ...],
-    sweep,  # () -> list of ((block_n, packed), launch) probe candidates
+    sweep,  # () -> list of (block_n, launch) probe candidates
 ) -> TunedKernel:
     """Shared memoization spine: process cache -> disk cache -> sweep."""
     cached = _CACHE.get((kind, interpret))
@@ -240,8 +236,8 @@ def _tuned(
         return cached
     tuned = _load_persisted(kind, interpret, candidates)
     if tuned is None:
-        bn, packed, dt = _best(sweep())
-        tuned = TunedKernel(block_n=bn, packed=packed, elapsed=dt)
+        bn, dt = _best(sweep())
+        tuned = TunedKernel(block_n=bn, elapsed=dt)
         _save_disk(kind, interpret, tuned)
         _STATS["sweeps"] += 1
     else:
@@ -251,7 +247,7 @@ def _tuned(
 
 
 def tuned_gf256(interpret: bool | None = None) -> TunedKernel:
-    """Winning (block_n, packed) for the batched GF(256) decode entry."""
+    """Winning block_n for the batched GF(256) decode entry."""
     interpret = resolve_interpret(interpret)
     from repro.kernels import ops  # deferred: ops imports this module
 
@@ -259,24 +255,22 @@ def tuned_gf256(interpret: bool | None = None) -> TunedKernel:
         n = max(GF_BLOCK_CANDIDATES)  # multiple of every candidate
         rng = np.random.default_rng(0)
         coefs = rng.integers(0, 256, size=(2, 2, 6), dtype=np.uint8)
-        data = jnp.asarray(rng.integers(0, 256, size=(2, 6, n), dtype=np.uint8))
+        data = rng.integers(0, 256, size=(2, 6, n), dtype=np.uint8)
         return [
             (
-                (bn, packed),
-                lambda bn=bn, packed=packed: ops.gf256_matmul_batched(
-                    coefs, data, block_n=bn, interpret=interpret, packed=packed
+                bn,
+                lambda bn=bn: ops.gf256_matmul_batched(
+                    coefs, data, block_n=bn, interpret=interpret
                 ),
             )
             for bn in GF_BLOCK_CANDIDATES
-            for packed in (False, True)
         ]
 
     return _tuned("gf256", interpret, GF_BLOCK_CANDIDATES, sweep)
 
 
 def tuned_xor(interpret: bool | None = None) -> TunedKernel:
-    """Winning block_n for the batched XOR parity entry (no packed
-    variant exists — XOR is already lane-width-agnostic)."""
+    """Winning block_n for the batched XOR parity entry."""
     interpret = resolve_interpret(interpret)
     from repro.kernels import ops
 
@@ -286,7 +280,7 @@ def tuned_xor(interpret: bool | None = None) -> TunedKernel:
         data = jnp.asarray(rng.integers(0, 256, size=(2, 3, n), dtype=np.uint8))
         return [
             (
-                (bn, False),
+                bn,
                 lambda bn=bn: ops.xor_parity_batched(
                     data, block_n=bn, interpret=interpret
                 ),
@@ -313,12 +307,11 @@ def _ragged_probe_chunks(kk: int, tn: int, rng) -> tuple[list[int], dict]:
 
     tiles_per_row = -(-_RAGGED_PROBE_ROW_BYTES // tn)
     chunks = chunk_sizes(_RAGGED_PROBE_ROWS * tiles_per_row)
+    # host-staged (K, C, ...) buffers, as the coalescer hands them over
     bufs = {
         c: (
-            rng.integers(0, 256, size=(c, kk, 8), dtype=np.uint8),
-            jnp.asarray(
-                rng.integers(0, 256, size=(c, kk, tn), dtype=np.uint8)
-            ),
+            rng.integers(0, 256, size=(kk, c, 8), dtype=np.uint8),
+            rng.integers(0, 256, size=(kk, c, tn), dtype=np.uint8),
         )
         for c in set(chunks)
     }
@@ -326,7 +319,7 @@ def _ragged_probe_chunks(kk: int, tn: int, rng) -> tuple[list[int], dict]:
 
 
 def tuned_ragged_gf256(interpret: bool | None = None) -> TunedKernel:
-    """Winning (tile width, packed) for the ragged GF(256) megakernel
+    """Winning tile width for the ragged GF(256) megakernel
     (``block_n`` is the descriptor tile width TN)."""
     interpret = resolve_interpret(interpret)
     from repro.kernels import ops
@@ -337,20 +330,18 @@ def tuned_ragged_gf256(interpret: bool | None = None) -> TunedKernel:
         out = []
         for tn in RAGGED_GF_TILE_CANDIDATES:
             chunks, bufs = _ragged_probe_chunks(kk, tn, rng)
-            for packed in (False, True):
 
-                def launch(chunks=chunks, bufs=bufs, packed=packed):
-                    return [
-                        jax.block_until_ready(
-                            ops.gf256_ragged(
-                                bufs[c][0], bufs[c][1],
-                                interpret=interpret, packed=packed,
-                            )
+            def launch(chunks=chunks, bufs=bufs):
+                return [
+                    jax.block_until_ready(
+                        ops.gf256_ragged(
+                            bufs[c][0], bufs[c][1], interpret=interpret
                         )
-                        for c in chunks
-                    ]
+                    )
+                    for c in chunks
+                ]
 
-                out.append(((tn, packed), launch))
+            out.append((tn, launch))
         return out
 
     return _tuned("ragged_gf256", interpret, RAGGED_GF_TILE_CANDIDATES, sweep)
@@ -376,7 +367,7 @@ def tuned_ragged_xor(interpret: bool | None = None) -> TunedKernel:
                     for c in chunks
                 ]
 
-            out.append(((tn, False), launch))
+            out.append((tn, launch))
         return out
 
     return _tuned("ragged_xor", interpret, RAGGED_XOR_TILE_CANDIDATES, sweep)

@@ -13,17 +13,24 @@ the data operand:
 
 The 8 constants gfmul(c, 2^b) per coefficient are precomputed host-side
 into an (M, K, 8) tensor, so the kernel body is pure VPU work: shifts,
-masks, byte multiplies by 0/1 (select), XOR accumulation — no gathers, no
-tables. Cost: 8 fused select-XOR passes over the data tile per (m, k)
-coefficient. For RS codes (K <= 16, M <= 4) the working set is the
-(K, BN) data tile + (M, BN) accumulator, tiled to stay within VMEM.
+masks, byte selects, XOR accumulation — no gathers, no tables.
 
-Grid: 1-D over the byte dimension N in BN-sized tiles. BN defaults to
-32768 bytes (lane-aligned: 256 sublanes x 128 lanes at u8): data tiles
-of K x BN <= 16 x 32 KiB = 512 KiB + the (M, BN) accumulator stay well
-inside VMEM while amortizing per-step grid/DMA overhead 16x better than
-the original 2 KiB tiles (§Perf kernel iteration: fewer, fatter DMAs on
-a bandwidth-bound kernel; validated vs ref.py across shapes/dtypes).
+Word layout, shared with kernels/ragged_decode.py: the kernels run on
+uint32 words holding 4 bytes each. The host views its byte buffers as
+words (``stage_words``, free in numpy) and byte-splats each plane
+constant (``stage_planes``: ``c * 0x01010101``); the device never
+changes bitwidth (Mosaic refuses in-kernel bitcasts, and XLA's relayout
+of uint8 arrays compiles slowly and, at 64 MiB blocks, does not fit).
+Every op is byte-lane-safe: ``(x >> b) & 0x01010101`` takes bit b of
+every byte and ``(bits << 8) - bits`` spreads each set bit to a 0xFF
+byte mask. There is no uint8 multiply, which Mosaic cannot lower.
+Sources are a grid axis: step (j, k) XORs source k's contribution into
+output block j, which stays resident across k, so VMEM per step does
+not grow with K.
+
+Grid: (N / BN, K). BN defaults to 32768 bytes: a (1, BN/4) word tile
+plus the (M, BN/4) accumulator stay well inside VMEM while amortizing
+per-step grid/DMA overhead.
 """
 
 from __future__ import annotations
@@ -51,103 +58,101 @@ def expand_coeff_bitplanes(coef: np.ndarray) -> np.ndarray:
     return planes.astype(np.uint8)
 
 
-def _gf_matmul_kernel(mc_ref, data_ref, out_ref, *, m: int, kk: int):
-    """mc_ref: (M, K, 8) u8 bit-plane constants (whole, VMEM-resident)
-    data_ref: (K, BN) u8 data tile; out_ref: (M, BN) u8."""
-    data = data_ref[...]  # (K, BN)
-    mc = mc_ref[...]  # (M, K, 8)
-    acc = jnp.zeros(out_ref.shape, dtype=jnp.uint8)
+BYTE_ONES = 0x01010101  # bit 0 of every byte of a uint32 word
+
+
+def stage_words(data) -> np.ndarray:
+    """(..., N) uint8 buffer -> (..., N / 4) uint32 words: a host view,
+    no copy when ``data`` is already a contiguous numpy array."""
+    return np.ascontiguousarray(data, dtype=np.uint8).view(np.uint32)
+
+
+def stage_planes(mc) -> np.ndarray:
+    """uint8 plane constants -> uint32 words holding each byte four
+    times, as the word product needs them."""
+    return np.asarray(mc, dtype=np.uint8).astype(np.uint32) * np.uint32(BYTE_ONES)
+
+
+def gf_word_product(d: jnp.ndarray, planes: jnp.ndarray) -> jnp.ndarray:
+    """GF(2^8) product of one source's data words ``d`` with splatted
+    plane constants ``planes`` (..., 8): XOR over bits b of
+    (byte mask of bit b of d) & planes[..., b]. Broadcasts ``d`` (R, W)
+    against each plane column (M, 1)."""
+    acc = None
     for b in range(8):
-        bits = jnp.bitwise_and(jnp.right_shift(data, b), jnp.uint8(1))  # (K, BN)
-        for k in range(kk):
-            # select the plane constant where the data bit is set
-            contrib = bits[k][None, :] * mc[:, k, b][:, None]  # (M, BN)
-            acc = jnp.bitwise_xor(acc, contrib)
-    out_ref[...] = acc
+        bits = jnp.bitwise_and(
+            jnp.right_shift(d, jnp.uint32(b)), jnp.uint32(BYTE_ONES)
+        )
+        mask = jnp.left_shift(bits, jnp.uint32(8)) - bits  # 0x00 / 0xFF bytes
+        term = jnp.bitwise_and(mask, planes[:, b : b + 1])
+        acc = term if acc is None else jnp.bitwise_xor(acc, term)
+    return acc
 
 
-def _gf_matmul_kernel_packed(mc_ref, data_ref, out_ref, *, m: int, kk: int):
-    """u32-packed variant (§Perf kernel iteration K2): 4 bytes per lane,
-    byte-select via mask-spread (3 shift-or) + AND instead of a byte
-    multiply — ~2x fewer VPU lane-ops than the u8 kernel, guaranteed
-    32-bit lane packing. All ops are byte-lane-safe: (x >> b) & 0x01010101
-    extracts bit b of every byte (b < 8 never crosses a byte boundary)
-    and the 0x01 -> 0xFF mask spread stays inside each byte."""
-    data = data_ref[...]  # (K, BN) u8
-    mc = mc_ref[...]  # (M, K, 8) u8
-    bn = data.shape[1]
-    d32 = jax.lax.bitcast_convert_type(
-        data.reshape(kk, bn // 4, 4), jnp.uint32
-    )  # (K, BN/4)
-    one = jnp.uint32(0x01010101)
-    acc = jnp.zeros((m, bn // 4), jnp.uint32)
-    for b in range(8):
-        bits = jnp.bitwise_and(jnp.right_shift(d32, jnp.uint32(b)), one)
-        sel = jnp.bitwise_or(bits, jnp.left_shift(bits, jnp.uint32(1)))
-        sel = jnp.bitwise_or(sel, jnp.left_shift(sel, jnp.uint32(2)))
-        sel = jnp.bitwise_or(sel, jnp.left_shift(sel, jnp.uint32(4)))  # 0x00/0xFF
-        for k in range(kk):
-            c32 = mc[:, k, b].astype(jnp.uint32) * one  # (M,) byte-splat
-            acc = jnp.bitwise_xor(
-                acc, jnp.bitwise_and(sel[k][None, :], c32[:, None])
-            )
-    out_ref[...] = jax.lax.bitcast_convert_type(acc, jnp.uint8).reshape(m, bn)
+def accumulate_over_sources(out_ref, part: jnp.ndarray) -> None:
+    """Grid axis 1 walks the K sources; the output block stays resident
+    across it, so source k's contribution XORs into it in place."""
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = part
+
+    @pl.when(pl.program_id(1) > 0)
+    def _():
+        out_ref[...] = jnp.bitwise_xor(out_ref[...], part)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret", "packed")
-)
+def _gf_matmul_kernel(mc_ref, data_ref, out_ref):
+    """mc_ref: (1, M, 8) splatted plane constants of source k;
+    data_ref: (1, 1, BN/4) words of source k; out_ref: (M, BN/4)."""
+    accumulate_over_sources(out_ref, gf_word_product(data_ref[0], mc_ref[0]))
+
+
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def gf256_matmul_planes(
-    mc: jnp.ndarray,
-    data: jnp.ndarray,
+    planes: jnp.ndarray,
+    words: jnp.ndarray,
     *,
     block_n: int = DEFAULT_BLOCK_N,
     interpret: bool | None = None,
-    packed: bool = False,
 ) -> jnp.ndarray:
-    """C (M, N) = coefficient-matrix x data over GF(2^8).
+    """C (M, N) = coefficient-matrix x data over GF(2^8), on words.
 
-    mc: (M, K, 8) bit-plane constants (see expand_coeff_bitplanes)
-    data: (K, N) uint8; N must be a multiple of block_n (ops.py pads).
-    interpret=None auto-detects the backend (compile on TPU, interpret
-    elsewhere — kernels/backend.py). packed selects the u32 mask-spread
-    kernel (K2) — structurally ~2x fewer VPU lane-ops on TPU, but slower
-    under the CPU interpreter (bitcast overhead), so the
-    measured-on-this-host default is False; flip it on real TPU
-    (EXPERIMENTS.md §Perf K2).
+    planes: (K, M, 8) splatted bit-plane constants (``stage_planes`` of
+    expand_coeff_bitplanes, source-major); words: (K, 1, N/4) data words
+    (``stage_words``) -> (M, N/4) uint32. N must be a multiple of block_n
+    (ops.py pads) and block_n of 4. interpret=None auto-detects the
+    backend (kernels/backend.py).
     """
     interpret = resolve_interpret(interpret)
-    m, kk, _ = mc.shape
-    k2, n = data.shape
-    assert kk == k2, (mc.shape, data.shape)
-    assert n % block_n == 0, (n, block_n)
-    kern = _gf_matmul_kernel_packed if (packed and block_n % 4 == 0) else _gf_matmul_kernel
-    grid = (n // block_n,)
+    kk, m, _ = planes.shape
+    k2, _, w = words.shape
+    assert kk == k2, (planes.shape, words.shape)
+    assert block_n % 4 == 0 and w % (block_n // 4) == 0, (w, block_n)
+    bw = block_n // 4
     return pl.pallas_call(
-        functools.partial(kern, m=m, kk=kk),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint8),
-        grid=grid,
+        _gf_matmul_kernel,
+        out_shape=jax.ShapeDtypeStruct((m, w), jnp.uint32),
+        grid=(w // bw, kk),
         in_specs=[
-            pl.BlockSpec((m, kk, 8), lambda j: (0, 0, 0)),  # coefficients: replicated
-            pl.BlockSpec((k2, block_n), lambda j: (0, j)),  # data tile
+            pl.BlockSpec((1, m, 8), lambda j, k: (k, 0, 0)),
+            pl.BlockSpec((1, 1, bw), lambda j, k: (k, 0, j)),
         ],
-        out_specs=pl.BlockSpec((m, block_n), lambda j: (0, j)),
+        out_specs=pl.BlockSpec((m, bw), lambda j, k: (0, j)),
         interpret=interpret,
-    )(mc, data)
+    )(planes, words)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret", "packed")
-)
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def gf256_matmul_planes_batched(
-    mc: jnp.ndarray,
-    data: jnp.ndarray,
+    planes: jnp.ndarray,
+    words: jnp.ndarray,
     *,
     block_n: int = DEFAULT_BLOCK_N,
     interpret: bool | None = None,
-    packed: bool = False,
 ) -> jnp.ndarray:
-    """Stacked decode: (B, M, K, 8) bit-planes x (B, K, N) data -> (B, M, N).
+    """Stacked decode: (B, K, M, 8) planes x (B, K, 1, N/4) words ->
+    (B, M, N/4).
 
     One launch serves B independent stripes that share a decode *shape*
     but not coefficients — the gateway coalescer's case: concurrent
@@ -157,11 +162,8 @@ def gf256_matmul_planes_batched(
     batch is a single kernel launch instead of B dispatches.
     """
     interpret = resolve_interpret(interpret)
-    b, m, kk, _ = mc.shape
-    b2, k2, n = data.shape
-    assert b == b2 and kk == k2, (mc.shape, data.shape)
-    assert n % block_n == 0, (n, block_n)
+    assert planes.shape[0] == words.shape[0], (planes.shape, words.shape)
     fn = functools.partial(
-        gf256_matmul_planes, block_n=block_n, interpret=interpret, packed=packed
+        gf256_matmul_planes, block_n=block_n, interpret=interpret
     )
-    return jax.vmap(fn)(mc, data)
+    return jax.vmap(fn)(planes, words)

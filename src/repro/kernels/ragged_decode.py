@@ -10,16 +10,17 @@ one op) into fixed-width tiles, gathers the tiles into a flat staging
 buffer, and the kernel's grid walks tiles, applying each tile's own
 coefficient row.
 
-Descriptor layout (built host-side by gateway/coalescer.py):
+Descriptor layout (built host-side by gateway/coalescer.py), source-
+major so that one source's slabs for a block of tiles are contiguous:
 
-  * ``data``  (C, K, TN) u8 — tile t's K source slabs.  A row of length
+  * ``data``  (K, C, TN) u8 — tile t's K source slabs.  A row of length
     L occupies ceil(L / TN) consecutive tiles; the tail tile is
     zero-padded past its valid length (zero bytes contribute zero to
     both GF(256) products and XOR, so no in-kernel masking is needed —
     the host slices the valid prefix back out).  Ops with fewer than K
     sources zero-pad the K axis (a zero row is the identity for both
     ops).
-  * ``mc``    (C, K, 8) u8 — tile t's coefficient row, bit-plane
+  * ``mc``    (K, C, 8) u8 — tile t's coefficient row, bit-plane
     expanded (gf256_matmul.expand_coeff_bitplanes); the GF kernel only.
     Replicating the planes per tile is the descriptor table: it is what
     lets tiles of DIFFERENT ops share one traced signature.
@@ -33,10 +34,17 @@ kind are therefore <= 2 regardless of shape diversity — the bucketed
 path's O(shapes x ladder) jit set becomes O(1) — and padding is bounded
 by CHUNK_SMALL - 1 tiles per window, not a 2x batch rung.
 
-Grid: 1-D over tile blocks of ``tile_block`` tiles; the kernel body is
-fully vectorized over the leading tile axis, so under the interpreter a
-whole chunk is a single Python grid step, while on TPU ``tile_block``
-is capped so a block's source slab stays within a VMEM budget.
+Kernel layout: gf256_matmul's uint32 word layout. The host views the
+staged bytes as (K, C, TN/4) words, which costs nothing in numpy, and
+byte-splats the plane constants (gf256_matmul.stage_words and
+stage_planes); the GF body is gf256_matmul's byte-lane-safe word
+product. Grid: (C / tile_block, K); step (j, k) XORs source k's slab
+into output block j, which stays resident across k, so VMEM per step
+is one (tile_block, TN) slab whatever K is.
+Under the interpreter ``tile_block`` is the whole chunk; on TPU it is
+capped so that slab stays within a VMEM budget, and it is always the
+whole chunk or a multiple of 8 tiles, the only block heights Mosaic
+accepts.
 """
 
 from __future__ import annotations
@@ -48,6 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.backend import resolve_interpret
+from repro.kernels.gf256_matmul import accumulate_over_sources, gf_word_product
 
 # Launch-size rungs, in tiles. Two rungs bound the traced signatures per
 # kind at 2 while keeping null-tile padding under CHUNK_SMALL per window
@@ -60,8 +69,8 @@ CHUNK_BIG = 32
 # overrides this per backend; callers cap it to the longest row staged).
 DEFAULT_TILE_N = 4096
 
-# Per-grid-step VMEM budget for the (tile_block, K, TN) source slab on a
-# compiled backend; the interpreter runs the whole chunk in one step.
+# Per-grid-step VMEM budget for one source's (tile_block, TN) slab on a
+# compiled backend; the interpreter takes the whole chunk per step.
 _VMEM_TILE_BUDGET = 1 << 21
 
 
@@ -76,123 +85,102 @@ def chunk_sizes(num_tiles: int) -> list[int]:
     return chunks
 
 
-def tile_block_for(c: int, kk: int, tn: int, interpret: bool) -> int:
+def tile_block_for(c: int, tn: int, interpret: bool) -> int:
     """Tiles per grid step: the whole chunk under the interpreter (one
-    Python step per launch), VMEM-capped on a compiled backend. Always a
-    power-of-two divisor of ``c`` (chunk sizes are powers of two)."""
+    step per source), VMEM-capped on a compiled backend — halved while
+    one source slab exceeds the budget, but only down to a multiple of
+    8, so the block is always the whole chunk or a multiple of 8.
+
+    Every chunk rung at the autotune candidates' widest tile (64 KiB)
+    fits the budget whole; the cap splits only wider tiles."""
     if interpret:
         return c
     tb = c
-    while tb > 1 and tb * kk * tn > _VMEM_TILE_BUDGET:
+    while tb % 16 == 0 and tb * tn > _VMEM_TILE_BUDGET:
         tb //= 2
     return tb
 
 
-def _ragged_gf_kernel(mc_ref, data_ref, out_ref, *, kk: int):
-    """mc_ref: (TB, K, 8) per-tile coefficient bit-planes; data_ref:
-    (TB, K, TN) source tiles; out_ref: (TB, TN). Vectorized over the
-    tile axis — mixed ops in one block cost nothing extra."""
-    data = data_ref[...]  # (TB, K, TN)
-    mc = mc_ref[...]  # (TB, K, 8)
-    acc = jnp.zeros(out_ref.shape, dtype=jnp.uint8)
-    for b in range(8):
-        bits = jnp.bitwise_and(jnp.right_shift(data, b), jnp.uint8(1))
-        for k in range(kk):
-            contrib = bits[:, k, :] * mc[:, k, b][:, None]  # (TB, TN)
-            acc = jnp.bitwise_xor(acc, contrib)
-    out_ref[...] = acc
+def _ragged_gf_kernel(mc_ref, data_ref, out_ref):
+    """mc_ref: (1, TB, 8) splatted plane constants of source k, one row
+    per tile; data_ref: (1, TB, TN/4) words of source k; out_ref:
+    (TB, TN/4). Every tile applies its own coefficient row, so mixed
+    ops in one block cost nothing extra."""
+    accumulate_over_sources(out_ref, gf_word_product(data_ref[0], mc_ref[0]))
 
 
-def _ragged_gf_kernel_packed(mc_ref, data_ref, out_ref, *, kk: int):
-    """u32 mask-spread variant (see gf256_matmul._gf_matmul_kernel_packed
-    for the lane-safety argument): 4 bytes per lane, byte-select via a
-    3-shift-or spread + AND — ~2x fewer VPU lane-ops per tile."""
-    data = data_ref[...]  # (TB, K, TN)
-    mc = mc_ref[...]  # (TB, K, 8)
-    tb, _, tn = data.shape
-    d32 = jax.lax.bitcast_convert_type(
-        data.reshape(tb, kk, tn // 4, 4), jnp.uint32
-    )  # (TB, K, TN/4)
-    one = jnp.uint32(0x01010101)
-    acc = jnp.zeros((tb, tn // 4), jnp.uint32)
-    for b in range(8):
-        bits = jnp.bitwise_and(jnp.right_shift(d32, jnp.uint32(b)), one)
-        sel = jnp.bitwise_or(bits, jnp.left_shift(bits, jnp.uint32(1)))
-        sel = jnp.bitwise_or(sel, jnp.left_shift(sel, jnp.uint32(2)))
-        sel = jnp.bitwise_or(sel, jnp.left_shift(sel, jnp.uint32(4)))
-        for k in range(kk):
-            c32 = mc[:, k, b].astype(jnp.uint32) * one  # (TB,) byte-splat
-            acc = jnp.bitwise_xor(
-                acc, jnp.bitwise_and(sel[:, k, :], c32[:, None])
-            )
-    out_ref[...] = jax.lax.bitcast_convert_type(acc, jnp.uint8).reshape(tb, tn)
+def _ragged_xor_kernel(data_ref, out_ref):
+    """data_ref: (1, TB, TN/4) words of source k -> out_ref (TB, TN/4):
+    XOR over the K axis (zero-padded K rows are the XOR identity)."""
+    accumulate_over_sources(out_ref, data_ref[0])
 
 
-def _ragged_xor_kernel(data_ref, out_ref, *, kk: int):
-    """data_ref: (TB, K, TN) -> out_ref (TB, TN): XOR over the K axis
-    per tile (zero-padded K rows are the XOR identity)."""
-    data = data_ref[...]
-    acc = data[:, 0, :]
-    for r in range(1, kk):
-        acc = jnp.bitwise_xor(acc, data[:, r, :])
-    out_ref[...] = acc
+def _check(words: jnp.ndarray, tile_block: int) -> tuple[int, int, int]:
+    kk, c, w = words.shape
+    assert words.dtype == jnp.uint32, words.dtype
+    assert c % tile_block == 0, (c, tile_block)
+    return kk, c, w
 
 
-@functools.partial(
-    jax.jit, static_argnames=("tile_block", "interpret", "packed")
-)
+def gf256_tiles_call(
+    planes: jnp.ndarray, words: jnp.ndarray, tile_block: int, interpret: bool
+) -> jnp.ndarray:
+    """The GF(256) tile launch shared by the decode and encode entries:
+    planes (K, C, 8) and words (K, C, W) uint32 -> (C, W) uint32."""
+    kk, c, w = _check(words, tile_block)
+    assert planes.shape == (kk, c, 8), (planes.shape, words.shape)
+    return pl.pallas_call(
+        _ragged_gf_kernel,
+        out_shape=jax.ShapeDtypeStruct((c, w), jnp.uint32),
+        grid=(c // tile_block, kk),
+        in_specs=[
+            pl.BlockSpec((1, tile_block, 8), lambda j, k: (k, j, 0)),
+            pl.BlockSpec((1, tile_block, w), lambda j, k: (k, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((tile_block, w), lambda j, k: (j, 0)),
+        interpret=interpret,
+    )(planes, words)
+
+
+def xor_tiles_call(
+    words: jnp.ndarray, tile_block: int, interpret: bool
+) -> jnp.ndarray:
+    """The XOR tile launch shared by the decode and encode entries:
+    words (K, C, W) uint32 -> (C, W) uint32."""
+    kk, c, w = _check(words, tile_block)
+    return pl.pallas_call(
+        _ragged_xor_kernel,
+        out_shape=jax.ShapeDtypeStruct((c, w), jnp.uint32),
+        grid=(c // tile_block, kk),
+        in_specs=[pl.BlockSpec((1, tile_block, w), lambda j, k: (k, j, 0))],
+        out_specs=pl.BlockSpec((tile_block, w), lambda j, k: (j, 0)),
+        interpret=interpret,
+    )(words)
+
+
+@functools.partial(jax.jit, static_argnames=("tile_block", "interpret"))
 def ragged_gf256_tiles(
-    mc: jnp.ndarray,
-    data: jnp.ndarray,
+    planes: jnp.ndarray,
+    words: jnp.ndarray,
     *,
     tile_block: int,
     interpret: bool | None = None,
-    packed: bool = False,
 ) -> jnp.ndarray:
     """One descriptor-driven launch over C tiles of mixed GF(256) ops.
 
-    mc: (C, K, 8) per-tile coefficient bit-planes; data: (C, K, TN)
-    source tiles -> (C, TN). C % tile_block == 0. ``packed`` selects the
-    u32 mask-spread body (TN must be a multiple of 4)."""
-    interpret = resolve_interpret(interpret)
-    c, kk, tn = data.shape
-    assert mc.shape == (c, kk, 8), (mc.shape, data.shape)
-    assert c % tile_block == 0, (c, tile_block)
-    kern = (
-        _ragged_gf_kernel_packed
-        if (packed and tn % 4 == 0)
-        else _ragged_gf_kernel
-    )
-    return pl.pallas_call(
-        functools.partial(kern, kk=kk),
-        out_shape=jax.ShapeDtypeStruct((c, tn), jnp.uint8),
-        grid=(c // tile_block,),
-        in_specs=[
-            pl.BlockSpec((tile_block, kk, 8), lambda j: (j, 0, 0)),
-            pl.BlockSpec((tile_block, kk, tn), lambda j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_block, tn), lambda j: (j, 0)),
-        interpret=interpret,
-    )(mc, data)
+    planes: (K, C, 8) per-tile splatted coefficient bit-planes
+    (gf256_matmul.stage_planes); words: (K, C, TN/4) source tiles
+    (gf256_matmul.stage_words) -> (C, TN/4) uint32. C % tile_block == 0."""
+    return gf256_tiles_call(planes, words, tile_block, resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_block", "interpret"))
 def ragged_xor_tiles(
-    data: jnp.ndarray,
+    words: jnp.ndarray,
     *,
     tile_block: int,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One descriptor-driven launch over C tiles of mixed XOR repairs:
-    data (C, K, TN) -> (C, TN). C % tile_block == 0."""
-    interpret = resolve_interpret(interpret)
-    c, kk, tn = data.shape
-    assert c % tile_block == 0, (c, tile_block)
-    return pl.pallas_call(
-        functools.partial(_ragged_xor_kernel, kk=kk),
-        out_shape=jax.ShapeDtypeStruct((c, tn), jnp.uint8),
-        grid=(c // tile_block,),
-        in_specs=[pl.BlockSpec((tile_block, kk, tn), lambda j: (j, 0, 0))],
-        out_specs=pl.BlockSpec((tile_block, tn), lambda j: (j, 0)),
-        interpret=interpret,
-    )(data)
+    words (K, C, TN/4) -> (C, TN/4) uint32. C % tile_block == 0."""
+    return xor_tiles_call(words, tile_block, resolve_interpret(interpret))

@@ -32,79 +32,45 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.kernels.backend import resolve_interpret
 from repro.kernels.ragged_decode import (  # noqa: F401  (re-exported contract)
     CHUNK_BIG,
     CHUNK_SMALL,
     DEFAULT_TILE_N,
-    _ragged_gf_kernel,
-    _ragged_gf_kernel_packed,
-    _ragged_xor_kernel,
     chunk_sizes,
+    gf256_tiles_call,
     tile_block_for,
+    xor_tiles_call,
 )
 
 
-@functools.partial(
-    jax.jit, static_argnames=("tile_block", "interpret", "packed")
-)
+@functools.partial(jax.jit, static_argnames=("tile_block", "interpret"))
 def ragged_gf256_encode_tiles(
-    mc: jnp.ndarray,
-    data: jnp.ndarray,
+    planes: jnp.ndarray,
+    words: jnp.ndarray,
     *,
     tile_block: int,
     interpret: bool | None = None,
-    packed: bool = False,
 ) -> jnp.ndarray:
     """One descriptor-driven launch over C tiles of mixed GF(256) parity
-    ENCODES: mc (C, K, 8) per-tile generator-row bit-planes, data
-    (C, K, TN) source-data tiles -> (C, TN) parity tiles.
+    ENCODES: planes (K, C, 8) per-tile splatted generator-row bit-planes,
+    words (K, C, TN/4) source-data tiles -> (C, TN/4) parity words.
     C % tile_block == 0; semantics identical to ragged_gf256_tiles, as a
     separately traced signature pool."""
-    interpret = resolve_interpret(interpret)
-    c, kk, tn = data.shape
-    assert mc.shape == (c, kk, 8), (mc.shape, data.shape)
-    assert c % tile_block == 0, (c, tile_block)
-    kern = (
-        _ragged_gf_kernel_packed
-        if (packed and tn % 4 == 0)
-        else _ragged_gf_kernel
-    )
-    return pl.pallas_call(
-        functools.partial(kern, kk=kk),
-        out_shape=jax.ShapeDtypeStruct((c, tn), jnp.uint8),
-        grid=(c // tile_block,),
-        in_specs=[
-            pl.BlockSpec((tile_block, kk, 8), lambda j: (j, 0, 0)),
-            pl.BlockSpec((tile_block, kk, tn), lambda j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_block, tn), lambda j: (j, 0)),
-        interpret=interpret,
-    )(mc, data)
+    return gf256_tiles_call(planes, words, tile_block, resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_block", "interpret"))
 def ragged_xor_encode_tiles(
-    data: jnp.ndarray,
+    words: jnp.ndarray,
     *,
     tile_block: int,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One descriptor-driven launch over C tiles of mixed XOR-delta
-    parity folds: data (C, K, TN) -> (C, TN), XOR over the K axis
+    parity folds: words (K, C, TN/4) -> (C, TN/4), XOR over the K axis
     (stored parity + any number of old/new row contributions; zero-
     padded K rows and tile tails are the XOR identity).
     C % tile_block == 0."""
-    interpret = resolve_interpret(interpret)
-    c, kk, tn = data.shape
-    assert c % tile_block == 0, (c, tile_block)
-    return pl.pallas_call(
-        functools.partial(_ragged_xor_kernel, kk=kk),
-        out_shape=jax.ShapeDtypeStruct((c, tn), jnp.uint8),
-        grid=(c // tile_block,),
-        in_specs=[pl.BlockSpec((tile_block, kk, tn), lambda j: (j, 0, 0))],
-        out_specs=pl.BlockSpec((tile_block, tn), lambda j: (j, 0)),
-        interpret=interpret,
-    )(data)
+    return xor_tiles_call(words, tile_block, resolve_interpret(interpret))

@@ -85,7 +85,6 @@ def run_cell(arch: str, shape: str, mesh, mesh_name: str, out_dir: str | None,
     import jax
     from repro.analysis.roofline import analyze_hlo
     from repro.configs import SHAPES, get_config
-    from repro.launch.mesh import mesh_context
     from repro.launch.specs import input_specs
     from repro.models.registry import get_model
     from repro.models.shardings import axes_for_mesh
@@ -118,7 +117,7 @@ def run_cell(arch: str, shape: str, mesh, mesh_name: str, out_dir: str | None,
     import tempfile
 
     dump_dir = tempfile.mkdtemp(prefix="dryrun_hlo_")
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(cell.step, in_shardings=in_shardings).lower(*cell.args)
         t_lower = time.perf_counter() - t0
         compiled = lowered.compile(
@@ -132,9 +131,7 @@ def run_cell(arch: str, shape: str, mesh, mesh_name: str, out_dir: str | None,
         if verbose:
             print(mem)
         if verbose:
-            from repro.analysis.hlo_cost import builtin_cost_dict
-
-            flops = builtin_cost_dict(compiled).get("flops", 0.0)
+            flops = compiled.cost_analysis().get("flops", 0.0)
             print(f"builtin cost_analysis (per-chip, scan bodies counted once): "
                   f"flops={flops:.3e}")
         # prefer the post-SPMD, pre-backend HLO snapshot: it is the

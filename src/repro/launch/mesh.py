@@ -12,16 +12,6 @@ import jax
 import numpy as np
 
 
-def _auto_axis_kwargs(n: int) -> dict:
-    """Version compat: ``jax.sharding.AxisType`` (and make_mesh's
-    ``axis_types`` kwarg) only exist in newer jax; 0.4.x meshes are
-    implicitly Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -29,7 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     for s in shape:
         n *= s
     return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
-                         **_auto_axis_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
@@ -41,17 +31,7 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
             n *= s
         devices = jax.devices()[:n]
     return jax.make_mesh(shape, axes, devices=list(devices),
-                         **_auto_axis_kwargs(len(axes)))
-
-
-def mesh_context(mesh):
-    """Version-compat mesh activation: ``jax.set_mesh`` is newer jax;
-    fall back to ``jax.sharding.use_mesh``, then to the 0.4.x idiom where
-    the Mesh object is itself the context manager."""
-    setter = getattr(jax, "set_mesh", None) or getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):

@@ -37,9 +37,10 @@ def main(argv=None) -> int:
             f"--xla_force_host_platform_device_count={args.devices}"
         )
 
+    import jax
 
     from repro.configs import get_config
-    from repro.launch.mesh import make_mesh, mesh_context
+    from repro.launch.mesh import make_mesh
     from repro.train import optimizer as opt
     from repro.train.loop import LoopConfig, Trainer
 
@@ -60,7 +61,7 @@ def main(argv=None) -> int:
     oc = opt.OptConfig(lr=args.lr, warmup_steps=min(20, args.steps // 10 + 1),
                        decay_steps=args.steps, quantize_v=args.quantize_v)
 
-    ctx = mesh_context(mesh) if mesh is not None else None
+    ctx = jax.set_mesh(mesh) if mesh is not None else None
     try:
         if ctx is not None:
             ctx.__enter__()

@@ -27,9 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
-from repro.models.shardings import MeshAxes, constrain, get_abstract_mesh
+from repro.models.shardings import MeshAxes, constrain
 
 # ---------------------------------------------------------------------------
 # norms
@@ -301,7 +300,7 @@ def attention_decode_general(x1, cache_k, cache_v, p, cfg: ArchConfig, ax: MeshA
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, 1, cfg.q_dim)
         return dense(o, p["wo"]["w"], p["wo"].get("b")), cache_k, cache_v
 
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     seq_axes = plan.seq_axes
     nshard = 1
     for a in seq_axes:
@@ -352,7 +351,7 @@ def attention_decode_general(x1, cache_k, cache_v, p, cfg: ArchConfig, ax: MeshA
 
     qspec = P(bspec, None, None, None)
     seq_spec = P(bspec, seq_axes, None, None)
-    o, cache_k, cache_v = compat.shard_map(
+    o, cache_k, cache_v = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(qspec, qspec, qspec, seq_spec, seq_spec),

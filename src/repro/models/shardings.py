@@ -143,35 +143,8 @@ def axes_for_mesh(mesh, strategy: str = "2d") -> MeshAxes:
     )
 
 
-def get_abstract_mesh():
-    """Version-compat shim: ``jax.sharding.get_abstract_mesh`` only exists
-    in newer jax. On older releases (e.g. 0.4.37) fall back to the
-    internal abstract-mesh context, then to the physical mesh entered via
-    ``with mesh:`` (thread_resources). Returns None when no mesh is in
-    context."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:
-        from jax._src import mesh as _mesh_internal
-    except ImportError:  # pragma: no cover - future jax without _src.mesh
-        return None
-    getter = getattr(_mesh_internal, "get_abstract_mesh", None)
-    if getter is not None:
-        m = getter()
-        # 0.4.37 returns the raw context value: () when unset.
-        if m is not None and not isinstance(m, tuple):
-            return m
-    env = getattr(_mesh_internal, "thread_resources", None)
-    physical = getattr(getattr(env, "env", None), "physical_mesh", None)
-    if physical is not None and not physical.empty:
-        return physical
-    return None
-
-
 def has_mesh() -> bool:
-    m = get_abstract_mesh()
-    return m is not None and not m.empty
+    return not jax.sharding.get_abstract_mesh().empty
 
 
 def constrain(x, spec: P):

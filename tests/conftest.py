@@ -4,15 +4,12 @@ Optional-dependency guard: some test extras cannot always be installed
 (no network in some environments), so modules that use them are skipped
 at collection time instead of erroring the whole run. The scan is
 content-based, keyed on the table below, so new test modules using an
-optional dependency are covered automatically. The same guard style
-protects the CI benchmark smoke: benchmarks/run.py applies it for the
-accelerator backend (falling back to the Pallas interpreter sweep when
-no TPU/GPU is attached) rather than for Python packages.
+optional dependency are covered automatically.
 
 Autotune-cache isolation: kernels/autotune.py persists sweep winners to
-a per-user disk cache by default. A test run must neither read ambient
-home-directory state (a stale winner would silently skip the sweep
-paths the tests exercise) nor write to the user's real cache, so the
+a disk cache in the checkout by default. A test run must neither read ambient
+cached state (a stale winner would silently skip the sweep
+paths the tests exercise) nor write to the checkout's cache, so the
 whole suite is pointed at a throwaway path unless the caller already
 pinned one.
 """

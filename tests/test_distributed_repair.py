@@ -15,7 +15,7 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import distributed_xor_repair
 
 for t, q in [(8, 4096), (5, 1000), (3, 257)]:
@@ -24,7 +24,7 @@ for t, q in [(8, 4096), (5, 1000), (3, 257)]:
     rng = np.random.default_rng(t)
     blocks = rng.integers(0, 256, (t, q), dtype=np.uint8)
     want = np.bitwise_xor.reduce(blocks, axis=0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         got = np.asarray(jax.jit(
             lambda b: distributed_xor_repair(b, mesh, "data")
         )(jnp.asarray(blocks)))

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -76,6 +77,24 @@ def test_matmul_matches_np():
     got = np.asarray(gf256.matmul(jnp.asarray(a), jnp.asarray(b)))
     want = gf256.np_matmul(a, b)
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize(
+    "q", [gf256.MATMUL_SLICE - 100, 2 * gf256.MATMUL_SLICE + 100]
+)
+def test_sliced_matmul_matches_full_product_formula(q):
+    """The sliced matmul is byte-identical to the formula it replaced,
+    which built the whole (..., M, K, q) log/exp product at once: on a
+    batched (t, K, q) input, with q inside one slice and with q past two
+    whole slices and a ragged tail."""
+    rng = np.random.default_rng(q)
+    a = rng.integers(0, 256, size=(4, 3), dtype=np.uint8)
+    a[0] = (0, 1, 255)
+    b = jnp.asarray(rng.integers(0, 256, size=(2, 3, q), dtype=np.uint8))
+    full = gf256._xor_reduce(gf256.mul(jnp.asarray(a)[:, :, None], b[..., None, :, :]), axis=-2)
+    got = gf256.matmul(jnp.asarray(a), b)
+    assert got.shape == (2, 4, q)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
 
 
 def test_np_inv_matrix_roundtrip():

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.analysis.hlo_cost import analyze_hlo_text, builtin_cost_dict, parse_module
+from repro.analysis.hlo_cost import analyze_hlo_text, parse_module
 
 
 def _cost(fn, *args):
@@ -34,7 +34,7 @@ def test_scan_matmul_flops_trip_scaled():
     expect = 2 * B * D * D * L
     assert cost.flops == pytest.approx(expect, rel=0.02), (cost.flops, expect)
     # builtin cost_analysis counts the body once -> must be ~L x smaller
-    builtin = builtin_cost_dict(co).get("flops", 0.0)
+    builtin = co.cost_analysis().get("flops", 0.0)
     assert builtin < expect / 2
 
 

@@ -83,3 +83,19 @@ def test_block_n_variants():
             ops.gf256_matmul(coef, jnp.asarray(data), block_n=bn, interpret=True)
         )
         np.testing.assert_array_equal(got, want, err_msg=f"block_n={bn}")
+
+
+@pytest.mark.parametrize("backend, interpret", [("tpu", False), ("cpu", True), ("gpu", None)])
+def test_auto_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """interpret=None compiles on a TPU, interprets on the CPU, and
+    refuses any other backend instead of hiding it behind the
+    interpreter."""
+    from repro.kernels import backend as be
+
+    monkeypatch.setattr(be.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            be.resolve_interpret(None)
+    else:
+        assert be.resolve_interpret(None) is interpret
+    assert be.resolve_interpret(True) is True  # an explicit choice stands

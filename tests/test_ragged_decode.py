@@ -17,6 +17,7 @@ from repro.gateway.coalescer import (
     RAGGED,
     DecodeCoalescer,
 )
+from repro.coding.gf256 import np_matmul
 from repro.gateway.planner import DecodeOp
 from repro.kernels import ops, ref
 from repro.kernels.gf256_matmul import expand_coeff_bitplanes
@@ -27,11 +28,10 @@ from repro.kernels.ragged_decode import CHUNK_BIG, CHUNK_SMALL, chunk_sizes
 # kernel level
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("packed", [False, True])
-def test_ragged_gf256_matches_reference_per_tile(packed):
+def test_ragged_gf256_matches_reference_per_tile():
     """Each tile applies ITS OWN coefficient row: C tiles with C distinct
     rows must match C independent reference products."""
-    rng = np.random.default_rng(7 + packed)
+    rng = np.random.default_rng(7)
     c, kk, tn = 8, 6, 256
     coef_rows = rng.integers(0, 256, (c, kk), dtype=np.uint8)
     mc = np.stack(
@@ -39,7 +39,9 @@ def test_ragged_gf256_matches_reference_per_tile(packed):
     )
     data = rng.integers(0, 256, (c, kk, tn), dtype=np.uint8)
     out = np.asarray(
-        ops.gf256_ragged(mc, jnp.asarray(data), interpret=True, packed=packed)
+        ops.gf256_ragged(
+            mc.transpose(1, 0, 2), data.transpose(1, 0, 2), interpret=True
+        )
     )
     for i in range(c):
         want = np.asarray(
@@ -51,8 +53,24 @@ def test_ragged_gf256_matches_reference_per_tile(packed):
 def test_ragged_xor_matches_reduce():
     rng = np.random.default_rng(11)
     data = rng.integers(0, 256, (12, 5, 512), dtype=np.uint8)
-    out = np.asarray(ops.xor_ragged(jnp.asarray(data), interpret=True))
+    out = np.asarray(ops.xor_ragged(data.transpose(1, 0, 2), interpret=True))
     np.testing.assert_array_equal(out, np.bitwise_xor.reduce(data, axis=1))
+
+
+@pytest.mark.parametrize("tile_block", [8, 16, CHUNK_BIG])
+def test_ragged_tile_blocks_split_a_chunk(tile_block):
+    """A compiled launch may walk a big chunk in several tile blocks:
+    every block height the TPU accepts gives the same bytes."""
+    rng = np.random.default_rng(tile_block)
+    c, kk, tn = CHUNK_BIG, 5, 512
+    coef_rows = rng.integers(0, 256, (c, kk), dtype=np.uint8)
+    mc = np.stack([expand_coeff_bitplanes(coef_rows[i][None, :])[0] for i in range(c)])
+    data = rng.integers(0, 256, (kk, c, tn), dtype=np.uint8)
+    out = ops.gf256_ragged(mc.transpose(1, 0, 2), data, interpret=True, tile_block=tile_block)
+    for i in range(c):
+        np.testing.assert_array_equal(out[i], np_matmul(coef_rows[i][None, :], data[:, i])[0])
+    xor = ops.xor_ragged(data, interpret=True, tile_block=tile_block)
+    np.testing.assert_array_equal(xor, np.bitwise_xor.reduce(data, axis=0))
 
 
 def test_ragged_zero_padding_is_identity():
@@ -67,7 +85,9 @@ def test_ragged_zero_padding_is_identity():
     data = np.zeros((c, kk, tn), dtype=np.uint8)
     live = rng.integers(0, 256, (c, 3, 100), dtype=np.uint8)  # ragged tail
     data[:, :3, :100] = live
-    out = np.asarray(ops.gf256_ragged(mc, jnp.asarray(data), interpret=True))
+    out = np.asarray(
+        ops.gf256_ragged(mc.transpose(1, 0, 2), data.transpose(1, 0, 2), interpret=True)
+    )
     for i in range(c):
         want = np.asarray(
             ref.gf256_matmul(jnp.asarray(coef_rows[i][None, :]), jnp.asarray(live[i]))
@@ -179,6 +199,8 @@ def test_ragged_top_rung_overflow_window():
     assert buck.stats.decode_calls == 2  # 256 + 10-padded-to-16
     # ragged: 266 tiles -> 8 big + 3 small chunks, all one signature set
     assert rag.stats.decode_calls == len(chunk_sizes(n_ops))
+    assert rag.stats.launches_by_kind == {"V": rag.stats.decode_calls}
+    assert buck.stats.launches_by_kind == {"V": 2}
     assert rag.stats.max_batch >= CHUNK_BIG
 
 
@@ -194,6 +216,26 @@ def test_ragged_multi_tile_rows_roundtrip():
     res, _units = co.execute([op], lambda k: store[k])
     want = np.bitwise_xor.reduce(np.stack([store[s] for s in sources]), axis=0)
     np.testing.assert_array_equal(res[0][0], want)
+
+
+def test_pinned_tile_width_cuts_and_bills_at_that_width():
+    """A pinned tile width replaces the autotuned one: rows are cut and
+    billed in tiles of that width, and no autotune lookup runs."""
+    from repro.kernels import autotune
+
+    rng = np.random.default_rng(6)
+    length = 65536
+    sources = tuple(("g0", r, 0) for r in range(3))
+    store = {s: rng.integers(0, 256, length, dtype=np.uint8) for s in sources}
+    op = DecodeOp("V", "g0", 3, (0,), sources, None)
+    before = autotune.cache_stats()
+    co = DecodeCoalescer(interpret=True, mode=RAGGED, tile_n=16384)
+    res, units = co.execute([op], lambda k: store[k])
+    want = np.bitwise_xor.reduce(np.stack([store[s] for s in sources]), axis=0)
+    np.testing.assert_array_equal(res[0][0], want)
+    assert sum(u.tiles for u in units) == 4
+    assert co.tiles_for(length, "V") == 4
+    assert autotune.cache_stats() == before
 
 
 # ---------------------------------------------------------------------------

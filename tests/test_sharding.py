@@ -249,3 +249,22 @@ def test_decode_cost_per_tile_validation():
         build(decode_cost_per_tile=0.01, coalesce="bucketed")
     gw = build(decode_cost_per_tile=0.01)
     assert gw.config.decode_cost_per_tile == 0.01
+
+
+
+def test_per_tile_billing_pins_the_tile_width():
+    """Billed tiles must not follow the autotune sweep: per-tile billing
+    fixes the ragged tile width, other billing leaves it autotuned."""
+    from repro.gateway import ObjectGateway
+    from repro.gateway.gateway import BILLED_TILE_N
+
+    def build(**cfg_kw):
+        return ObjectGateway(
+            CODE,
+            ClusterProfile.computation_critical(),
+            NUM_NODES,
+            GatewayConfig(**cfg_kw),
+        )
+
+    assert build(decode_cost_per_tile=0.01).coalescer.tile_n == BILLED_TILE_N
+    assert build(decode_cost=0.01).coalescer.tile_n is None

@@ -58,7 +58,11 @@ def test_ragged_gf256_encode_matches_parity_oracle():
     mc = np.stack(
         [expand_coeff_bitplanes(pmat[i % (n - k)][None, :]) [0] for i in range(c)]
     )
-    out = np.asarray(ops.gf256_ragged_encode(mc, data, interpret=True))
+    out = np.asarray(
+        ops.gf256_ragged_encode(
+            mc.transpose(1, 0, 2), data.transpose(1, 0, 2), interpret=True
+        )
+    )
     for i in range(c):
         want = np_matmul(pmat[i % (n - k)][None, :], data[i])[0]
         assert np.array_equal(out[i], want)
@@ -70,7 +74,7 @@ def test_ragged_xor_encode_matches_fold_oracle():
     c = CHUNK_SMALL
     kk = 5  # stored parity + two (old, new) delta pairs
     data = rng.integers(0, 256, (c, kk, tn), dtype=np.uint8)
-    out = np.asarray(ops.xor_ragged_encode(data, interpret=True))
+    out = np.asarray(ops.xor_ragged_encode(data.transpose(1, 0, 2), interpret=True))
     for i in range(c):
         want = data[i][0].copy()
         for r in range(1, kk):

@@ -135,12 +135,9 @@ def run(seed: int, block_bytes: int = BLOCK_BYTES) -> dict:
         ops_by_kind=dict(st.ops_by_kind),
         launches_by_kind=dict(st.launches_by_kind),
         blocks_repaired=sum(r.blocks_repaired for r in report.repair_reports),
-        serve_warmup_s=st.warmup_seconds,
     )
     say(
-        f"serve: {len(requests)} requests, {len(crashes)} node crashes, {out['serve_s']} s, "
-        f"of which first-sight kernel launches (trace, compile, run) "
-        f"{out['serve_warmup_s']} s; "
+        f"serve: {len(requests)} requests, {len(crashes)} node crashes, {out['serve_s']} s; "
         f"GETs {out['gets']} ({len(report.degraded_gets)} degraded), "
         f"verified {out['gets_verified']}; PUTs {out['puts']}"
     )

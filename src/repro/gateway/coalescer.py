@@ -78,6 +78,7 @@ from repro.kernels import autotune, ops
 from repro.kernels import ragged_decode as _rdk
 from repro.kernels.gf256_matmul import expand_coeff_bitplanes
 from repro.kernels.ops import _next_pow2
+from repro.obs.host import span
 from repro.storage.blockstore import BlockKey
 
 _log = logging.getLogger(__name__)
@@ -136,7 +137,6 @@ class CoalescerStats:
     sources_by_kind: dict[str, int] = field(default_factory=dict)
     jit_entries: int = 0  # LIVE traced kernel signatures (see below)
     jit_retraces: int = 0  # every trace ever taken (compile churn)
-    warmup_seconds: float = 0.0  # wall time of those first-sight launches
     decode_shapes: int = 0  # distinct decode shape_keys ever executed
     # write-dataplane counters (kinds "EH"/"EV"): kept separate so a
     # read-only run's decode stats stay bit-identical with or without
@@ -423,20 +423,21 @@ class DecodeCoalescer:
         billed by tile share."""
         # source-major staging (K, C, TN): the kernels' layout, so the
         # launch views it as words with no host copy
-        data = self._buffer((kind, "data", c), (k_cap, c, tn))
-        data.fill(0)
-        mc = None
-        if kind not in ("V", "EV"):
-            mc = self._buffer((kind, "mc", c), (k_cap, c, 8))
-            mc.fill(0)
-        useful = 0
-        for slot, (ri, off, valid) in enumerate(chunk_tiles):
-            _j, _col, planes, sources, _length = rows[ri]
-            for k, s in enumerate(sources):
-                data[k, slot, :valid] = src[s][off : off + valid]
-            if mc is not None:
-                mc[: planes.shape[0], slot, :] = planes
-            useful += valid * len(sources)
+        with span("stage.gather", kind=kind, tiles=c):
+            data = self._buffer((kind, "data", c), (k_cap, c, tn))
+            data.fill(0)
+            mc = None
+            if kind not in ("V", "EV"):
+                mc = self._buffer((kind, "mc", c), (k_cap, c, 8))
+                mc.fill(0)
+            useful = 0
+            for slot, (ri, off, valid) in enumerate(chunk_tiles):
+                _j, _col, planes, sources, _length = rows[ri]
+                for k, s in enumerate(sources):
+                    data[k, slot, :valid] = src[s][off : off + valid]
+                if mc is not None:
+                    mc[: planes.shape[0], slot, :] = planes
+                useful += valid * len(sources)
         interpret = self.interpret
         # encode kinds route to the separate ragged_encode jit entries,
         # keeping the encode/decode signature pools independently
@@ -475,9 +476,8 @@ class DecodeCoalescer:
                     "retired %d traced signature(s)",
                     kind, k_cap, tn, len(stale),
                 )
-            t_warm = time.perf_counter()
-            jax.block_until_ready(launch())
-            self.stats.warmup_seconds += time.perf_counter() - t_warm
+            with span("kernel.warmup", kind=kind):
+                jax.block_until_ready(launch())
             self._warm.add(sig)
             self.stats.jit_entries = len(self._warm)
             self.stats.jit_retraces += 1
@@ -489,8 +489,9 @@ class DecodeCoalescer:
         best = self._best.get(sig)
         dt = dt if best is None or dt < best else best
         self._best[sig] = dt
-        for slot, (ri, off, valid) in enumerate(chunk_tiles):
-            out_rows[ri][off : off + valid] = out[slot, :valid]
+        with span("stage.scatter", kind=kind, tiles=c):
+            for slot, (ri, off, valid) in enumerate(chunk_tiles):
+                out_rows[ri][off : off + valid] = out[slot, :valid]
         # one unit per op, billed its tile share of the launch, so the
         # engine pool can spread this single launch across engines
         # (the gateway still gates all of them on the launch-wide
@@ -550,9 +551,8 @@ class DecodeCoalescer:
         # window's simulated decode latency.
         sig = (BUCKETED, key, b_pad, data.shape[-1])
         if sig not in self._warm:
-            t_warm = time.perf_counter()
-            jax.block_until_ready(launch())
-            self.stats.warmup_seconds += time.perf_counter() - t_warm
+            with span("kernel.warmup", kind=kind):
+                jax.block_until_ready(launch())
             self._warm.add(sig)
             self.stats.jit_entries = len(self._warm)
             self.stats.jit_retraces += 1

@@ -9,6 +9,7 @@ a real TPU backend pass ``interpret=False`` / rely on the default).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -17,6 +18,7 @@ from repro.kernels import ragged_decode as _rdk
 from repro.kernels import ragged_encode as _rek
 from repro.kernels import xor_parity as _xpk
 from repro.kernels.backend import resolve_interpret
+from repro.obs.host import span
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> tuple[jnp.ndarray, int]:
@@ -128,14 +130,16 @@ def _ragged(entry, mc, data, interpret, tile_block) -> np.ndarray:
     _, c, tn = data.shape
     if tile_block is None:
         tile_block = _rdk.tile_block_for(c, tn, interpret)
-    planes = () if mc is None else (jnp.asarray(_gfk.stage_planes(mc)),)
-    out = entry(
-        *planes,
-        jnp.asarray(_gfk.stage_words(data)),
-        tile_block=tile_block,
-        interpret=interpret,
-    )
-    return np.asarray(out).view(np.uint8)
+    with span("stage.h2d", tiles=c, bytes=data.nbytes):
+        planes = () if mc is None else (jnp.asarray(_gfk.stage_planes(mc)),)
+        planes, words = jax.block_until_ready(
+            (planes, jnp.asarray(_gfk.stage_words(data)))
+        )
+    with span("kernel.run", tiles=c):
+        out = entry(*planes, words, tile_block=tile_block, interpret=interpret)
+        out.block_until_ready()
+    with span("stage.d2h", tiles=c):
+        return np.asarray(out).view(np.uint8)
 
 
 def gf256_ragged(

@@ -1,10 +1,12 @@
-"""Sim-time observability plane: spans, streaming metrics, Perfetto
-export and critical-path accounting for the gateway stack.
+"""Observability plane: sim-time spans, streaming metrics, Perfetto
+export and critical-path accounting for the gateway stack, and the
+wall-clock spans of its host path (``repro.obs.host``).
 
-Everything here runs over the SIMULATED clock — spans measure simulated
-seconds, not wall time — and is observation-only by contract: enabling
-tracing never changes event ordering, simulated timestamps, or payload
-bytes (tests/test_obs.py pins traced ≡ untraced fingerprints).
+Everything but ``repro.obs.host`` runs over the SIMULATED clock — spans
+measure simulated seconds, not wall time — and is observation-only by
+contract: enabling tracing never changes event ordering, simulated
+timestamps, or payload bytes (tests/test_obs.py pins traced ≡ untraced
+fingerprints).
 
 Span taxonomy
 =============
@@ -83,6 +85,43 @@ deliver); ``stage_shares`` aggregates a run into shares summing to 1.0;
 ``launch_amortization`` reports how ops shared physical launches.
 Export: ``write_chrome_trace`` / ``validate_chrome_trace`` produce and
 check Perfetto-loadable JSON (see examples/gateway_serving.py --trace).
+
+Wall-clock spans
+================
+
+``repro.obs.host.span(name, **attrs)`` is a ``jax.profiler.TraceAnnotation``:
+WALL-clock time on the profiler's host line, the clock of the device's
+ops, recorded only while a profiler trace is active (no switch of its
+own). Nesting on the thread gives each span its parent; a reduction
+gives each name the window time in which it is the innermost span.
+``repro.obs.host.SPANS`` holds every name:
+
+  ``gw.serve``          ObjectGateway.serve, whole body (self time: the
+                        event loop's own Python)
+  ``gw.plan``           a GET window's planning and admission loop
+  ``gw.fetch``          a GET window's fetch/replan loop (store reads,
+                        replans, hedging)
+  ``gw.decode``         the window's decode, around coalescer.execute
+  ``gw.handoff``        payload assembly and its sha256
+  ``fabric.transfer``   NetSimulator.transfer (host cost of the
+                        simulated fabric)
+  ``store.crc32``       BlockStore.digest, the one crc32 site, with its
+                        copy to bytes
+  ``stage.gather``      a ragged launch's zero-fill and gather
+  ``stage.h2d``         a ragged launch's host-to-device uploads
+  ``kernel.run``        a launch's dispatch up to block_until_ready
+                        (ragged entries and BlockFixer's jits)
+  ``kernel.warmup``     the coalescer's unbilled first-sight launch (a
+                        compile inside a traced window shows here)
+  ``stage.d2h``         a ragged launch's device-to-host copy
+  ``stage.scatter``     a ragged launch's scatter into output rows
+  ``repair.sweep``      the crc32 check of a group's survivors before
+                        its rebuild (children: ``store.crc32``)
+  ``repair.gather``     BlockFixer's stack of a step's sources
+  ``repair.h2d``        BlockFixer's host-to-device copy
+  ``repair.d2h``        BlockFixer's device-to-host copy
+  ``repair.writeback``  put_block of a rebuilt block (children:
+                        ``store.crc32``)
 """
 
 from repro.obs.critical_path import (
@@ -95,7 +134,6 @@ from repro.obs.critical_path import (
 from repro.obs.export import (
     to_chrome_trace,
     validate_chrome_trace,
-    validate_file,
     write_chrome_trace,
 )
 from repro.obs.metrics import (
@@ -127,6 +165,5 @@ __all__ = [
     "stage_shares",
     "to_chrome_trace",
     "validate_chrome_trace",
-    "validate_file",
     "write_chrome_trace",
 ]

@@ -140,9 +140,3 @@ def validate_chrome_trace(doc: dict) -> int:
                 )
     return len(events)
 
-
-def validate_file(path: str) -> int:
-    """Load ``path`` and validate it; returns the event count."""
-    with open(path) as f:
-        doc = json.load(f)
-    return validate_chrome_trace(doc)

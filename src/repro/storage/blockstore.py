@@ -34,6 +34,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.obs.host import span
+
 BlockKey = tuple[str, int, int]  # (group_id, row, col)
 
 
@@ -49,6 +51,9 @@ class BlockStore:
     placement: dict[BlockKey, int] = field(default_factory=dict)
     failed_nodes: set[int] = field(default_factory=set)
     checksums: dict[BlockKey, int] = field(default_factory=dict)
+    # bytes digested since the store was made, cumulative: the integrity
+    # plane's work, read as a difference over a window
+    crc32_bytes: int = 0
     _group_counter: int = 0
 
     # -- failure domains -------------------------------------------------------
@@ -60,10 +65,13 @@ class BlockStore:
         return int(node) // self.nodes_per_rack
 
     # -- integrity -------------------------------------------------------------
-    @staticmethod
-    def digest(data: np.ndarray) -> int:
-        """crc32c-style content digest of a block's bytes."""
-        return zlib.crc32(np.asarray(data).tobytes())
+    def digest(self, data: np.ndarray) -> int:
+        """crc32c-style content digest of a block's bytes; the one crc32
+        site of the store, counted in ``crc32_bytes``."""
+        data = np.asarray(data)
+        self.crc32_bytes += data.nbytes
+        with span("store.crc32", bytes=data.nbytes):
+            return zlib.crc32(data.tobytes())
 
     # -- placement -----------------------------------------------------------
     def _place_group(self, group_id: str, rows: int, cols: int) -> None:

@@ -60,6 +60,8 @@ from __future__ import annotations
 import bisect
 from dataclasses import dataclass, field
 
+from repro.obs.host import span
+
 FIFO = "fifo"
 QUANTUM = "quantum"
 
@@ -371,58 +373,59 @@ class NetSimulator:
 
     def transfer(self, t: Transfer) -> float:
         """Schedule a transfer; returns its completion time (seconds)."""
-        tenant = t.effective_tenant
-        if self.mode == QUANTUM:
-            end, busy, first_start = self._transfer_quantum(t, tenant)
-        else:
-            end, busy, first_start = self._transfer_fifo(t, tenant)
-        self.total_bytes += t.nbytes
-        self.makespan = max(self.makespan, end)
-        self.class_bytes[tenant] = self.class_bytes.get(tenant, 0) + t.nbytes
-        self.class_busy[tenant] = self.class_busy.get(tenant, 0.0) + busy
-        self.class_makespan[tenant] = max(
-            self.class_makespan.get(tenant, 0.0), end
-        )
-        # starvation accounting: how long the transfer queued before its
-        # first byte moved (beyond its own dependency time)
-        wait = max(0.0, first_start - t.not_before)
-        self.tenant_wait_max[tenant] = max(
-            self.tenant_wait_max.get(tenant, 0.0), wait
-        )
-        self.tenant_wait_sum[tenant] = self.tenant_wait_sum.get(tenant, 0.0) + wait
-        self.tenant_transfers[tenant] = self.tenant_transfers.get(tenant, 0) + 1
-        if t.deadline is not None:
-            key = (
-                "tenant_deadline_missed" if end > t.deadline else "tenant_deadline_met"
+        with span("fabric.transfer", bytes=t.nbytes):
+            tenant = t.effective_tenant
+            if self.mode == QUANTUM:
+                end, busy, first_start = self._transfer_quantum(t, tenant)
+            else:
+                end, busy, first_start = self._transfer_fifo(t, tenant)
+            self.total_bytes += t.nbytes
+            self.makespan = max(self.makespan, end)
+            self.class_bytes[tenant] = self.class_bytes.get(tenant, 0) + t.nbytes
+            self.class_busy[tenant] = self.class_busy.get(tenant, 0.0) + busy
+            self.class_makespan[tenant] = max(
+                self.class_makespan.get(tenant, 0.0), end
             )
-            counter = getattr(self, key)
-            counter[tenant] = counter.get(tenant, 0) + 1
-        if (
-            t.ctx is not None
-            and self.tracer is not None
-            and getattr(self.tracer, "enabled", False)
-        ):
-            tid, pid = t.ctx
-            track = self._port_tracks.get(t.src_node)
-            if track is None:
-                track = self._port_tracks[t.src_node] = (
-                    "fabric",
-                    f"port{t.src_node}",
+            # starvation accounting: how long the transfer queued before its
+            # first byte moved (beyond its own dependency time)
+            wait = max(0.0, first_start - t.not_before)
+            self.tenant_wait_max[tenant] = max(
+                self.tenant_wait_max.get(tenant, 0.0), wait
+            )
+            self.tenant_wait_sum[tenant] = self.tenant_wait_sum.get(tenant, 0.0) + wait
+            self.tenant_transfers[tenant] = self.tenant_transfers.get(tenant, 0) + 1
+            if t.deadline is not None:
+                key = (
+                    "tenant_deadline_missed" if end > t.deadline else "tenant_deadline_met"
                 )
-            self.tracer.span(
-                "xfer",
-                first_start,
-                end,
-                tid,
-                pid,
-                track=track,
-                src=t.src_node,
-                dst=t.dst_node,
-                bytes=t.nbytes,
-                tenant=tenant,
-                wait=wait,
-            )
-        return end
+                counter = getattr(self, key)
+                counter[tenant] = counter.get(tenant, 0) + 1
+            if (
+                t.ctx is not None
+                and self.tracer is not None
+                and getattr(self.tracer, "enabled", False)
+            ):
+                tid, pid = t.ctx
+                track = self._port_tracks.get(t.src_node)
+                if track is None:
+                    track = self._port_tracks[t.src_node] = (
+                        "fabric",
+                        f"port{t.src_node}",
+                    )
+                self.tracer.span(
+                    "xfer",
+                    first_start,
+                    end,
+                    tid,
+                    pid,
+                    track=track,
+                    src=t.src_node,
+                    dst=t.dst_node,
+                    bytes=t.nbytes,
+                    tenant=tenant,
+                    wait=wait,
+                )
+            return end
 
     def send_backlog(self, node: int, tenant, now: float) -> float:
         """How far beyond ``now`` this tenant's next quantum on the

@@ -35,6 +35,7 @@ from repro.core.failure_matrix import independent_clusters
 from repro.core.product_code import CoreCode, CoreCodec
 from repro.core.recoverability import is_recoverable
 from repro.core.scheduling import SCHEDULERS, RepairStep
+from repro.obs.host import span
 from repro.storage.blockstore import BlockStore
 from repro.storage.netmodel import ClusterProfile, NetSimulator, Transfer
 
@@ -235,14 +236,20 @@ class BlockFixer:
 
     # -- timed codec ops ------------------------------------------------------
     def _measure(self, fn, *args):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        self._timed += (time.perf_counter() - t0) * self.profile.compute_scale
-        return out
+        """``fn(*args)`` on the device: host copies in, the timed launch,
+        the rebuilt blocks copied back."""
+        with span("repair.h2d"):
+            args = jax.block_until_ready([jnp.asarray(a) for a in args])
+        with span("kernel.run"):
+            t0 = time.perf_counter()
+            out = fn(*args)
+            jax.block_until_ready(out)
+            self._timed += (time.perf_counter() - t0) * self.profile.compute_scale
+        with span("repair.d2h"):
+            return np.asarray(out)
 
     def _vertical_repair(self, sources: np.ndarray) -> np.ndarray:
-        return np.asarray(self._measure(_xor_jit, jnp.asarray(sources)))
+        return self._measure(_xor_jit, sources)
 
     def _horizontal_repair(
         self, avail_cols: np.ndarray, blocks: np.ndarray, missing_cols: np.ndarray
@@ -250,9 +257,7 @@ class BlockFixer:
         row_ids, coeffs = self.code.horizontal.repair_matrix(avail_cols, missing_cols)
         pos = {int(a): i for i, a in enumerate(avail_cols)}
         sel = np.asarray([pos[int(r)] for r in row_ids])
-        return np.asarray(
-            self._measure(_gf_matmul_jit, jnp.asarray(coeffs), jnp.asarray(blocks[sel]))
-        )
+        return self._measure(_gf_matmul_jit, coeffs, blocks[sel])
 
     # -- main entry ------------------------------------------------------------
     def fix_group(self, group_id: str, rows: int | None = None) -> RepairReport:
@@ -296,9 +301,10 @@ class BlockFixer:
         # source; its bytes exist only once its own fetches landed
         repaired_ready: dict[int, float] = {}
         for kind, sources, repaired in plan:
-            blocks = np.stack(
-                [self.store.get((group_id, 0, c)) for c in sources]
-            )
+            with span("repair.gather", group=group_id):
+                blocks = np.stack(
+                    [self.store.get((group_id, 0, c)) for c in sources]
+                )
             dst = self._dst_node(group_id, 0, repaired[0])
             ready = 0.0
             for c in sources:
@@ -323,7 +329,8 @@ class BlockFixer:
                     np.asarray(sources), blocks, np.asarray(repaired)
                 )
             for i, c in enumerate(repaired):
-                self.store.put_block((group_id, 0, c), rep[i])
+                with span("repair.writeback", key=(group_id, 0, c)):
+                    self.store.put_block((group_id, 0, c), rep[i])
                 repaired_ready[c] = ready
                 if self.on_block_repaired is not None:
                     self.on_block_repaired((group_id, 0, c))
@@ -355,11 +362,7 @@ class BlockFixer:
         row_ids, coeffs = self.family.code.repair_matrix(sources, missing)
         pos = {int(a): i for i, a in enumerate(sources)}
         sel = np.asarray([pos[int(r)] for r in row_ids])
-        return np.asarray(
-            self._measure(
-                _gf_matmul_jit, jnp.asarray(coeffs), jnp.asarray(blocks[sel])
-            )
-        )
+        return self._measure(_gf_matmul_jit, coeffs, blocks[sel])
 
     # -- HDFS-RAID modes --------------------------------------------------------
     def _fix_raid(self, group_id: str, rows: int, cols: int, optimized: bool) -> RepairReport:
@@ -389,7 +392,10 @@ class BlockFixer:
                     fetch_cols = avail[: self.code.k]  # Opt1: exactly k
                 else:
                     fetch_cols = avail  # classic: ALL remaining blocks
-                blocks = np.stack([self._get(group_id, r, c, repaired_cells) for c in fetch_cols])
+                with span("repair.gather", group=group_id):
+                    blocks = np.stack(
+                        [self._get(group_id, r, c, repaired_cells) for c in fetch_cols]
+                    )
                 dst = self._dst_node(group_id, r, batch[0])
                 ready = 0.0
                 for c in fetch_cols:
@@ -412,7 +418,8 @@ class BlockFixer:
                     np.asarray(batch),
                 )
                 for i, c in enumerate(batch):
-                    self.store.put_block((group_id, r, c), rep[i])
+                    with span("repair.writeback", key=(group_id, r, c)):
+                        self.store.put_block((group_id, r, c), rep[i])
                     repaired_cells.add(c)
                     if self.on_block_repaired is not None:
                         self.on_block_repaired((group_id, r, c))
@@ -483,7 +490,8 @@ class BlockFixer:
         report: RepairReport,
     ) -> None:
         srcs = [(r, c) for (r, c) in step.sources]
-        blocks = np.stack([self.store.get((group_id, r, c)) for r, c in srcs])
+        with span("repair.gather", group=group_id):
+            blocks = np.stack([self.store.get((group_id, r, c)) for r, c in srcs])
         dst_cell = step.repairs[0]
         dst = self._dst_node(group_id, *dst_cell)
         ctx = self._obs_ctx()
@@ -521,7 +529,8 @@ class BlockFixer:
             missing_cols = np.asarray([c for (_, c) in step.repairs])
             rep = self._horizontal_repair(avail_cols, blocks, missing_cols)
         for i, cell in enumerate(step.repairs):
-            self.store.put_block((group_id, cell[0], cell[1]), rep[i])
+            with span("repair.writeback", key=(group_id, *cell)):
+                self.store.put_block((group_id, cell[0], cell[1]), rep[i])
             block_ready[cell] = ready
             if self.on_block_repaired is not None:
                 self.on_block_repaired((group_id, cell[0], cell[1]))
@@ -580,11 +589,7 @@ class BlockFixer:
                 )
             report.blocks_fetched += len(fetch)
             report.bytes_fetched += int(blocks.nbytes)
-            data = np.asarray(
-                self._measure(
-                    _decode_jit_factory(self.code, tuple(fetch)), jnp.asarray(blocks)
-                )
-            )
+            data = self._measure(_decode_jit_factory(self.code, tuple(fetch)), blocks)
         else:
             got: dict[int, np.ndarray] = {}
             for c in range(k):

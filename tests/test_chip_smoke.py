@@ -28,7 +28,6 @@ def test_smoke_phases_pass_at_small_blocks(chip_smoke):
     assert all(out["ops_by_kind"][k] > 0 for k in ("V", "H", "EH", "EV"))
     assert all(out["launches_by_kind"][k] > 0 for k in ("V", "H", "EH", "EV"))
     assert out["blocks_repaired"] > 0
-    assert 0 < out["serve_warmup_s"] < out["serve_s"]
     assert out["durability"]["blocks_lost"] == 0
     assert out["parity"]["stale_blocks"] == 0
 

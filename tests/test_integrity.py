@@ -73,6 +73,25 @@ def test_put_records_digest_and_verify_passes_when_clean():
         assert store.checksum_ok(key, store.get(key)) is True
 
 
+def test_crc32_bytes_counts_exactly_the_digested_bytes():
+    """Every digest the store takes (PUT, verify, decode-output check,
+    repair write-back) adds its block's bytes to ``crc32_bytes``, and
+    nothing else does."""
+    code = CoreCode(9, 6, 3)
+    store = BlockStore(num_nodes=60)
+    make_group(code, store, q=1024)
+    blocks = code.rows * code.n
+    assert store.crc32_bytes == blocks * 1024
+    store.get(("g0", 0, 0))
+    store.quarantine(("g0", 1, 1))
+    assert store.crc32_bytes == blocks * 1024
+    assert store.verify(("g0", 0, 0)) and store.verify(("g0", 1, 1))  # quarantined: no digest
+    assert store.checksum_ok(("g0", 0, 1), np.zeros(1024, np.uint8)) is False
+    assert store.checksum_ok(("gX", 0, 0), np.zeros(1024, np.uint8)) is None
+    store.put_block(("g0", 1, 1), np.zeros(512, np.uint8))
+    assert store.crc32_bytes == (blocks + 2) * 1024 + 512
+
+
 def test_corrupt_block_modes_break_verify_but_not_checksum():
     code = CoreCode(9, 6, 3)
     store = BlockStore(num_nodes=30)

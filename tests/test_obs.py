@@ -449,3 +449,52 @@ def test_chrome_validator_rejects_malformed():
         validate_chrome_trace(
             {"traceEvents": [{"name": "x", "ph": "X", "pid": 1, "tid": 1, "ts": 0}]}
         )  # X without dur
+
+
+def test_every_wall_clock_span_is_in_the_table():
+    """The program opens a wall-clock span only under a name of
+    ``repro.obs.host.SPANS``, and every name there is opened somewhere."""
+    import pathlib
+    import re
+
+    from repro.obs.host import SPANS
+
+    src = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
+    used = {
+        m
+        for path in src.rglob("*.py")
+        for m in re.findall(r'(?<![.\w])span\(\s*"([^"]+)"', path.read_text())
+    }
+    assert used == set(SPANS)
+
+
+def test_wall_clock_spans_record_only_under_a_trace(tmp_path):
+    """A span is a profiler annotation: with no trace active it records
+    nothing and returns what its body returns; under a trace it lands on
+    the host line, nested in its parent, with its name as given."""
+    import jax
+
+    from repro.obs.host import span
+
+    with span("gw.serve", object_id=1):
+        assert 2 + 2 == 4
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with span("gw.serve"):
+        with span("store.crc32", bytes=16384, key=("g0", 0, 1)):
+            pass
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    got = {
+        ev.name.split("#", 1)[0]: (ev.start_ns, ev.start_ns + ev.duration_ns)
+        for plane in data.planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith(("gw.", "store."))
+    }
+    assert set(got) == {"gw.serve", "store.crc32"}
+    assert got["gw.serve"][0] <= got["store.crc32"][0] <= got["store.crc32"][1]
+    assert got["store.crc32"][1] <= got["gw.serve"][1]
+

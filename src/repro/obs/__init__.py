@@ -105,8 +105,8 @@ gives each name the window time in which it is the innermost span.
   ``gw.handoff``        payload assembly and its sha256
   ``fabric.transfer``   NetSimulator.transfer (host cost of the
                         simulated fabric)
-  ``store.crc32``       BlockStore.digest, the one crc32 site, with its
-                        copy to bytes
+  ``store.crc32``       BlockStore.digest, the one crc32 site, with the
+                        wait for its chunks' crc32s on the digest pool
   ``stage.gather``      a ragged launch's zero-fill and gather
   ``stage.h2d``         a ragged launch's host-to-device uploads
   ``kernel.run``        a launch's dispatch up to block_until_ready

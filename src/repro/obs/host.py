@@ -30,7 +30,7 @@ SPANS: dict[str, str] = {
     "gw.decode": "a GET window's decode through the coalescer, as a whole",
     "gw.handoff": "payload assembly and its sha256 (the payload hand-off)",
     "fabric.transfer": "one simulated-fabric transfer's bookkeeping (host cost of the simulation)",
-    "store.crc32": "one crc32 digest of a block, with its copy to bytes (the integrity plane)",
+    "store.crc32": "one crc32 digest of a block, with its chunks' pool work (the integrity plane)",
     "stage.gather": "zero-fill and gather of one ragged launch's staging buffers",
     "stage.h2d": "host-to-device copy of one ragged launch's operands",
     "kernel.run": "dispatch of one kernel launch and the wait for its result",

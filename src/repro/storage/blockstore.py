@@ -29,7 +29,11 @@ situ and the repaired bytes can be checked against the original digest.
 
 from __future__ import annotations
 
+import functools
+import os
+import threading
 import zlib
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -37,6 +41,87 @@ import numpy as np
 from repro.obs.host import span
 
 BlockKey = tuple[str, int, int]  # (group_id, row, col)
+
+# A block of at least two chunks is digested chunk by chunk on a thread
+# pool (zlib.crc32 releases the GIL) and the chunks' crc32s are combined
+# into the whole block's; a smaller block is digested serially in place.
+CRC32_CHUNK_BYTES = 8 << 20
+CRC32_MAX_WORKERS = 8
+_CRC32_POLY = 0xEDB88320  # zlib's crc32 polynomial, bit-reflected
+
+_crc32_pool: ThreadPoolExecutor | None = None
+_crc32_pool_lock = threading.Lock()
+
+
+def _crc32_workers() -> int:
+    return min(CRC32_MAX_WORKERS, os.cpu_count() or 1)
+
+
+def crc32_splits(nbytes: int) -> bool:
+    """Whether ``crc32`` digests ``nbytes`` in chunks on the pool."""
+    return nbytes >= 2 * CRC32_CHUNK_BYTES and _crc32_workers() > 1
+
+
+def _crc32_executor() -> ThreadPoolExecutor:
+    """The process's digest pool, made on first use."""
+    global _crc32_pool
+    with _crc32_pool_lock:
+        if _crc32_pool is None:
+            _crc32_pool = ThreadPoolExecutor(_crc32_workers(), thread_name_prefix="crc32")
+        return _crc32_pool
+
+
+def _gf2_times(mat: tuple[int, ...], vec: int) -> int:
+    """A 32 x 32 GF(2) matrix (one int per column) times a 32-bit vector."""
+    out, i = 0, 0
+    while vec:
+        if vec & 1:
+            out ^= mat[i]
+        vec >>= 1
+        i += 1
+    return out
+
+
+def _gf2_mul(a: tuple[int, ...], b: tuple[int, ...]) -> tuple[int, ...]:
+    return tuple(_gf2_times(a, col) for col in b)
+
+
+@functools.lru_cache(maxsize=16)
+def _crc32_zeros_op(nbytes: int) -> tuple[int, ...]:
+    """The operator that advances a crc32 register over ``nbytes`` zero
+    bytes, built by squaring (zlib's crc32_combine)."""
+    op = (_CRC32_POLY, *(1 << i for i in range(31)))  # one zero bit
+    for _ in range(3):  # one zero byte
+        op = _gf2_mul(op, op)
+    out = tuple(1 << i for i in range(32))
+    while nbytes:
+        if nbytes & 1:
+            out = _gf2_mul(op, out)
+        nbytes >>= 1
+        if nbytes:
+            op = _gf2_mul(op, op)
+    return out
+
+
+def crc32_combine(crc1: int, crc2: int, len2: int) -> int:
+    """crc32 of A + B from crc32(A), crc32(B) and len(B)."""
+    return _gf2_times(_crc32_zeros_op(len2), crc1) ^ crc2
+
+
+def crc32(buf: np.ndarray) -> int:
+    """``zlib.crc32`` of a flat uint8 array, split across the pool when
+    it holds two chunks or more and the host has cores to spare."""
+    n = buf.nbytes
+    if not crc32_splits(n):
+        return zlib.crc32(buf)
+    starts = range(0, n, CRC32_CHUNK_BYTES)
+    crcs = list(
+        _crc32_executor().map(zlib.crc32, (buf[s : s + CRC32_CHUNK_BYTES] for s in starts))
+    )
+    out = crcs[0]
+    for s, c in zip(starts[1:], crcs[1:]):
+        out = crc32_combine(out, c, min(CRC32_CHUNK_BYTES, n - s))
+    return out
 
 
 class PlacementError(RuntimeError):
@@ -52,8 +137,10 @@ class BlockStore:
     failed_nodes: set[int] = field(default_factory=set)
     checksums: dict[BlockKey, int] = field(default_factory=dict)
     # bytes digested since the store was made, cumulative: the integrity
-    # plane's work, read as a difference over a window
+    # plane's work, read as a difference over a window; of those, the
+    # bytes digested in chunks on the pool (crc32's split path)
     crc32_bytes: int = 0
+    crc32_split_bytes: int = 0
     _group_counter: int = 0
 
     # -- failure domains -------------------------------------------------------
@@ -66,12 +153,15 @@ class BlockStore:
 
     # -- integrity -------------------------------------------------------------
     def digest(self, data: np.ndarray) -> int:
-        """crc32c-style content digest of a block's bytes; the one crc32
-        site of the store, counted in ``crc32_bytes``."""
-        data = np.asarray(data)
-        self.crc32_bytes += data.nbytes
-        with span("store.crc32", bytes=data.nbytes):
-            return zlib.crc32(data.tobytes())
+        """zlib crc32 of a block's bytes, read where they lie (only a
+        non-contiguous block is copied, once); the one crc32 site of the
+        store, counted in ``crc32_bytes``."""
+        buf = np.ascontiguousarray(data).reshape(-1).view(np.uint8)
+        self.crc32_bytes += buf.nbytes
+        with span("store.crc32", bytes=buf.nbytes):
+            if crc32_splits(buf.nbytes):
+                self.crc32_split_bytes += buf.nbytes
+            return crc32(buf)
 
     # -- placement -----------------------------------------------------------
     def _place_group(self, group_id: str, rows: int, cols: int) -> None:

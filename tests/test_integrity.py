@@ -12,6 +12,10 @@ from __future__ import annotations
 
 import importlib
 import importlib.util
+import sys
+import threading
+import tracemalloc
+import zlib
 
 import numpy as np
 import pytest
@@ -36,6 +40,7 @@ from repro.scenario import (
     run_scenario,
     trace_from_jsonable,
 )
+from repro.storage import blockstore
 from repro.storage.blockstore import BlockStore
 from repro.storage.netmodel import ClusterProfile, NetSimulator, Transfer
 from repro.storage.repair import Scrubber
@@ -90,6 +95,124 @@ def test_crc32_bytes_counts_exactly_the_digested_bytes():
     assert store.checksum_ok(("gX", 0, 0), np.zeros(1024, np.uint8)) is None
     store.put_block(("g0", 1, 1), np.zeros(512, np.uint8))
     assert store.crc32_bytes == (blocks + 2) * 1024 + 512
+
+
+CHUNK = blockstore.CRC32_CHUNK_BYTES
+# around the serial form's edges, the split path's threshold (two chunks)
+# and a split with an odd tail
+DIGEST_SIZES = [0, 1, CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK - 1, 2 * CHUNK, 2 * CHUNK + 1,
+                3 * CHUNK + 12345]
+
+
+def _random_bytes(n: int, seed: int = 0) -> np.ndarray:
+    return np.random.default_rng(seed + n).integers(0, 256, n, dtype=np.uint8)
+
+
+def _layout(raw: np.ndarray, layout: str):
+    """The bytes of ``raw`` (those of ``want`` below) in ``layout``."""
+    if layout == "uint8":
+        return raw
+    if layout == "uint32":
+        return raw[: raw.size - raw.size % 4].view(np.uint32)
+    if layout == "strided":
+        wide = np.zeros(2 * raw.size, np.uint8)
+        wide[::2] = raw
+        return wide[::2]
+    import jax.numpy as jnp
+
+    return jnp.asarray(raw)
+
+
+@pytest.mark.parametrize("layout", ["uint8", "uint32", "strided", "jax"])
+@pytest.mark.parametrize("n", DIGEST_SIZES)
+def test_digest_is_zlib_crc32_of_the_bytes(n, layout):
+    raw = _random_bytes(n)
+    data = _layout(raw, layout)
+    want = zlib.crc32(np.asarray(data).tobytes())
+    assert BlockStore(num_nodes=3).digest(data) == want
+
+
+@pytest.mark.parametrize("len1,len2", [(0, 0), (5, 0), (0, 7), (1, 1), (4096, 12345), (70000, 3)])
+def test_crc32_combine_joins_two_crc32s(len1, len2):
+    a, b = _random_bytes(len1, 1).tobytes(), _random_bytes(len2, 2).tobytes()
+    assert blockstore.crc32_combine(zlib.crc32(a), zlib.crc32(b), len2) == zlib.crc32(a + b)
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+def test_one_flipped_bit_in_any_chunk_fails_verify(where):
+    n = 3 * CHUNK + 12345
+    pos = {"first": 0, "middle": CHUNK + CHUNK // 2, "last": n - 1}[where]
+    store = BlockStore(num_nodes=3)
+    key = ("g0", 0, 0)
+    store.put_block(key, _random_bytes(n), node=0)
+    assert store.verify(key)
+    flipped = store.blocks[key].copy()
+    flipped[pos] ^= 0x10
+    store.blocks[key] = flipped
+    assert not store.verify(key)
+
+
+def test_crc32_split_bytes_counts_the_split_path_only():
+    store = BlockStore(num_nodes=3)
+    small, big = _random_bytes(16 << 10), _random_bytes(2 * CHUNK)
+    store.digest(small)
+    assert (store.crc32_bytes, store.crc32_split_bytes) == (small.nbytes, 0)
+    store.digest(big)
+    store.digest(big[: 2 * CHUNK - 1])
+    assert store.crc32_bytes == small.nbytes + 4 * CHUNK - 1
+    assert store.crc32_split_bytes == (2 * CHUNK if blockstore.crc32_splits(2 * CHUNK) else 0)
+
+
+def test_one_core_hosts_digest_serially(monkeypatch):
+    monkeypatch.setattr(blockstore.os, "cpu_count", lambda: 1)
+    store = BlockStore(num_nodes=3)
+    big = _random_bytes(3 * CHUNK + 12345)
+    assert store.digest(big) == zlib.crc32(big.tobytes())
+    assert (store.crc32_bytes, store.crc32_split_bytes) == (big.nbytes, 0)
+
+
+def test_concurrent_first_digests_share_one_pool(monkeypatch):
+    """More callers than cores, with frequent thread switches, all at the
+    pool's first use: one pool is made and every digest is right."""
+    monkeypatch.setattr(blockstore, "_crc32_pool", None)
+    monkeypatch.setattr(blockstore.os, "cpu_count", lambda: 8)
+    blocks = [_random_bytes(2 * CHUNK + i, seed=i) for i in range(3)]
+    want = [zlib.crc32(b) for b in blocks]
+    got, pools = {}, set()
+
+    def digest(i):
+        got[i] = blockstore.crc32(blocks[i % 3])
+        pools.add(id(blockstore._crc32_pool))
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=digest, args=(i,)) for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    try:
+        assert not any(t.is_alive() for t in threads)
+        assert got == {i: want[i % 3] for i in range(16)}
+        assert len(pools) == 1
+    finally:
+        blockstore._crc32_pool.shutdown()
+
+
+def test_digest_of_a_contiguous_block_copies_nothing():
+    store = BlockStore(num_nodes=3)
+    block = _random_bytes(32 << 20)
+    store.digest(block)  # makes the pool and the combine operator before the count
+    tracemalloc.start()
+    try:
+        store.digest(block)
+        _now, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < CHUNK // 16
 
 
 def test_corrupt_block_modes_break_verify_but_not_checksum():

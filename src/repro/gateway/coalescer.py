@@ -135,6 +135,10 @@ class CoalescerStats:
     ops_by_kind: dict[str, int] = field(default_factory=dict)
     launches_by_kind: dict[str, int] = field(default_factory=dict)
     sources_by_kind: dict[str, int] = field(default_factory=dict)
+    # decode ops by plan kind (DecodeOp.plan: "local" XOR, "global"
+    # GF(256) row decode): blocks they rebuilt, source blocks they read
+    rebuilt_by_plan: dict[str, int] = field(default_factory=dict)
+    read_by_plan: dict[str, int] = field(default_factory=dict)
     jit_entries: int = 0  # LIVE traced kernel signatures (see below)
     jit_retraces: int = 0  # every trace ever taken (compile churn)
     decode_shapes: int = 0  # distinct decode shape_keys ever executed
@@ -164,13 +168,25 @@ class CoalescerStats:
     def record_launch(self, kind: str) -> None:
         self.launches_by_kind[kind] = self.launches_by_kind.get(kind, 0) + 1
 
+    def record_ops(self, kind: str, ops: list[DecodeOp]) -> None:
+        """Count one launch set's ops of ``kind``; decode ops also by plan."""
+        sources = sum(len(op.sources) for op in ops)
+        self.ops_by_kind[kind] = self.ops_by_kind.get(kind, 0) + len(ops)
+        self.sources_by_kind[kind] = self.sources_by_kind.get(kind, 0) + sources
+        if ops and not kind.startswith("E"):
+            plan = ops[0].plan
+            rebuilt = sum(len(op.targets) for op in ops)
+            self.rebuilt_by_plan[plan] = self.rebuilt_by_plan.get(plan, 0) + rebuilt
+            self.read_by_plan[plan] = self.read_by_plan.get(plan, 0) + sources
+
     def record_batch(self, n_ops: int) -> None:
         self.batch_hist[n_ops] = self.batch_hist.get(n_ops, 0) + 1
         self.max_batch = max(self.max_batch, n_ops)
 
     def sources_per_op(self, kind: str) -> float:
         """Mean source blocks per reconstruction of this kind — the
-        paper's Table 1 costs: exactly t for "V", exactly k for "H"."""
+        paper's Table 1 costs: exactly t for a CORE "V" (a local group's
+        other members for an LRC one), exactly k for "H"."""
         n = self.ops_by_kind.get(kind, 0)
         return self.sources_by_kind.get(kind, 0) / n if n else 0.0
 
@@ -399,12 +415,7 @@ class DecodeCoalescer:
             self.stats.encode_ops += len(idxs)
         else:
             self.stats.decode_ops += len(idxs)
-        self.stats.ops_by_kind[kind] = (
-            self.stats.ops_by_kind.get(kind, 0) + len(idxs)
-        )
-        self.stats.sources_by_kind[kind] = self.stats.sources_by_kind.get(
-            kind, 0
-        ) + sum(len(decode_ops[j].sources) for j in idxs)
+        self.stats.record_ops(kind, [decode_ops[j] for j in idxs])
 
     def _buffer(self, key: tuple, shape: tuple) -> np.ndarray:
         """Preallocated staging buffer, reused across windows; replaced
@@ -584,9 +595,4 @@ class DecodeCoalescer:
         self.stats.decode_ops += len(idxs)
         self.stats.padded_ops += b_pad - len(idxs)
         self.stats.record_batch(len(idxs))
-        self.stats.ops_by_kind[kind] = (
-            self.stats.ops_by_kind.get(kind, 0) + len(idxs)
-        )
-        self.stats.sources_by_kind[kind] = self.stats.sources_by_kind.get(
-            kind, 0
-        ) + sum(len(decode_ops[i].sources) for i in idxs)
+        self.stats.record_ops(kind, [decode_ops[i] for i in idxs])

@@ -293,8 +293,9 @@ class GatewayConfig:
     record_requests: bool = True
     # -- code family (per-namespace property) ----------------------------------
     # "core" (the (n,k,t) product code, default), "rs" (plain (n,k)
-    # Reed-Solomon rows — the paper's traditional-EC baseline), or "lrc"
-    # ((n,k) Azure-style Local Reconstruction Code rows). RS/LRC derive
+    # Reed-Solomon rows — the paper's traditional-EC baseline), "lrc"
+    # ((n,k) Azure-style Local Reconstruction Code rows) or "xorbas"
+    # ((n,k) HDFS-Xorbas LRC rows, 1301.3791). RS/LRC derive
     # (n,k) from the gateway's CoreCode so all families stripe the same
     # row geometry; planner candidates, repair plans, PUT re-encode, and
     # the durability audit all go through repro.gateway.planner.CodeFamily.
@@ -1484,7 +1485,7 @@ class ObjectGateway:
                     uops.append(op)
                     owners.append([])
                 owners[j].append(i)
-        with span("gw.decode"):
+        with span("gw.decode", plan="+".join(sorted({op.plan for op in uops}))):
             results, units = self.coalescer.execute(uops, lambda k: fetched[k])
         if verify_ck:
             # end-to-end integrity: a reconstruction must reproduce the

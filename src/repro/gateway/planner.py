@@ -50,6 +50,12 @@ class DecodeOp:
         batched kernel launch."""
         return (self.kind, len(self.targets), len(self.sources))
 
+    @property
+    def plan(self) -> str:
+        """Plan kind: "local" (an XOR over a CORE column or a local
+        group) or "global" (a GF(256) row decode)."""
+        return "local" if self.kind == "V" else "global"
+
 
 @dataclass(frozen=True)
 class ReadPlan:
@@ -450,30 +456,28 @@ class RSFamily(RowCodeFamily):
 
 
 class LRCFamily(RowCodeFamily):
-    """(n, k) Azure-style Local Reconstruction Code (coding/lrc.py).
+    """(n, k) Local Reconstruction Code (coding/lrc.py): Azure-style
+    ("lrc") or HDFS-Xorbas ("xorbas").
 
-    Single-block loss inside a local group repairs from the k/2
-    surviving group members by plain XOR (a "V" uop — the coalescer's
-    XOR path takes any source count); multi-loss patterns fall back to
-    one global "H" decode over >= k independent survivors."""
+    A block that is the only loss of one of its code's local groups
+    repairs from the group's other members by plain XOR (a "V" uop: the
+    coalescer's XOR path takes any source count), parities included;
+    other patterns fall back to one global "H" decode over >= k
+    independent survivors."""
 
-    name = "lrc"
-
-    def __init__(self, n: int, k: int):
-        super().__init__(lrc_mod.make_lrc(n, k))
+    def __init__(self, code: lrc_mod.LRC, name: str):
+        super().__init__(code)
+        self.name = name
 
     @property
     def tolerance(self) -> int:
-        # d = n - k: any n-k-1 erasures decode (many n-k patterns do
-        # too, but admission bounds on the guarantee).
-        return self.n - self.k - 1
+        # every pattern of this many erasures decodes (computed from the
+        # generator, once per code); admission bounds on the guarantee
+        return self.code.tolerance
 
     def single_repair_cost(self, col: int) -> int:
-        return self.k // 2 if self.code.local_group(col) is not None else self.k
-
-    @property
-    def avg_repair_cost(self) -> float:
-        return lrc_mod.avg_single_repair_cost(self.n, self.k)
+        local = self.code.local_cost(col)
+        return self.k if local is None else local
 
     def repair_plan(self, failed):
         return self.code.repair_plan(set(failed))
@@ -493,43 +497,46 @@ class LRCFamily(RowCodeFamily):
         plans.extend(
             super()._degraded_plans(available, group_id, row, direct, missing, at)
         )
-        # Order by traffic: local XOR costs k/2 per missing block, the
-        # global decode k for the whole row. Prefer local on ties.
+        # Order by traffic: a local XOR reads its group's other members
+        # per missing block, the global decode k for the whole row.
+        # Prefer local on ties.
         plans.sort(key=lambda p: p.reconstruction_blocks)
         return plans
 
     def _local_op(self, available, group_id, row, col) -> DecodeOp | None:
-        grp = self.code.local_group(col)
-        if grp is None:
-            return None
-        sources = [g for g in grp if g != col]
-        if not all(available((group_id, row, g)) for g in sources):
-            return None
-        return DecodeOp(
-            "V",
-            group_id,
-            row,
-            (col,),
-            tuple((group_id, row, g) for g in sources),
-            None,
-        )
+        """XOR over the other members of the smallest of col's local
+        groups whose other members are all available."""
+        for grp in sorted(self.code.local_groups(col), key=len):
+            sources = [g for g in grp if g != col]
+            if all(available((group_id, row, g)) for g in sources):
+                return DecodeOp(
+                    "V",
+                    group_id,
+                    row,
+                    (col,),
+                    tuple((group_id, row, g) for g in sources),
+                    None,
+                )
+        return None
 
 
-FAMILY_NAMES = ("core", "rs", "lrc")
+FAMILY_NAMES = ("core", "rs", "lrc", "xorbas")
 
 
 def make_family(code: CoreCode, name: str = "core") -> CodeFamily:
     """Build the named family on the shared (n, k) geometry of ``code``.
 
-    RS and LRC derive (n, k) from the CORE parameters so all three
-    families stripe the same row shape — the bake-off comparison and the
+    RS and the LRCs derive (n, k) from the CORE parameters so every
+    family stripes the same row shape — the bake-off comparison and the
     GatewayConfig plumbing both key off one CoreCode."""
     if name == "core":
         return CoreFamily(code)
     if name == "rs":
         return RSFamily(code.n, code.k)
     if name == "lrc":
-        return LRCFamily(code.n, code.k)
+        return LRCFamily(lrc_mod.make_lrc(code.n, code.k), name)
+    if name == "xorbas":
+        return LRCFamily(lrc_mod.make_xorbas(code.n, code.k), name)
     raise ValueError(f"unknown code family {name!r} (want one of {FAMILY_NAMES})")
 
 

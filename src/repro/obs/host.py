@@ -27,7 +27,7 @@ SPANS: dict[str, str] = {
     "gw.serve": "ObjectGateway.serve, the whole event loop; self time is the loop's own Python",
     "gw.plan": "a GET window's planning and SLO admission",
     "gw.fetch": "a GET window's store reads, replans and hedges",
-    "gw.decode": "a GET window's decode through the coalescer, as a whole",
+    "gw.decode": "a GET window's decode through the coalescer, as a whole; attr plan: its ops' plan kinds",
     "gw.handoff": "payload assembly and its sha256 (the payload hand-off)",
     "fabric.transfer": "one simulated-fabric transfer's bookkeeping (host cost of the simulation)",
     "store.crc32": "one crc32 digest of a block, with its chunks' pool work (the integrity plane)",

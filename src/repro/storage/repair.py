@@ -23,7 +23,7 @@ math and scaled by the cluster profile.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Callable
 
 import jax
@@ -50,10 +50,23 @@ class RepairReport:
     compute_time: float = 0.0
     schedule: str = ""
     recovered: bool = True
+    # by plan kind, "local" (XOR of a CORE column or an LRC local group)
+    # or "global" (a GF(256) decode): blocks rebuilt, source blocks read
+    rebuilt_by_plan: dict[str, int] = field(default_factory=dict)
+    read_by_plan: dict[str, int] = field(default_factory=dict)
 
     @property
     def total_time(self) -> float:
         return self.network_time + self.compute_time
+
+    def record(self, plan: str, blocks: np.ndarray, rebuilt: int) -> None:
+        """Count one repair step: its stacked source ``blocks`` read and
+        ``rebuilt`` blocks written back, under ``plan``."""
+        self.blocks_fetched += len(blocks)
+        self.bytes_fetched += int(blocks.nbytes)
+        self.blocks_repaired += rebuilt
+        self.rebuilt_by_plan[plan] = self.rebuilt_by_plan.get(plan, 0) + rebuilt
+        self.read_by_plan[plan] = self.read_by_plan.get(plan, 0) + len(blocks)
 
 
 class UnrecoverableError(RuntimeError):
@@ -199,9 +212,9 @@ class BlockFixer:
     tracer: object = None
     trace_ctx: tuple | None = None
     # Code family (repro.gateway.planner.CodeFamily). None or a "core"
-    # family keeps the product-code modes above; a row family ("rs" /
-    # "lrc") repairs through the family's repair_plan — LRC local steps
-    # fetch ONLY the local group (k/2 survivors), not k blocks.
+    # family keeps the product-code modes above; a row family ("rs",
+    # "lrc", "xorbas") repairs through the family's repair_plan — an LRC
+    # local step fetches ONLY the other members of a local group.
     family: object = None
 
     def __post_init__(self):
@@ -274,13 +287,15 @@ class BlockFixer:
             return self._fix_core(group_id, rows, cols)
         return self._fix_raid(group_id, rows, cols, optimized=self.mode == "hdfs_raid_opt")
 
-    # -- row-family mode (rs / lrc via CodeFamily.repair_plan) -----------------
+    # -- row-family mode (rs / lrc / xorbas via CodeFamily.repair_plan) -------
     def _fix_family(self, group_id: str) -> RepairReport:
         """Repair the group's single codeword row through the family's
-        repair plan. LRC 'local' steps fetch ONLY the k/2 surviving
-        members of the broken local group and XOR them — the locality
-        win the bake-off bench measures against the RS baseline, whose
-        every repair is a 'global' k-source GF(256) decode."""
+        repair plan. An LRC 'local' step fetches ONLY the other members
+        of a local group holding one loss (data or parity blocks alike:
+        Xorbas's implied group is the RS parities and both local
+        parities) and XORs them on ``_xor_jit`` — the locality win the
+        bake-off bench measures against the RS baseline, whose every
+        repair is a 'global' k-source GF(256) decode."""
         fam = self.family
         report = RepairReport(mode=fam.name)
         cols = self.code.n
@@ -343,9 +358,7 @@ class BlockFixer:
                             priority=self.priority, ctx=ctx,
                         )
                     )
-            report.blocks_fetched += len(sources)
-            report.bytes_fetched += int(blocks.nbytes)
-            report.blocks_repaired += len(repaired)
+            report.record(kind, blocks, len(repaired))
             descs.append(f"{'L' if kind == 'local' else 'G'}x{len(repaired)}")
         report.network_time = self._net_time(sim)
         report.compute_time = self._timed
@@ -423,9 +436,7 @@ class BlockFixer:
                     repaired_cells.add(c)
                     if self.on_block_repaired is not None:
                         self.on_block_repaired((group_id, r, c))
-                report.blocks_fetched += len(fetch_cols)
-                report.bytes_fetched += sum(b.nbytes for b in blocks)
-                report.blocks_repaired += len(batch)
+                report.record("global", blocks, len(batch))
                 sched_desc.append(f"H{r}x{len(batch)}")
         report.network_time = self._net_time(sim)
         report.compute_time = self._timed
@@ -543,9 +554,9 @@ class BlockFixer:
                         priority=self.priority, ctx=ctx,
                     )
                 )
-        report.blocks_fetched += len(srcs)
-        report.bytes_fetched += int(blocks.nbytes)
-        report.blocks_repaired += len(step.repairs)
+        report.record(
+            "local" if step.kind == "V" else "global", blocks, len(step.repairs)
+        )
 
     # -- degraded read -------------------------------------------------------------
     def degraded_read(self, group_id: str, row: int) -> tuple[np.ndarray, RepairReport]:

@@ -53,12 +53,13 @@ def test_family_geometry():
     core = make_family(CODE, "core")
     rs = make_family(CODE, "rs")
     lrc = make_family(CODE, "lrc")
+    xorbas = make_family(CODE, "xorbas")
     assert (core.rows, core.n, core.k) == (CODE.t + 1, CODE.n, CODE.k)
-    for fam in (rs, lrc):
+    for fam in (rs, lrc, xorbas):
         assert (fam.rows, fam.n, fam.k) == (1, CODE.n, CODE.k)
         assert fam.objects_per_group == 1
     assert core.objects_per_group == CODE.t
-    assert set(FAMILY_NAMES) == {"core", "rs", "lrc"}
+    assert set(FAMILY_NAMES) == {"core", "rs", "lrc", "xorbas"}
     with pytest.raises(ValueError):
         make_family(CODE, "raptor")
 
@@ -172,7 +173,7 @@ def _serve_degraded(fam: str) -> dict[int, str]:
 
 def test_degraded_byte_identity_across_families():
     digests = {fam: _serve_degraded(fam) for fam in FAMILY_NAMES}
-    assert digests["core"] == digests["rs"] == digests["lrc"]
+    assert digests["core"] == digests["rs"] == digests["lrc"] == digests["xorbas"]
 
 
 # -- failure inter-arrival laws (1309.0186) --------------------------------

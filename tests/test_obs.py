@@ -498,3 +498,70 @@ def test_wall_clock_spans_record_only_under_a_trace(tmp_path):
     assert got["gw.serve"][0] <= got["store.crc32"][0] <= got["store.crc32"][1]
     assert got["store.crc32"][1] <= got["gw.serve"][1]
 
+
+
+def _xorbas_gateway() -> tuple[ObjectGateway, list]:
+    """HDFS-Xorbas LRC(16, 10) rows: object 0 misses one data block (a
+    local group rebuilds it), object 1 two of one group (a global
+    decode rebuilds both), object 2 none."""
+    gw = ObjectGateway(
+        CoreCode(16, 10, 1),
+        ClusterProfile.network_critical(),
+        40,
+        GatewayConfig(code_family="xorbas", record_payloads=True),
+    )
+    gw.load_objects(
+        np.random.default_rng(4).integers(0, 256, (3, 10, 1024), dtype=np.uint8)
+    )
+    keys = [(*gw._objects[0], 3), (*gw._objects[1], 6), (*gw._objects[1], 8)]
+    for key in keys:
+        gw.store.drop_block(key)
+    return gw, keys
+
+
+def test_plan_kind_counters_count_a_scripted_trace():
+    """The GET path's CoalescerStats and the repair path's RepairReport
+    count each reconstructed block, and the source blocks its step read,
+    under its plan kind."""
+    from repro.gateway import Request
+
+    gw, _keys = _xorbas_gateway()
+    report = gw.serve(
+        [Request(time=0.1 * (i + 1), object_id=i) for i in (0, 1, 2)], []
+    )
+    assert [r.degraded for r in report.records] == [True, True, False]
+    st = gw.coalescer.stats
+    assert st.rebuilt_by_plan == {"local": 1, "global": 2}
+    assert st.read_by_plan == {"local": 5, "global": 10}
+    local = gw.fixer.fix_group(gw._objects[0][0])
+    assert (local.rebuilt_by_plan, local.read_by_plan) == ({"local": 1}, {"local": 5})
+    both = gw.fixer.fix_group(gw._objects[1][0])
+    assert (both.rebuilt_by_plan, both.read_by_plan) == ({"global": 2}, {"global": 10})
+    assert both.blocks_repaired == 2 and both.blocks_fetched == 10
+
+
+def test_decode_span_carries_its_plan_kind_only_under_a_trace(tmp_path):
+    """``gw.decode`` holds its window's plan kinds as the attr ``plan``,
+    recorded like every wall-clock span only while a trace is active:
+    the local GET served before the trace leaves nothing in it."""
+    import jax
+
+    from repro.gateway import Request
+
+    gw, _keys = _xorbas_gateway()
+    gw.serve([Request(time=0.1, object_id=0)], [])
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    gw.serve([Request(time=0.2, object_id=1)], [])
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    plans = [
+        dict(ev.stats).get("plan")
+        for plane in data.planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "gw.decode"
+    ]
+    assert plans == ["global"]

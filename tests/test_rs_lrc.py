@@ -8,7 +8,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.coding import lrc, rs
+from repro.coding import gf256, lrc, rs
 from repro.coding.linear import rank_gf256
 
 
@@ -140,3 +140,80 @@ def test_lrc_repair_plan_executes_correctly():
                 store[t] = full[t]
     for i in range(10):
         np.testing.assert_array_equal(store[i], cw[i])
+
+
+# ---------------------------------------------------------------------------
+# HDFS-Xorbas LRC(16, 10) and the computed tolerance of every LRC
+# ---------------------------------------------------------------------------
+
+
+def test_xorbas_rs_parities_sum_to_the_implied_parity():
+    assert lrc.cyclic_generator_poly(4) == (85, 120, 36, 8, 1)
+    gen = lrc.xorbas_generator(16, 10)
+    np.testing.assert_array_equal(gen[:10], np.eye(10, dtype=np.uint8))
+    # g(1) = 0, so P1 + .. + P4 = X1 + .. + X10 = S1 + S2
+    np.testing.assert_array_equal(
+        np.bitwise_xor.reduce(gen[10:14], axis=0), np.ones(10, dtype=np.uint8)
+    )
+    np.testing.assert_array_equal(gen[14], [1] * 5 + [0] * 5)
+    np.testing.assert_array_equal(gen[15], [0] * 5 + [1] * 5)
+
+
+def test_xorbas_rs_part_is_mds():
+    gen = lrc.xorbas_generator(16, 10)[:14]
+    for subset in itertools.combinations(range(14), 10):
+        assert rank_gf256(gen[list(subset)]) == 10, subset
+
+
+def test_xorbas_every_four_losses_decode_and_tolerance_is_four():
+    code = lrc.make_xorbas(16, 10)
+    for lost in itertools.combinations(range(16), 4):
+        assert code.decodable(np.setdiff1d(np.arange(16), lost)), lost
+    assert code.tolerance == 4
+    # distance 5: some five losses do not decode
+    assert not code.decodable(np.setdiff1d(np.arange(16), [0, 1, 2, 3, 4]))
+
+
+def test_xorbas_every_block_has_a_five_source_local_group():
+    code = lrc.make_xorbas(16, 10)
+    for i in range(16):
+        assert code.local_cost(i) == 5, i
+        plan = code.repair_plan({i})
+        assert [(kind, len(src), rep) for kind, src, rep in plan] == [("local", 5, [i])]
+    # an RS parity's group is the implied parity's: the other P's, S1, S2
+    ((_kind, sources, _rep),) = code.repair_plan({12})
+    assert sorted(sources) == [10, 11, 13, 14, 15]
+
+
+def test_azure_lrc_16_10_tolerates_four_not_n_minus_k_minus_one():
+    code = lrc.make_lrc(16, 10)
+    assert code.tolerance == 4
+    undecodable = [
+        lost
+        for lost in itertools.combinations(range(16), 5)
+        if not code.decodable(np.setdiff1d(np.arange(16), lost))
+    ]
+    assert len(undecodable) == 1, undecodable
+
+
+@pytest.mark.parametrize(
+    "make,n,k",
+    [
+        (lrc.make_lrc, 9, 6),
+        (lrc.make_lrc, 10, 6),
+        (lrc.make_lrc, 16, 10),
+        (lrc.make_xorbas, 9, 6),
+        (lrc.make_xorbas, 16, 10),
+    ],
+)
+def test_every_pattern_up_to_tolerance_decodes(make, n, k):
+    """Decode real data from every erasure pattern up to the code's
+    tolerance, through the code's own solver."""
+    code = make(n, k)
+    data = np.random.default_rng(n * k).integers(0, 256, size=(k, 8), dtype=np.uint8)
+    stripe = np.asarray(code.encode(jnp.asarray(data)))
+    for e in range(1, code.tolerance + 1):
+        for lost in itertools.combinations(range(n), e):
+            avail = np.setdiff1d(np.arange(n), lost)
+            rows, inv = code.decode_matrix(avail)
+            np.testing.assert_array_equal(gf256.np_matmul(inv, stripe[rows]), data)

@@ -78,10 +78,26 @@ def _compile_ragged(one_chip, entry, c, kk, tn) -> int:
     return tb
 
 
-@pytest.mark.parametrize("tn", [4096, 65536])
-@pytest.mark.parametrize("kk", [3, 6])
-@pytest.mark.parametrize("c", [CHUNK_SMALL, CHUNK_BIG])
-@pytest.mark.parametrize("entry", sorted(ENTRIES))
+# Source counts: every entry at CORE's t = 3 and RS(9,6)'s k = 6; the
+# XOR decode at HDFS-Xorbas's local groups of 5 and the GF(256) decode
+# at its k = 10 global fallback.
+RAGGED_CASES = [
+    (entry, c, kk, tn)
+    for entry in sorted(ENTRIES)
+    for c in (CHUNK_SMALL, CHUNK_BIG)
+    for kk in (3, 6)
+    for tn in (4096, 65536)
+] + [
+    (entry, c, kk, tn)
+    for entry, kk in (("decode_xor", 5), ("decode_gf", 10))
+    for c in (CHUNK_SMALL, CHUNK_BIG)
+    for tn in (4096, 65536)
+]
+
+
+@pytest.mark.parametrize(
+    "entry, c, kk, tn", RAGGED_CASES, ids=["-".join(map(str, case)) for case in RAGGED_CASES]
+)
 def test_ragged_entry_compiles_for_v5e(one_chip, entry, c, kk, tn):
     _compile_ragged(one_chip, entry, c, kk, tn)
 

@@ -552,6 +552,15 @@ class GatewayReport:
         return out
 
 
+def _payload_digest(blocks) -> str:
+    """sha256 of a payload, streamed over its C-contiguous blocks in
+    order: the digest of their concatenation, read where they lie."""
+    h = hashlib.sha256()
+    for blk in blocks:
+        h.update(blk)
+    return h.hexdigest()
+
+
 class EnginePool:
     """``num_engines`` parallel simulated decode-engine timelines with
     least-loaded dispatch and per-tenant weighted admission.
@@ -951,6 +960,11 @@ class ObjectGateway:
         # per-tile modeled billing history (admission estimator input)
         self._pt_tiles = 0
         self._pt_launches = 0
+        # payload bytes handed off (to verify or the digest) since the
+        # gateway was made, cumulative; of those, the bytes copied to
+        # make a block contiguous
+        self.handoff_bytes = 0
+        self.handoff_copied_bytes = 0
 
     # -- scale-out plumbing ----------------------------------------------------
     @property
@@ -1638,12 +1652,14 @@ class ObjectGateway:
             digest = None
             if self.config.verify or self.config.record_payloads:
                 with span("gw.handoff", object_id=req.object_id):
-                    payload = self._assemble_payload(req, plan, fetched, decoded_per_req[i])
+                    payload = self._handoff_blocks(
+                        self._assemble_payload(req, plan, fetched, decoded_per_req[i])
+                    )
                     if self.config.verify:
                         self._verify_get(req, payload)
                         report.metrics.counter("verified_gets").inc()
                     if self.config.record_payloads:
-                        digest = hashlib.sha256(payload.tobytes()).hexdigest()
+                        digest = _payload_digest(payload)
             if self.cache is not None:
                 gid, row = self._objects[req.object_id]
                 costs = decode_cost.get(i, {})
@@ -3226,8 +3242,9 @@ class ObjectGateway:
         base = (self.shard_id or 0) * self.config.num_client_ports
         return -(1 + base + h % self.config.num_client_ports)
 
-    def _assemble_payload(self, req, plan, fetched, decoded) -> np.ndarray:
-        """The GET's (k, q) payload: direct blocks + reconstructions."""
+    def _assemble_payload(self, req, plan, fetched, decoded) -> list[np.ndarray]:
+        """The GET's payload as its k blocks in column order: the fetched
+        arrays and the decoded rows themselves, never a stacked copy."""
         gid, row = self._objects[req.object_id]
         got = []
         for c in range(self.code.k):
@@ -3236,11 +3253,28 @@ class ObjectGateway:
                 got.append(fetched[key])
             else:
                 got.append(decoded[c])
-        return np.stack(got)
+        return got
 
-    def _verify_get(self, req, payload: np.ndarray) -> None:
+    def _handoff_blocks(self, payload) -> list[np.ndarray]:
+        """The payload's blocks in order, each C-contiguous where it lies
+        (a sequence of blocks, or a 2-D array's rows): only a block that
+        is not contiguous is copied. Counted in ``handoff_bytes`` and
+        ``handoff_copied_bytes``."""
+        blocks = []
+        for blk in payload:
+            blk = np.asarray(blk)
+            if not blk.flags.c_contiguous:
+                blk = np.ascontiguousarray(blk)
+                self.handoff_copied_bytes += blk.nbytes
+            self.handoff_bytes += blk.nbytes
+            blocks.append(blk)
+        return blocks
+
+    def _verify_get(self, req, payload: list[np.ndarray]) -> None:
         want = self._expected[req.object_id]
-        if not np.array_equal(payload, want):
+        if len(payload) != len(want) or not all(
+            np.array_equal(got, w) for got, w in zip(payload, want)
+        ):
             raise AssertionError(
                 f"GET integrity failure for object {req.object_id}"
             )

@@ -102,7 +102,8 @@ gives each name the window time in which it is the innermost span.
   ``gw.fetch``          a GET window's fetch/replan loop (store reads,
                         replans, hedging)
   ``gw.decode``         the window's decode, around coalescer.execute
-  ``gw.handoff``        payload assembly and its sha256
+  ``gw.handoff``        the payload's k blocks, in place, to verify
+                        and its sha256
   ``fabric.transfer``   NetSimulator.transfer (host cost of the
                         simulated fabric)
   ``store.crc32``       BlockStore.digest, the one crc32 site, with the

@@ -28,7 +28,7 @@ SPANS: dict[str, str] = {
     "gw.plan": "a GET window's planning and SLO admission",
     "gw.fetch": "a GET window's store reads, replans and hedges",
     "gw.decode": "a GET window's decode through the coalescer, as a whole; attr plan: its ops' plan kinds",
-    "gw.handoff": "payload assembly and its sha256 (the payload hand-off)",
+    "gw.handoff": "the payload hand-off: its k blocks, read in place, to verify and its sha256",
     "fabric.transfer": "one simulated-fabric transfer's bookkeeping (host cost of the simulation)",
     "store.crc32": "one crc32 digest of a block, with its chunks' pool work (the integrity plane)",
     "stage.gather": "zero-fill and gather of one ragged launch's staging buffers",

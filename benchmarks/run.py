@@ -1,12 +1,11 @@
-"""Benchmark driver: one module per paper figure/table (+ kernels and the
-serving gateway).
+"""Paper-figure runner: one module per figure/table of the paper, whose
+results are analytical (core/analysis.py), not clock readings.
 
-``PYTHONPATH=src python -m benchmarks.run [--full|--fast] [--only fig12,...]``
+``PYTHONPATH=src python -m benchmarks.run [--full] [--only fig12,...]``
 
 Prints every row as CSV-ish dicts, then the paper-claim validation
 summary (PASS/FAIL per headline claim). --full uses paper-scale sample
-counts (slow on 1 CPU); --fast runs only the quick smoke set
-(gateway_load + kernels) for the perf trajectory.
+counts (slow on 1 CPU). The chip benchmark lives in benchmarks/chip/.
 """
 
 from __future__ import annotations
@@ -25,11 +24,7 @@ MODULES = [
     "scheduling",        # Fig 11 + Table 1
     "repair_e2e",        # Fig 12
     "scheduling_e2e",    # Fig 13
-    "kernels",           # Pallas kernels
-    "gateway_load",      # serving gateway (throughput / latency / coalescing)
 ]
-
-FAST_MODULES = ["gateway_load", "kernels"]
 
 
 def describe_device() -> str:
@@ -45,17 +40,10 @@ def describe_device() -> str:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale sample counts")
-    ap.add_argument("--fast", action="store_true",
-                    help="quick smoke set only (gateway_load + kernels)")
     ap.add_argument("--only", default=None, help="comma-separated module list")
     args = ap.parse_args()
 
-    if args.only:
-        mods = args.only.split(",")
-    elif args.fast:
-        mods = FAST_MODULES
-    else:
-        mods = MODULES
+    mods = args.only.split(",") if args.only else MODULES
     from repro.kernels.backend import enable_compile_cache
 
     print(f"device: {describe_device()}")
@@ -70,11 +58,6 @@ def main() -> int:
         print(f"\n=== benchmarks.{name} ({dt:.1f}s) " + "=" * 40)
         for r in rows:
             print(",".join(f"{k}={v}" for k, v in r.items()))
-        if hasattr(mod, "write_bench"):
-            # machine-readable perf snapshot (BENCH_<name>.json) so the
-            # trajectory is tracked across PRs
-            mod.write_bench(rows)
-            print(f"wrote {mod.BENCH_PATH}")
         if hasattr(mod, "check"):
             msgs = mod.check(rows)
             all_checks.extend(msgs)

@@ -5,8 +5,8 @@ a Zipf/Poisson request trace is planned per-request against the live
 failure set (vertical XOR at t blocks vs horizontal RS at k — the
 paper's Table 1), and each window's reconstructions — however mixed
 their shapes — are staged as fixed-width descriptor tiles and decoded
-by the ragged MEGAKERNEL (GatewayConfig.coalesce="ragged", the
-default): one descriptor-driven Pallas launch set per kind, <= 2 live
+by the ragged MEGAKERNEL: one descriptor-driven Pallas launch set per
+kind, <= 2 live
 jit signatures per kind, tile widths autotuned per backend and the
 winners persisted across processes. A small rebuild-cost-aware cache
 absorbs hot reconstructions, and background repair contends with
@@ -59,12 +59,11 @@ RS at k, LRC local groups at k/2), repair time, degraded p99, storage
 overhead, and the CORE-vs-RS repair ratio the paper claims at ~0.5x.
 
 Write dataplane (--writes): mixed read/write churn — full-row
-overwrite PUTs, small sealed PUTs, deletes — served twice through the
-same trace: once with write_coalesce="sync" (one billed encode launch
-pair per PUT) and once with "ragged" (the window's RS parity
-generations in ONE ragged EH launch, its vertical-parity XOR-delta
-folds in ONE EV launch, both billed on the shared engine pool before
-any client transfer starts). The demo prints PUT throughput/latency,
+overwrite PUTs, small sealed PUTs, deletes — served through the ragged
+ENCODE megakernel (each window's RS parity generations in ONE ragged EH
+launch, its vertical-parity XOR-delta folds in ONE EV launch, both
+billed on the shared engine pool before any client transfer starts).
+The demo prints PUT throughput/latency,
 encode launch counts and live jit signatures per kind, stripe-sealing
 volume, and both end-to-end consistency audits (zero stale parity,
 every sealed extent byte-identical).
@@ -113,7 +112,7 @@ find its ``request`` span, then look at whichever child ends last:
 that dependency (a queued ``fetch``, a shared ``engine.launch``, a
 paced repair transfer in the way) is the critical path — the same
 decomposition ``repro.obs.critical_path`` computes, whose fleet-level
-stage shares the gateway_obs benchmark reports.
+stage shares ``repro.obs.stage_shares`` reports.
 
     PYTHONPATH=src python examples/gateway_serving.py
     PYTHONPATH=src python examples/gateway_serving.py --tenants
@@ -271,7 +270,7 @@ def main_tenants():
 def main_scenario():
     """Fault-injection demo: the canonical correlated-failure + surge
     scenario (repro.scenario.correlated_surge_setup — the same setup the
-    benchmark gate and regression test validate), replayed with fixed
+    regression test validates), replayed with fixed
     full-weight repair and with SLO-paced repair, plus a flapping node
     after the surge. The repair backlog (one rack's worth of every
     group) is far too large to finish inside the surge even at full
@@ -322,8 +321,7 @@ def main_scenario():
 def main_graybox():
     """Gray-failure demo: hedged degraded reads racing a fail-slow node,
     then a corruption + fail-slow + crash scenario exercising the
-    corruption-as-erasure integrity plane end to end (the same two
-    setups the gateway_integrity benchmark rows gate)."""
+    corruption-as-erasure integrity plane end to end."""
     code = CoreCode(9, 6, 3)
     q, num_objects = 4096, 30
 
@@ -445,8 +443,7 @@ def main_graybox():
 
 def main_bakeoff():
     """Code-family bake-off demo: RS vs CORE vs LRC through the same
-    gateway, objects, workload, and Weibull fault trace (the same setup
-    the gateway_bakeoff benchmark block gates)."""
+    gateway, objects, workload, and Weibull fault trace."""
     code = CoreCode(9, 6, 3)  # even k, n >= k+2: valid for all 3 families
     q, num_objects, num_nodes = 4096, 30, 60
 
@@ -502,10 +499,9 @@ def main_bakeoff():
 
 
 def main_writes():
-    """Write-dataplane demo: the same mixed read/write churn trace
-    served through the per-PUT sync baseline and the ragged ENCODE
-    megakernel (the setup the gateway_writes benchmark block gates),
-    ending with the end-to-end consistency audits."""
+    """Write-dataplane demo: a mixed read/write churn trace served
+    through the ragged ENCODE megakernel, ending with the end-to-end
+    consistency audits."""
     code = CoreCode(9, 6, 3)
     q, num_objects, num_nodes = 4096, 24, 60
 
@@ -526,56 +522,52 @@ def main_writes():
     print(f"CORE ({code.n},{code.k},{code.t}) cluster, {num_nodes} nodes; "
           f"{len(reqs)} requests: {n_puts} PUTs ({n_small} small, sealed), "
           f"{sum(1 for r in reqs if r.kind == 'delete')} deletes")
-    for mode in ("sync", "ragged"):
-        cfg = GatewayConfig(
-            batch_window=0.01,
-            write_coalesce=mode,
-            encode_cost=0.002,      # modeled launch billing (deterministic)
-            decode_cost=0.002,
-        )
-        gw = ObjectGateway(
-            code, ClusterProfile.computation_critical(), num_nodes, cfg
-        )
-        rng = np.random.default_rng(61)
-        gw.load_objects(
-            rng.integers(0, 256, (num_objects, code.k, q), dtype=np.uint8)
-        )
-        rep = gw.serve(list(reqs))
-        gw.seal_flush(reqs[-1].time + 1.0)
-        puts = [r for r in rep.records
-                if r.kind == "put" and r.latency is not None]
-        lats = sorted(r.latency for r in puts)
-        span = (max(r.time + r.latency for r in puts)
-                - min(r.time for r in puts))
-        st = gw.coalescer.stats
-        by_kind = gw.coalescer.jit_entries_by_kind()
-        parity = gw.audit_parity()
-        sealed = gw.audit_sealed_stripes()
-        print(f"\n  write_coalesce={mode}:")
-        print(f"    PUT throughput  {len(puts) / max(span, 1e-9):8.1f} put/s "
-              f"(p50 {lats[len(lats) // 2] * 1e3:.1f} ms, "
-              f"p99 {lats[int(len(lats) * 0.99)] * 1e3:.1f} ms)")
-        print(f"    ragged encode   {st.encode_ops:8d} encode ops in "
-              f"{st.encode_calls} billed launches over {st.encode_windows} "
-              f"windows (live jit: EH {by_kind.get('EH', 0)}, "
-              f"EV {by_kind.get('EV', 0)})")
-        print(f"    stripes sealed  {sealed['rows_checked']:8d} rows "
-              f"({sealed['extents_checked']} small extents; "
-              f"{int(rep.metrics.counter_total('stripes_sealed'))} sealed "
-              f"mid-trace, the rest at drain)")
-        print(f"    parity audit    {parity['blocks_checked']:8d} blocks: "
-              f"{parity['stale_blocks']} stale, "
-              f"{parity['corrupt_blocks']} corrupt")
-        print(f"    sealed audit    {sealed['rows_checked']:8d} rows "
-              f"decoded: {sealed['extents_wrong']} wrong extents, "
-              f"{sealed['rows_unreadable']} unreadable")
+    cfg = GatewayConfig(
+        batch_window=0.01,
+        encode_cost=0.002,      # modeled launch billing (deterministic)
+        decode_cost=0.002,
+    )
+    gw = ObjectGateway(
+        code, ClusterProfile.computation_critical(), num_nodes, cfg
+    )
+    rng = np.random.default_rng(61)
+    gw.load_objects(
+        rng.integers(0, 256, (num_objects, code.k, q), dtype=np.uint8)
+    )
+    rep = gw.serve(list(reqs))
+    gw.seal_flush(reqs[-1].time + 1.0)
+    puts = [r for r in rep.records
+            if r.kind == "put" and r.latency is not None]
+    lats = sorted(r.latency for r in puts)
+    span = (max(r.time + r.latency for r in puts)
+            - min(r.time for r in puts))
+    st = gw.coalescer.stats
+    by_kind = gw.coalescer.jit_entries_by_kind()
+    parity = gw.audit_parity()
+    sealed = gw.audit_sealed_stripes()
+    print(f"\n  PUT throughput  {len(puts) / max(span, 1e-9):8.1f} put/s "
+          f"(p50 {lats[len(lats) // 2] * 1e3:.1f} ms, "
+          f"p99 {lats[int(len(lats) * 0.99)] * 1e3:.1f} ms)")
+    print(f"  ragged encode   {st.encode_ops:8d} encode ops in "
+          f"{st.encode_calls} billed launches over {st.encode_windows} "
+          f"windows (live jit: EH {by_kind.get('EH', 0)}, "
+          f"EV {by_kind.get('EV', 0)})")
+    print(f"  stripes sealed  {sealed['rows_checked']:8d} rows "
+          f"({sealed['extents_checked']} small extents; "
+          f"{int(rep.metrics.counter_total('stripes_sealed'))} sealed "
+          f"mid-trace, the rest at drain)")
+    print(f"  parity audit    {parity['blocks_checked']:8d} blocks: "
+          f"{parity['stale_blocks']} stale, "
+          f"{parity['corrupt_blocks']} corrupt")
+    print(f"  sealed audit    {sealed['rows_checked']:8d} rows "
+          f"decoded: {sealed['extents_wrong']} wrong extents, "
+          f"{sealed['rows_unreadable']} unreadable")
 
 
 def main_shards(num_shards: int):
     """Sharded scale-out demo: the same decode-bound trace at 1 shard
-    and at N over one shared store (the setup the gateway_shards
-    benchmark block gates), then the N-shard run with a whole shard
-    killed mid-trace."""
+    and at N over one shared store, then the N-shard run with a whole
+    shard killed mid-trace."""
     code = CoreCode(9, 6, 3)
     q, num_objects, num_nodes = 4096, 60, 60
     tenants = [
@@ -664,7 +656,7 @@ if __name__ == "__main__":
                          "under the same workload and fault trace)")
     ap.add_argument("--writes", action="store_true",
                     help="write-dataplane demo (ragged ENCODE megakernel "
-                         "vs per-PUT sync baseline + consistency audits)")
+                         "+ consistency audits)")
     ap.add_argument("--shards", metavar="N", type=int, default=None,
                     help="sharded scale-out demo: N shard gateways over "
                          "one shared store (speedup vs 1 shard, "

@@ -1,16 +1,14 @@
 # Client-facing object-storage serving layer over the simulated CORE
 # cluster: Zipf/Poisson workloads, per-request degraded-read planning
 # (paper Table 1), a pipelined fetch->decode->verify dataplane whose
-# decode stage is the ragged MEGAKERNEL (GatewayConfig.coalesce,
-# default "ragged"): a window's whole mixed-shape decode set — H and V
-# ops of any (M, K, blocklen) — is staged as fixed-width descriptor
-# tiles and decoded in ONE Pallas launch per kind, with <= 2 traced
-# signatures per kind and only tail-tile padding; the measured launch
-# time is split by tile ranges into per-op LaunchUnits so the engine
-# pool spreads one launch across engines. coalesce="bucketed" keeps
-# the per-shape stacked launches (ladder-padded, autotuned) as the
-# measured baseline. Plus rebuild-cost-aware block caching and
-# weighted-fair quantum fabric sharing between any number of tenants.
+# decode stage is the ragged MEGAKERNEL: a window's whole mixed-shape
+# decode set — H and V ops of any (M, K, blocklen) — is staged as
+# fixed-width descriptor tiles and decoded in ONE Pallas launch per
+# kind, with <= 2 traced signatures per kind and only tail-tile
+# padding; the measured launch time is split by tile ranges into
+# per-op LaunchUnits so the engine pool spreads one launch across
+# engines. Plus rebuild-cost-aware block caching and weighted-fair
+# quantum fabric sharing between any number of tenants.
 #
 # Tenancy and SLOs: every request is tagged with a tenant; each tenant's
 # fabric traffic is shaped by its weighted-fair quantum ratio
@@ -55,14 +53,13 @@
 # urgency as a repair drags — to the "repair" tenant's fabric weight
 # and engine share before every group repair (GatewayReport.pacing).
 #
-# Write dataplane (GatewayConfig.write_coalesce, default "ragged"):
-# PUT windows mirror the decode megakernel — a batch's RS parity-row
-# generations (kind "EH") and XOR-delta vertical-parity folds (kind
-# "EV", one fold op per touched parity block via XOR associativity)
-# each run as ONE ragged ENCODE launch (kernels/ragged_encode.py),
-# billed on the same engine pool decodes ride; client transfers start
-# only after the billed encodes land. write_coalesce="sync" is the
-# per-PUT launch baseline. Small PUTs (Request.nbytes set) journal for
+# Write dataplane: PUT windows mirror the decode megakernel — a
+# batch's RS parity-row generations (kind "EH") and XOR-delta
+# vertical-parity folds (kind "EV", one fold op per touched parity
+# block via XOR associativity) each run as ONE ragged ENCODE launch
+# (kernels/ragged_encode.py), billed on the same engine pool decodes
+# ride; client transfers start only after the billed encodes land.
+# Small PUTs (Request.nbytes set) journal for
 # an instant ack and pack into shared codeword rows via StripeSealer;
 # deletes tombstone in place. audit_parity() / audit_sealed_stripes()
 # are the end-to-end churn consistency audits (zero stale parity, every
@@ -82,7 +79,6 @@
 # lost blocks. serve() returns GatewayReport.merged across shards.
 from repro.gateway.cache import CacheStats, LRUBlockCache
 from repro.gateway.coalescer import (
-    PAD_LADDER,
     CoalescerStats,
     DecodeCoalescer,
     LaunchUnit,
@@ -137,7 +133,6 @@ __all__ = [
     "EnginePool",
     "LRUBlockCache",
     "NodeRecoverEvent",
-    "PAD_LADDER",
     "CoalescerStats",
     "DecodeCoalescer",
     "LaunchUnit",

@@ -24,16 +24,15 @@ planner's candidates by estimated time instead of Table-1 bytes) and
 rejects only if even that plan busts the target. Rejections are tracked
 per tenant in ``GatewayReport.rejections``.
 
-Pipeline stages (config.pipeline):
+Pipeline stages:
 
   1. **fetch**   — every source block of the window's plans is scheduled
-     on the fabric at the request's plan time (``ReadPlan.planned_at``);
-     cache hits are ready immediately. Under the quantum fabric
-     (config.fabric) these transfers preempt long background repair
-     transfers at quantum granularity instead of queueing behind them.
+     on the quantum fabric at the request's plan time
+     (``ReadPlan.planned_at``); cache hits are ready immediately. These
+     transfers preempt long background repair transfers at quantum
+     granularity instead of queueing behind them.
   2. **decode**  — reconstructions are deduped across the window and
-     executed by the ragged megakernel dataplane
-     (``config.coalesce="ragged"``, the default): the whole window's
+     executed by the ragged megakernel dataplane: the whole window's
      mixed-shape decode set is staged as fixed-width descriptor tiles
      and decoded in ONE Pallas launch per kind (two chunk rungs bound
      the traced signatures at <= 2 per kind; see gateway/coalescer.py).
@@ -43,26 +42,14 @@ Pipeline stages (config.pipeline):
      decode-engine timelines once its LAUNCH's source transfers have
      all completed (a physical launch's staging buffer holds every one
      of its ops' tiles) and an engine frees, so a single physical
-     launch still spreads across the pool. ``coalesce="bucketed"`` keeps the
-     pre-megakernel shape-bucketed dataplane (one stacked launch per
-     (kind, M, K, blocklen) bucket, ladder-padded) as the measured
-     baseline.
+     launch still spreads across the pool.
   3. **verify / deliver** — each GET completes at the max of its direct
      fetches and the decode launches it depends on; contents are checked
      against ground truth host-side (zero simulated cost).
 
-In ``pipelined`` mode (default) the stages overlap across windows:
-window N+1's fabric transfers proceed while window N's decode launches
-occupy the engine, and the engine drains buckets in source-arrival
-order. ``serial`` mode is the comparison baseline: it charges the
-serialization a synchronous flush-per-batch loop actually implies — a
-window's transfers may not start before the previous window fully
-completed, no launch is issued before ALL the window's transfers land,
-the launches run back-to-back, and every degraded GET of the window
-waits for the last of them. (The PR-1 loop executed stages strictly in
-sequence but its simulated timestamps let them overlap optimistically;
-serial mode prices that loop honestly rather than reproducing its
-accounting.)
+The stages overlap across windows: window N+1's fabric transfers proceed
+while window N's decode launches occupy the engine, and the engine
+drains launches in source-arrival order.
 
 Fabric quantum model (storage/netmodel.py): transfers are scheduled in
 fixed full-rate quanta; a priority class with share s may claim one
@@ -71,9 +58,9 @@ background class leaves are real preemption points for foreground reads
 — ``background_share`` is a weighted-fair quantum ratio, not a rate cap.
 
 Latency model per request: arrival -> (cache | fabric transfers to the
-request's client port) -> per-bucket decode on the shared engine ->
+request's client port) -> per-launch decode on the shared engine ->
 completion. Decode compute is measured on the real jitted kernels
-(autotuned per backend, batch sizes padded up a fixed ladder so the jit
+(autotuned per backend, launch tile counts on two chunk rungs so the jit
 cache stays bounded — GatewayReport.jit_cache_entries) and scaled by the
 cluster profile.
 
@@ -151,9 +138,6 @@ from repro.storage.netmodel import (
 )
 from repro.storage.repair import BlockFixer, PacingController, Scrubber
 
-PIPELINED = "pipelined"
-SERIAL = "serial"
-
 # Sealed-stripe rows register as synthetic objects above this id, so
 # they can never collide with workload-drawn tenant object ids.
 SEAL_OID_BASE = 1 << 40
@@ -179,21 +163,14 @@ BILLED_TILE_N = 65536
 @dataclass(frozen=True)
 class GatewayConfig:
     batch_window: float = 0.002  # seconds of arrival coalescing
-    cache_bytes: int = 0  # 0 disables the block cache
-    cache_policy: str = "cost"  # "cost" (rebuild-cost-aware) | "lru"
+    cache_bytes: int = 0  # 0 disables the rebuild-cost-aware block cache
     num_client_ports: int = 32  # parallel client-side NICs
     background_share: float = 0.5  # repair's weighted-fair quantum ratio
-    fabric: str = "quantum"  # "quantum" (preemptive) | "fifo"
     repair_on_failure: bool = False  # run BlockFixer after detection
     repair_delay: float = 5.0  # failure-detection lag (seconds)
     verify: bool = True  # check every GET against ground truth
     interpret: bool | None = None  # kernel backend override
-    pipeline: str = PIPELINED  # "pipelined" | "serial" (PR-1 loop)
     autotune: bool = True  # measured kernel-parameter sweep at first use
-    # decode dataplane: "ragged" = one descriptor-driven megakernel
-    # launch per (window, kind); "bucketed" = the pre-megakernel
-    # per-shape stacked launches (kept as the measured baseline)
-    coalesce: str = "ragged"
     record_payloads: bool = False  # sha256 of every GET payload in records
     # -- multi-tenant QoS ------------------------------------------------------
     tenant_weights: dict | None = None  # tenant -> fabric quantum ratio
@@ -222,9 +199,8 @@ class GatewayConfig:
     # the SAME op stream into DIFFERENT window sizes (the sharded
     # scale-out bench: N shards cut windows ~N ways, and per-launch
     # billing would charge the cluster N times for the same tiles).
-    # Requires coalesce="ragged" (bucketed units carry no tile counts)
-    # and is mutually exclusive with decode_cost. Pins the ragged tile
-    # width at BILLED_TILE_N.
+    # Mutually exclusive with decode_cost. Pins the ragged tile width
+    # at BILLED_TILE_N.
     decode_cost_per_tile: float | None = None
     # -- write dataplane -------------------------------------------------------
     # Modeled ENCODE cost per launch (same semantics as decode_cost);
@@ -233,12 +209,6 @@ class GatewayConfig:
     # the SAME engine pool decodes ride, so PUT latency reflects the
     # engine backlog and writes push back on degraded reads.
     encode_cost: float | None = None
-    # write dataplane shape: "ragged" = one descriptor-driven encode
-    # megakernel window per PUT batch (EH parity-row generation + EV
-    # XOR-delta parity folds, one launch per kind); "sync" = one
-    # launch pair PER PUT (the synchronous write baseline the bench
-    # compares against).
-    write_coalesce: str = "ragged"
     # -- fault scenarios / closed-loop repair ---------------------------------
     negative_ttl: float = 5.0  # seconds a known-down block stays negative-cached
     repair_pacing: bool = False  # SLO-aware closed-loop repair pacing
@@ -688,11 +658,6 @@ class ObjectGateway:
         # the namespace's code family: geometry + encode + degraded-read
         # candidates + repair cost surface (raises on unknown names)
         self.family = make_family(code, self.config.code_family)
-        if self.config.pipeline not in (PIPELINED, SERIAL):
-            raise ValueError(
-                f"pipeline must be 'pipelined' or 'serial', got "
-                f"{self.config.pipeline!r}"
-            )
         if self.config.admission not in (ADMIT_OFF, ADMIT_REJECT, ADMIT_DEGRADE):
             raise ValueError(
                 f"admission must be 'off', 'reject' or 'degrade', got "
@@ -701,11 +666,6 @@ class ObjectGateway:
         if self.config.num_engines < 1:
             raise ValueError(
                 f"num_engines must be >= 1, got {self.config.num_engines}"
-            )
-        if self.config.coalesce not in ("ragged", "bucketed"):
-            raise ValueError(
-                f"coalesce must be 'ragged' or 'bucketed', got "
-                f"{self.config.coalesce!r}"
             )
         if self.config.decode_cost is not None and self.config.decode_cost <= 0:
             raise ValueError(
@@ -728,16 +688,6 @@ class ObjectGateway:
                     "decode_cost and decode_cost_per_tile are mutually "
                     "exclusive timing models"
                 )
-            if self.config.coalesce != "ragged":
-                raise ValueError(
-                    "decode_cost_per_tile requires coalesce='ragged' "
-                    "(bucketed launch units carry no tile counts)"
-                )
-        if self.config.write_coalesce not in ("ragged", "sync"):
-            raise ValueError(
-                f"write_coalesce must be 'ragged' or 'sync', got "
-                f"{self.config.write_coalesce!r}"
-            )
         if (
             self.config.repair_groups_per_run is not None
             and self.config.repair_groups_per_run < 1
@@ -780,14 +730,6 @@ class ObjectGateway:
                 f"scrub_blocks_per_run must be >= 1, got "
                 f"{self.config.scrub_blocks_per_run}"
             )
-        if self.config.pipeline == SERIAL and self.config.num_engines != 1:
-            # the serial baseline prices the PR-1 synchronous loop, which
-            # had exactly one decode engine — extra engines would sit
-            # idle while still skewing the admission estimator
-            raise ValueError(
-                "pipeline='serial' models a single-engine synchronous "
-                f"loop; num_engines must be 1, got {self.config.num_engines}"
-            )
         # sim-time observability plane (repro.obs): one tracer threaded
         # through the fabric, engine pool and repair engine. NULL_TRACER
         # when disabled, so emission sites cost one attribute check.
@@ -814,7 +756,6 @@ class ObjectGateway:
             self.sim = NetSimulator(
                 profile,
                 background_share=self.config.background_share,
-                mode=self.config.fabric,
                 tenant_weights=self.config.tenant_weights,
             )
         if sim is None or self.tracer.enabled:
@@ -827,7 +768,7 @@ class ObjectGateway:
         # shared fabric.
         self._repair_tenant = shard_tenant(REPAIR_TENANT, shard_id)
         self.cache = (
-            LRUBlockCache(self.config.cache_bytes, policy=self.config.cache_policy)
+            LRUBlockCache(self.config.cache_bytes, policy="cost")
             if self.config.cache_bytes
             else None
         )
@@ -839,7 +780,6 @@ class ObjectGateway:
             compute_scale=profile.compute_scale,
             interpret=self.config.interpret,
             autotune_kernels=self.config.autotune,
-            mode=self.config.coalesce,
             tile_n=(
                 None if self.config.decode_cost_per_tile is None else BILLED_TILE_N
             ),
@@ -880,15 +820,13 @@ class ObjectGateway:
         # Simulated decode engines: each runs one batched launch at a
         # time; launches dispatch to the least-loaded engine under the
         # owning tenant's engine share. The pool persists across windows
-        # so pipelined windows overlap on it; repair decode compute is
+        # so windows overlap on it; repair decode compute is
         # billed on it too (as the "repair" tenant), so repair and
         # foreground reconstruction contend for the same engines.
         self._pool = EnginePool(
             self.config.num_engines, weights=self.config.engine_weights
         )
         self._pool.tracer = self.tracer
-        # Serial-mode barrier: completion time of the previous window.
-        self._window_free = 0.0
         # Scenario bookkeeping: when each currently-unavailable block was
         # lost (feeds MTTR samples on heal/recover), persisted across
         # serve() calls like _healing. Shared: a loss is a cluster fact.
@@ -1180,7 +1118,6 @@ class ObjectGateway:
 
     # -- request batch execution ------------------------------------------------
     def _flush(self, batch: list[Request], report: GatewayReport) -> None:
-        serial = self.config.pipeline == SERIAL
         tracer = self.tracer
         gets: list[tuple[Request, ReadPlan]] = []
         tids: list[int] = []  # per-get trace id, parallel to ``gets``
@@ -1279,10 +1216,7 @@ class ObjectGateway:
         # (config.hedge): past the deadline derived from the healthy-
         # fabric estimate, the cheapest single-block recovery plan races
         # the primary and the first verified winner serves the column.
-        # Serial mode gates the whole window's transfers on the previous
-        # window's completion (the synchronous loop cannot start
-        # fetching window N+1 while window N is still decoding);
-        # pipelined mode starts them at plan time.
+        # Transfers start at plan time.
         verify_ck = self.config.verify_checksums
         ready: list[dict[BlockKey, float]] = []
         bytes_read: list[int] = []
@@ -1295,11 +1229,7 @@ class ObjectGateway:
                 client = self._client_port(req)
                 tid = tids[i]
                 gid, row = self._objects[req.object_id]
-                fetch_at0 = fetch_at = (
-                    max(plan.planned_at, self._window_free)
-                    if serial
-                    else plan.planned_at
-                )
+                fetch_at0 = fetch_at = plan.planned_at
                 # SLO tenants stamp their fabric transfers with a deadline so
                 # the simulator's per-tenant miss counters line up with the
                 # report's violation rates.
@@ -1481,9 +1411,10 @@ class ObjectGateway:
                 fetch_ats.append(fetch_at0)
 
         # 2) decode: dedup identical reconstructions (a hot degraded
-        # object appears once per window, not once per request), then one
-        # stacked launch per shape bucket, scheduled on the simulated
-        # serial decode engine.
+        # object appears once per window, not once per request), then
+        # the window's ragged megakernel launches (one per kind and chunk
+        # of descriptor tiles), each split into per-op units scheduled on
+        # the simulated decode-engine pool.
         unique_idx: dict[tuple, int] = {}
         uops = []
         owners: list[list[int]] = []
@@ -1549,82 +1480,47 @@ class ObjectGateway:
         # dispatch interval of the unit that COMPLETED the op (its max
         # end), plus the launch-wide source barrier it waited behind
         op_meta: list[dict | None] = [None] * len(uops)
-        if serial:
-            # strict staging: no launch before ALL the window's transfers
-            # (even direct-only fetches) complete; launches back-to-back
-            # on ONE engine (the synchronous loop this baseline prices
-            # had no decode parallelism); the whole window waits for the
-            # last launch.
-            window_net = max(
-                (t for key_ready in ready for t in key_ready.values()),
-                default=self._window_free,
+        # a PHYSICAL launch cannot start before every source staged into
+        # it lands (its buffer holds all its ops' tiles), so all units
+        # sharing a launch_id wait for the launch-wide barrier; past it
+        # they dispatch independently, in arrival order, onto the
+        # least-loaded decode engine under the owning tenant's engine
+        # share — windows (and one megakernel launch's per-op tile
+        # ranges) overlap across the engine pool
+        launch_ready: dict[int, float] = {}
+        for u in units:
+            r = max(op_ready[j] for j in u.op_indices)
+            launch_ready[u.launch_id] = max(
+                launch_ready.get(u.launch_id, 0.0), r
             )
-            if units:
-                total = sum(u.compute for u in units)
-                start, end = self._pool.dispatch(
-                    window_net,
-                    total,
-                    ctx=(
-                        (tids[0], tids[0], {"kind": "serial", "launch_id": -1})
-                        if tracer.enabled
-                        else None
-                    ),
+        for u in sorted(units, key=lambda u: launch_ready[u.launch_id]):
+            ctx = None
+            if tracer.enabled:
+                # bill the engine-track span to the trace of the
+                # earliest request owning this unit's first op (the
+                # same owner the engine time is billed to)
+                ctx = (
+                    tids[owners[u.op_indices[0]][0]],
+                    tids[owners[u.op_indices[0]][0]],
+                    {"kind": u.kind, "launch_id": u.launch_id},
                 )
-                op_done = [end] * len(uops)
-                op_meta = [
-                    {
+            start, end = self._pool.dispatch(
+                launch_ready[u.launch_id], u.compute,
+                tenant=op_tenant[u.op_indices[0]],
+                ctx=ctx,
+            )
+            for j in u.op_indices:
+                if end >= op_done[j]:
+                    op_done[j] = end
+                    op_meta[j] = {
                         "start": start,
                         "end": end,
-                        "ready": window_net,
-                        "kind": "serial",
-                        "launch_id": -1,
-                        "fraction": 1.0,
-                        "tiles": 0,
+                        "ready": launch_ready[u.launch_id],
+                        "kind": u.kind,
+                        "launch_id": u.launch_id,
+                        "fraction": u.fraction,
+                        "tiles": u.tiles,
                     }
-                ] * len(uops)
-        else:
-            # pipelined: a PHYSICAL launch cannot start before every
-            # source staged into it lands (its buffer holds all its
-            # ops' tiles), so all units sharing a launch_id wait for
-            # the launch-wide barrier; past it they dispatch
-            # independently, in arrival order, onto the least-loaded
-            # decode engine under the owning tenant's engine share —
-            # windows (and one megakernel launch's per-op tile ranges)
-            # overlap across the engine pool
-            launch_ready: dict[int, float] = {}
-            for u in units:
-                r = max(op_ready[j] for j in u.op_indices)
-                launch_ready[u.launch_id] = max(
-                    launch_ready.get(u.launch_id, 0.0), r
-                )
-            for u in sorted(units, key=lambda u: launch_ready[u.launch_id]):
-                ctx = None
-                if tracer.enabled:
-                    # bill the engine-track span to the trace of the
-                    # earliest request owning this unit's first op (the
-                    # same owner the engine time is billed to)
-                    ctx = (
-                        tids[owners[u.op_indices[0]][0]],
-                        tids[owners[u.op_indices[0]][0]],
-                        {"kind": u.kind, "launch_id": u.launch_id},
-                    )
-                start, end = self._pool.dispatch(
-                    launch_ready[u.launch_id], u.compute,
-                    tenant=op_tenant[u.op_indices[0]],
-                    ctx=ctx,
-                )
-                for j in u.op_indices:
-                    if end >= op_done[j]:
-                        op_done[j] = end
-                        op_meta[j] = {
-                            "start": start,
-                            "end": end,
-                            "ready": launch_ready[u.launch_id],
-                            "kind": u.kind,
-                            "launch_id": u.launch_id,
-                            "fraction": u.fraction,
-                            "tiles": u.tiles,
-                        }
 
         # 3) verify + deliver
         decoded_per_req: list[dict[int, np.ndarray]] = [dict() for _ in gets]
@@ -1639,7 +1535,6 @@ class ObjectGateway:
                 costs = decode_cost.setdefault(i, {})
                 for col in op.targets:
                     costs[col] = len(op.sources)
-        window_end = self._window_free
         for i, (req, plan) in enumerate(gets):
             if not alive[i]:
                 continue
@@ -1732,9 +1627,6 @@ class ObjectGateway:
                     tenant=req.tenant,
                 )
             )
-            window_end = max(window_end, done)
-        if serial:
-            self._window_free = window_end
 
     # -- integrity plane ---------------------------------------------------------
     def _note_corrupt(
@@ -1992,8 +1884,8 @@ class ObjectGateway:
     def _flush_puts(self, batch: list[Request], report: GatewayReport) -> None:
         """One PUT window: admission, small-object journaling/sealing,
         then the window's encodes — ONE ragged ENCODE megakernel window
-        for the whole batch (``write_coalesce="ragged"``) or one per PUT
-        (``"sync"``, the synchronous write baseline)."""
+        for the whole batch (EH parity-row generation + EV XOR-delta
+        parity folds, one launch per kind)."""
         cfg = self.config
         slos = cfg.tenant_slo_p99 or {}
         full_reqs: list[Request] = []
@@ -2059,13 +1951,8 @@ class ObjectGateway:
                     "enc_done": req.time,
                 }
             )
-        if cfg.write_coalesce == "ragged":
-            windows = [(jobs, seal_groups)] if (jobs or seal_groups) else []
-        else:
-            windows = [([j], []) for j in jobs]
-            windows += [([], [g]) for g in seal_groups]
-        for wjobs, wseals in windows:
-            self._encode_window(wjobs, wseals, report)
+        if jobs or seal_groups:
+            self._encode_window(jobs, seal_groups, report)
 
     def _append_small(
         self, reqs: list[Request], report: GatewayReport
@@ -3220,10 +3107,6 @@ class ObjectGateway:
             est += sum(b for _e, b in live) / (
                 share * self.profile.node_bandwidth
             )
-        if self.config.pipeline == SERIAL:
-            # serial mode gates every fetch on the previous window's
-            # completion — under load that barrier IS the latency
-            est += max(0.0, self._window_free - now)
         if plan.decodes:
             est += max(0.0, self._pool.earliest_start(now) - now)
             est += self._decode_launch_estimate() * len(plan.decodes)

@@ -46,8 +46,8 @@ class DecodeOp:
 
     @property
     def shape_key(self) -> tuple:
-        """Decode-shape bucket: ops sharing this key can share one
-        batched kernel launch."""
+        """Decode shape (kind, targets, sources); the coalescer counts
+        the distinct shapes it has run (``stats.decode_shapes``)."""
         return (self.kind, len(self.targets), len(self.sources))
 
     @property

@@ -1,15 +1,11 @@
-"""Measured autotuning for the GF(256) / XOR Pallas entry points.
+"""Measured autotuning for the ragged GF(256) / XOR Pallas entry points.
 
-The kernels expose knobs whose best setting is backend-dependent:
-
-  * ``block_n`` — grid tile width. On TPU, fatter tiles amortize
-    per-step grid/DMA overhead on these bandwidth-bound kernels; under
-    the CPU interpreter, each grid step is a Python execution of the
-    kernel body, so the trade-off inverts at small N.
-  * the ragged megakernel's TILE WIDTH (kernels/ragged_decode.py) — the
-    same grid-overhead-vs-padding trade-off, but per descriptor tile:
-    fat tiles mean fewer grid steps and launches, narrow tiles mean less
-    tail-tile filler on short rows.
+The ragged megakernels' TILE WIDTH (kernels/ragged_decode.py) is a knob
+whose best setting is backend-dependent: fat tiles mean fewer grid
+steps and launches, narrow tiles mean less tail-tile filler on short
+rows. On TPU, fatter tiles amortize per-step grid/DMA overhead on these
+bandwidth-bound kernels; under the CPU interpreter, each grid step is a
+Python execution of the kernel body, so the trade-off shifts.
 
 Instead of hard-coding per-backend defaults, this module *measures* the
 candidates once per (kernel, backend) at first use — including the
@@ -30,11 +26,10 @@ configuration), and ``clear_cache()`` drops the disk file along with the
 in-process winners. A candidate that fails to compile raises: the sweep
 never skips one.
 
-The probe shapes are deliberately tiny (a few batched stripes over the
-candidates' least common multiple of bytes): the point is ranking the
-candidates, not absolute numbers. Callers cap ``block_n`` to their
-actual byte length (ops.py pads N up to a block_n multiple, so a tuned
-32 KiB tile applied to 4 KiB blocks would 8x the work).
+The probe window is deliberately tiny (a few 64 KiB rows): the point is
+ranking the candidates, not absolute numbers. Callers cap the tile
+width to their actual row length (``TunedKernel.block_n_for``), so a
+tuned 64 KiB tile applied to 4 KiB rows does not 16x the work.
 """
 
 from __future__ import annotations
@@ -47,13 +42,10 @@ import time
 from dataclasses import dataclass
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.backend import CACHE_DIR, resolve_interpret
 
-GF_BLOCK_CANDIDATES = (2048, 8192, 32768)
-XOR_BLOCK_CANDIDATES = (8192, 65536)
 # Ragged megakernel tile widths (bytes per descriptor tile).
 RAGGED_GF_TILE_CANDIDATES = (1024, 4096, 16384, 65536)
 RAGGED_XOR_TILE_CANDIDATES = (4096, 65536)
@@ -244,51 +236,6 @@ def _tuned(
         _STATS["disk_hits"] += 1
     _CACHE[(kind, interpret)] = tuned
     return tuned
-
-
-def tuned_gf256(interpret: bool | None = None) -> TunedKernel:
-    """Winning block_n for the batched GF(256) decode entry."""
-    interpret = resolve_interpret(interpret)
-    from repro.kernels import ops  # deferred: ops imports this module
-
-    def sweep():
-        n = max(GF_BLOCK_CANDIDATES)  # multiple of every candidate
-        rng = np.random.default_rng(0)
-        coefs = rng.integers(0, 256, size=(2, 2, 6), dtype=np.uint8)
-        data = rng.integers(0, 256, size=(2, 6, n), dtype=np.uint8)
-        return [
-            (
-                bn,
-                lambda bn=bn: ops.gf256_matmul_batched(
-                    coefs, data, block_n=bn, interpret=interpret
-                ),
-            )
-            for bn in GF_BLOCK_CANDIDATES
-        ]
-
-    return _tuned("gf256", interpret, GF_BLOCK_CANDIDATES, sweep)
-
-
-def tuned_xor(interpret: bool | None = None) -> TunedKernel:
-    """Winning block_n for the batched XOR parity entry."""
-    interpret = resolve_interpret(interpret)
-    from repro.kernels import ops
-
-    def sweep():
-        n = max(XOR_BLOCK_CANDIDATES)
-        rng = np.random.default_rng(1)
-        data = jnp.asarray(rng.integers(0, 256, size=(2, 3, n), dtype=np.uint8))
-        return [
-            (
-                bn,
-                lambda bn=bn: ops.xor_parity_batched(
-                    data, block_n=bn, interpret=interpret
-                ),
-            )
-            for bn in XOR_BLOCK_CANDIDATES
-        ]
-
-    return _tuned("xor", interpret, XOR_BLOCK_CANDIDATES, sweep)
 
 
 # The ragged tile-width probe stages a fixed WINDOW — a few rows of a

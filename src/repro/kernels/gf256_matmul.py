@@ -141,29 +141,3 @@ def gf256_matmul_planes(
         out_specs=pl.BlockSpec((m, bw), lambda j, k: (0, j)),
         interpret=interpret,
     )(planes, words)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def gf256_matmul_planes_batched(
-    planes: jnp.ndarray,
-    words: jnp.ndarray,
-    *,
-    block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Stacked decode: (B, K, M, 8) planes x (B, K, 1, N/4) words ->
-    (B, M, N/4).
-
-    One launch serves B independent stripes that share a decode *shape*
-    but not coefficients — the gateway coalescer's case: concurrent
-    degraded reads each need their own repair matrix (their failure sets
-    differ) over same-sized blocks. vmap over the single-stripe kernel
-    folds the batch into an extra Pallas grid dimension, so the whole
-    batch is a single kernel launch instead of B dispatches.
-    """
-    interpret = resolve_interpret(interpret)
-    assert planes.shape[0] == words.shape[0], (planes.shape, words.shape)
-    fn = functools.partial(
-        gf256_matmul_planes, block_n=block_n, interpret=interpret
-    )
-    return jax.vmap(fn)(planes, words)

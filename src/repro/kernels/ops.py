@@ -31,26 +31,6 @@ def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> tuple[jnp.ndarray, int]:
     return jnp.pad(x, pad), n
 
 
-def _stage_gf(coefs: np.ndarray, data, block_n: int | None):
-    """Host staging for the word GF kernels: pad N to a block_n multiple,
-    view the bytes as words with a unit axis per source, and splat the
-    coefficient bit-planes source-major. Returns (planes, words, n,
-    block_n)."""
-    data = np.asarray(data, dtype=np.uint8)
-    n = data.shape[-1]
-    if block_n is None:
-        block_n = min(_gfk.DEFAULT_BLOCK_N, _next_pow2(n))
-    rem = (-n) % block_n
-    if rem:
-        data = np.pad(data, [(0, 0)] * (data.ndim - 1) + [(0, rem)])
-    words = _gfk.stage_words(data)[..., None, :]  # (..., K, 1, N/4)
-    planes = np.stack(
-        [_gfk.expand_coeff_bitplanes(c) for c in coefs.reshape(-1, *coefs.shape[-2:])]
-    ).reshape(*coefs.shape, 8)
-    planes = _gfk.stage_planes(np.swapaxes(planes, -3, -2))  # (..., K, M, 8)
-    return jnp.asarray(planes), jnp.asarray(words), n, block_n
-
-
 def gf256_matmul(
     coef: np.ndarray,
     data,
@@ -61,12 +41,25 @@ def gf256_matmul(
     """C (M, N) = coef (M, K) @ data (K, N) over GF(2^8), Pallas-backed.
 
     ``coef`` is a host-side numpy matrix (generator/repair coefficients);
-    ``data`` is staged on the host as words, and the result comes back
-    as (M, N) uint8 numpy.
+    ``data`` is staged on the host as words (N padded to a block_n
+    multiple, a unit axis per source) with the coefficient bit-planes
+    splatted source-major, and the result comes back as (M, N) uint8
+    numpy.
     """
     interpret = resolve_interpret(interpret)
-    planes, words, n, block_n = _stage_gf(np.asarray(coef, np.uint8), data, block_n)
-    out = _gfk.gf256_matmul_planes(planes, words, block_n=block_n, interpret=interpret)
+    data = np.asarray(data, dtype=np.uint8)
+    n = data.shape[-1]
+    if block_n is None:
+        block_n = min(_gfk.DEFAULT_BLOCK_N, _next_pow2(n))
+    rem = (-n) % block_n
+    if rem:
+        data = np.pad(data, [(0, 0), (0, rem)])
+    words = _gfk.stage_words(data)[:, None, :]  # (K, 1, N/4)
+    planes = _gfk.expand_coeff_bitplanes(coef)  # (M, K, 8)
+    planes = _gfk.stage_planes(np.swapaxes(planes, 0, 1))  # (K, M, 8)
+    out = _gfk.gf256_matmul_planes(
+        jnp.asarray(planes), jnp.asarray(words), block_n=block_n, interpret=interpret
+    )
     return np.asarray(out).view(np.uint8)[:, :n]
 
 
@@ -82,44 +75,6 @@ def xor_parity(
     data_p, orig_n = _pad_to(data, block_n, axis=-1)
     out = _xpk.xor_parity(data_p, block_n=block_n, interpret=interpret)
     return out[:orig_n]
-
-
-def gf256_matmul_batched(
-    coefs: np.ndarray,
-    data,
-    *,
-    block_n: int | None = None,
-    interpret: bool | None = None,
-) -> np.ndarray:
-    """Stacked decode: out (B, M, N) = coefs (B, M, K) @ data (B, K, N),
-    each batch element an independent GF(2^8) product, in ONE kernel
-    launch (the gateway coalescer's batched degraded-read decode).
-
-    ``coefs`` is host-side numpy (per-stripe repair/decode matrices);
-    ``data`` is staged on the host as words; the result is uint8 numpy.
-    """
-    interpret = resolve_interpret(interpret)
-    coefs = np.asarray(coefs, dtype=np.uint8)
-    assert coefs.shape[0] == np.shape(data)[0], (coefs.shape, np.shape(data))
-    planes, words, n, block_n = _stage_gf(coefs, data, block_n)
-    out = _gfk.gf256_matmul_planes_batched(
-        planes, words, block_n=block_n, interpret=interpret
-    )
-    return np.asarray(out).view(np.uint8)[..., :n]
-
-
-def xor_parity_batched(
-    data: jnp.ndarray, *, block_n: int | None = None, interpret: bool | None = None
-) -> jnp.ndarray:
-    """data (B, T, N) uint8 -> (B, N): batched XOR over rows, one launch."""
-    interpret = resolve_interpret(interpret)
-    n = data.shape[-1]
-    if block_n is None:
-        block_n = min(_xpk.DEFAULT_BLOCK_N, _next_pow2(n))
-    data = data.astype(jnp.uint8)
-    data_p, orig_n = _pad_to(data, block_n, axis=-1)
-    out = _xpk.xor_parity_batched(data_p, block_n=block_n, interpret=interpret)
-    return out[..., :orig_n]
 
 
 def _ragged(entry, mc, data, interpret, tile_block) -> np.ndarray:

@@ -2,13 +2,11 @@
 
 The gateway's decode hot path is a WINDOW of reconstructions with mixed
 shapes — horizontal RS decodes of varying target counts, vertical XOR
-repairs, ragged byte lengths — and the shape-bucketed dataplane pays one
-stacked launch per (kind, M, K, blocklen) bucket, each padded up a
-power-of-two batch ladder.  These kernels collapse a whole window into
-ONE launch per kind: the host cuts every decode ROW (one output row of
-one op) into fixed-width tiles, gathers the tiles into a flat staging
-buffer, and the kernel's grid walks tiles, applying each tile's own
-coefficient row.
+repairs, ragged byte lengths.  These kernels run a whole window in ONE
+launch per kind and chunk: the host cuts every decode ROW (one output
+row of one op) into fixed-width tiles, gathers the tiles into a flat
+staging buffer, and the kernel's grid walks tiles, applying each tile's
+own coefficient row.
 
 Descriptor layout (built host-side by gateway/coalescer.py), source-
 major so that one source's slabs for a block of tiles are contiguous:
@@ -30,9 +28,8 @@ The launch tile count C is the jit shape key, so it is drawn from
 exactly two rungs (``CHUNK_SMALL``, ``CHUNK_BIG``): a window with T
 tiles issues T // CHUNK_BIG big launches plus ceil(rem / CHUNK_SMALL)
 small ones, the last padded with null tiles.  Traced signatures per
-kind are therefore <= 2 regardless of shape diversity — the bucketed
-path's O(shapes x ladder) jit set becomes O(1) — and padding is bounded
-by CHUNK_SMALL - 1 tiles per window, not a 2x batch rung.
+kind are therefore <= 2 regardless of shape diversity, and padding is
+bounded by CHUNK_SMALL - 1 tiles per window.
 
 Kernel layout: gf256_matmul's uint32 word layout. The host views the
 staged bytes as (K, C, TN/4) words, which costs nothing in numpy, and

@@ -50,17 +50,3 @@ def xor_parity(
         interpret=interpret,
     )(data)
     return out[0]
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def xor_parity_batched(
-    data: jnp.ndarray, *, block_n: int = DEFAULT_BLOCK_N, interpret: bool | None = None
-) -> jnp.ndarray:
-    """data: (B, T, N) uint8 -> (B, N): B independent vertical repairs in
-    one launch (vmap folds the batch into the Pallas grid). The gateway
-    coalescer's vertical fast path."""
-    interpret = resolve_interpret(interpret)
-    b, t, n = data.shape
-    assert n % block_n == 0, (n, block_n)
-    fn = functools.partial(xor_parity, block_n=block_n, interpret=interpret)
-    return jax.vmap(fn)(data)

@@ -36,8 +36,8 @@
 # decode-engine share (slowing repair when the tier nears its SLO,
 # accelerating toward the MTTR target when idle), and run_scenario
 # returns MTTR / durability / p99-under-failure metrics so paced and
-# fixed-weight repair compare head to head (BENCH_gateway.json
-# gateway_scenario rows). deterministic_fingerprint hashes the
+# fixed-weight repair compare head to head (tests/test_scenario.py).
+# deterministic_fingerprint hashes the
 # wall-clock-free outcome so golden-trace replays guard event ordering.
 from repro.scenario.engine import (
     SURGE_FAIL_AT,

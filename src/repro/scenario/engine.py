@@ -1,7 +1,7 @@
 """Scenario runner: drive a gateway over a fault trace and summarize.
 
-``run_scenario`` is the one-call harness the benchmarks, tests and the
-example share: it synthesizes the surge-aware request stream, replays
+``run_scenario`` is the one-call harness the tests and the example
+share: it synthesizes the surge-aware request stream, replays
 the trace's cluster events through ``ObjectGateway.serve`` (the gateway
 consumes them mid-run — the planner, negative cache and admission
 controller all see availability change between requests), audits
@@ -94,9 +94,8 @@ SURGE_END = 0.65
 
 def correlated_surge_setup(code, num_requests: int = 200) -> dict:
     """The canonical paced-vs-fixed repair scenario, defined ONCE and
-    shared by the benchmark gate (benchmarks/gateway_load.py), the
-    regression test (tests/test_scenario.py) and the example demo — so
-    all three always validate the same setup.
+    shared by the regression test (tests/test_scenario.py) and the
+    example demo — so both always validate the same setup.
 
     Shape: a dense 20-node cluster (racks of n - k, so the correlated
     burst sits exactly at the code's tolerance) loses rack 2 at t=0.05

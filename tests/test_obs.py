@@ -399,6 +399,40 @@ def test_streaming_mode_bounded_and_aggregates_agree():
     assert len(stream.recent) <= RECENT_CAP
 
 
+def test_long_trace_streaming_keeps_resident_memory_bounded():
+    """A 2000-request run of the canonical scenario (10x its usual
+    length) in streaming mode with tail-biased trace sampling keeps no
+    per-request record; resident samples and spans stay within their
+    caps however long the trace runs."""
+    code = CoreCode(9, 6, 3)
+    setup = correlated_surge_setup(code, num_requests=2000)
+    cfg = GatewayConfig(
+        repair_pacing=True,
+        tracing=True,
+        trace_sample=f"head:64,tail:{setup['slo']}",
+        record_requests=False,
+        **setup["gateway_kwargs"],
+    )
+    gw = ObjectGateway(
+        code, ClusterProfile.network_critical(), setup["num_nodes"], cfg
+    )
+    rng = np.random.default_rng(setup["seed"])
+    gw.load_objects(
+        rng.integers(
+            0, 256, (setup["num_objects"], code.k, setup["block_bytes"]),
+            dtype=np.uint8,
+        )
+    )
+    rep = run_scenario(gw, setup["trace"], setup["workload"]).report
+    assert rep.metrics.counter_total("requests") >= 2000
+    assert rep.metrics.counter_total("completed") == rep.metrics.counter_total(
+        "requests"
+    )
+    assert len(rep.records) == 0
+    assert rep.resident_samples() < 50_000
+    assert gw.tracer.resident() <= cfg.trace_capacity
+
+
 # ---------------------------------------------------------------------------
 # chrome-tracing export + validation
 # ---------------------------------------------------------------------------

@@ -1,22 +1,18 @@
 """Ragged decode megakernel (kernels/ragged_decode.py + the coalescer's
-``mode="ragged"`` dataplane): kernel-level correctness against the jnp
-oracles, the byte-identity property against the bucketed baseline over
-randomized mixed-shape windows (H+V, ragged lengths, top-rung-overflow
-batch sizes), the O(1)-per-kind jit-signature bound, and the LaunchUnit
-accounting contract the gateway's engine dispatch relies on."""
+dataplane): kernel-level correctness against the jnp oracles, byte
+identity against the oracle over randomized mixed-shape windows (H+V,
+ragged lengths, windows of hundreds of ops), the O(1)-per-kind
+jit-signature bound, and the LaunchUnit accounting contract the
+gateway's engine dispatch relies on."""
 
+import hashlib
 from collections import defaultdict
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.gateway.coalescer import (
-    PAD_LADDER,
-    BUCKETED,
-    RAGGED,
-    DecodeCoalescer,
-)
+from repro.gateway.coalescer import DecodeCoalescer
 from repro.coding.gf256 import np_matmul
 from repro.gateway.planner import DecodeOp
 from repro.kernels import ops, ref
@@ -108,7 +104,7 @@ def test_chunk_sizes_two_rungs_bound_padding():
 
 
 # ---------------------------------------------------------------------------
-# coalescer property: ragged vs bucketed vs reference, randomized windows
+# coalescer property: ragged vs reference, randomized windows
 # ---------------------------------------------------------------------------
 
 def _random_window(rng, n_ops, lengths=(100, 512, 1000, 4096)):
@@ -145,27 +141,21 @@ def _reference(op, store):
 
 
 @pytest.mark.parametrize("seed", range(5))
-def test_ragged_matches_bucketed_and_reference_on_mixed_windows(seed):
+def test_ragged_matches_reference_on_mixed_windows(seed):
     """The megakernel changes HOW a window decodes, never WHAT: over
     randomized mixed-shape windows the ragged path must be byte-identical
-    to the bucketed baseline and to the jnp oracle, with zero filler
-    stripes (padded_ops) by construction."""
+    to the jnp oracle."""
     rng = np.random.default_rng(seed)
     window, store = _random_window(rng, n_ops=int(rng.integers(1, 16)))
-    fetch = lambda key: store[key]
-    rag = DecodeCoalescer(interpret=True, mode=RAGGED)
-    buck = DecodeCoalescer(interpret=True, mode=BUCKETED)
-    res_r, units_r = rag.execute(window, fetch)
-    res_b, _units_b = buck.execute(window, fetch)
-    assert len(res_r) == len(res_b) == len(window)
-    for op, a, b in zip(window, res_r, res_b):
+    rag = DecodeCoalescer(interpret=True)
+    res_r, units_r = rag.execute(window, lambda key: store[key])
+    assert len(res_r) == len(window)
+    for op, a in zip(window, res_r):
         want = _reference(op, store)
-        assert set(a) == set(b) == set(want)
+        assert set(a) == set(want)
         for col in want:
-            np.testing.assert_array_equal(a[col], b[col])
             np.testing.assert_array_equal(a[col], want[col])
-    assert rag.stats.padded_ops == 0
-    assert rag.stats.decode_ops == buck.stats.decode_ops == len(window)
+    assert rag.stats.decode_ops == len(window)
     # unit fractions of each physical launch sum to 1 (modeled-cost
     # billing depends on it), and every op got at least one unit
     frac = defaultdict(float)
@@ -178,29 +168,24 @@ def test_ragged_matches_bucketed_and_reference_on_mixed_windows(seed):
 
 
 def test_ragged_top_rung_overflow_window():
-    """A window far beyond the bucketed top rung (PAD_LADDER[-1]) — the
-    bucketed path splits into top-rung chunks, the ragged path into
-    big/small tile chunks; bytes must agree either way."""
+    """A window of 266 one-tile ops, far beyond the big chunk rung: the
+    ragged path splits it into big/small tile chunks, and every op's
+    bytes must match the oracle."""
     rng = np.random.default_rng(99)
-    n_ops = PAD_LADDER[-1] + 10
+    n_ops = 266
     ops_, store = [], {}
     for i in range(n_ops):
         sources = tuple((f"g{i}", r, 0) for r in range(3))
         for s in sources:
             store[s] = rng.integers(0, 256, 64, dtype=np.uint8)
         ops_.append(DecodeOp("V", f"g{i}", 3, (0,), sources, None))
-    fetch = lambda key: store[key]
-    rag = DecodeCoalescer(interpret=True, mode=RAGGED)
-    buck = DecodeCoalescer(interpret=True, mode=BUCKETED)
-    res_r, _ = rag.execute(ops_, fetch)
-    res_b, _ = buck.execute(ops_, fetch)
-    for a, b in zip(res_r, res_b):
-        np.testing.assert_array_equal(a[0], b[0])
-    assert buck.stats.decode_calls == 2  # 256 + 10-padded-to-16
-    # ragged: 266 tiles -> 8 big + 3 small chunks, all one signature set
+    rag = DecodeCoalescer(interpret=True)
+    res_r, _ = rag.execute(ops_, lambda key: store[key])
+    for op, a in zip(ops_, res_r):
+        np.testing.assert_array_equal(a[0], _reference(op, store)[0])
+    # 266 tiles -> 8 big + 3 small chunks, all one signature set
     assert rag.stats.decode_calls == len(chunk_sizes(n_ops))
     assert rag.stats.launches_by_kind == {"V": rag.stats.decode_calls}
-    assert buck.stats.launches_by_kind == {"V": 2}
     assert rag.stats.max_batch >= CHUNK_BIG
 
 
@@ -212,7 +197,7 @@ def test_ragged_multi_tile_rows_roundtrip():
     sources = tuple(("g0", r, 0) for r in range(3))
     store = {s: rng.integers(0, 256, length, dtype=np.uint8) for s in sources}
     op = DecodeOp("V", "g0", 3, (0,), sources, None)
-    co = DecodeCoalescer(interpret=True, mode=RAGGED, autotune_kernels=False)
+    co = DecodeCoalescer(interpret=True, autotune_kernels=False)
     res, _units = co.execute([op], lambda k: store[k])
     want = np.bitwise_xor.reduce(np.stack([store[s] for s in sources]), axis=0)
     np.testing.assert_array_equal(res[0][0], want)
@@ -229,7 +214,7 @@ def test_pinned_tile_width_cuts_and_bills_at_that_width():
     store = {s: rng.integers(0, 256, length, dtype=np.uint8) for s in sources}
     op = DecodeOp("V", "g0", 3, (0,), sources, None)
     before = autotune.cache_stats()
-    co = DecodeCoalescer(interpret=True, mode=RAGGED, tile_n=16384)
+    co = DecodeCoalescer(interpret=True, tile_n=16384)
     res, units = co.execute([op], lambda k: store[k])
     want = np.bitwise_xor.reduce(np.stack([store[s] for s in sources]), axis=0)
     np.testing.assert_array_equal(res[0][0], want)
@@ -248,7 +233,7 @@ def test_ragged_jit_entries_bounded_at_two_per_kind():
     traced signatures per kind (the two chunk rungs). This is the
     megakernel's core promise: shape diversity costs zero retraces."""
     rng = np.random.default_rng(3)
-    co = DecodeCoalescer(interpret=True, mode=RAGGED)
+    co = DecodeCoalescer(interpret=True)
     for n_ops in (1, 3, 9, 40, 130):
         window, store = _random_window(
             rng, n_ops, lengths=(512, 1000, 4096)
@@ -262,7 +247,7 @@ def test_ragged_jit_entries_bounded_at_two_per_kind():
 
 
 def test_gateway_ragged_jit_entries_bounded_end_to_end():
-    """Through the full gateway (default coalesce="ragged"): a degraded
+    """Through the full gateway: a degraded
     500-request run with organically varying window sizes stays within
     2 signatures per kind."""
     from repro.core.product_code import CoreCode
@@ -295,6 +280,47 @@ def test_gateway_ragged_jit_entries_bounded_end_to_end():
     assert report.launches_per_window > 0
 
 
+def test_gateway_mixed_shape_windows_hold_signature_and_filler_bounds():
+    """Windows that mix decode shapes — (V,1,t) on single-loss rows,
+    (H,1,k) on broken columns, (H,2,k) on a double-loss row — run in at
+    most 2 traced signatures per kind, and tile tails and null tiles
+    stay under half of the staged bytes."""
+    from repro.core.product_code import CoreCode
+    from repro.gateway import (
+        GatewayConfig,
+        ObjectGateway,
+        WorkloadConfig,
+        generate_requests,
+    )
+    from repro.storage.netmodel import ClusterProfile
+
+    code = CoreCode(9, 6, 3)
+    gw = ObjectGateway(
+        code, ClusterProfile.computation_critical(), 60,
+        GatewayConfig(batch_window=0.008),
+    )
+    rng = np.random.default_rng(31)
+    gw.load_objects(rng.integers(0, 256, (30, code.k, 4096), dtype=np.uint8))
+    for g in ("g0", "g1", "g2"):  # one loss per row, columns intact
+        for r in range(3):
+            gw.store.drop_block((g, r, r))
+    for key in (("g3", 0, 1), ("g3", 2, 1), ("g4", 0, 2), ("g4", 1, 2),
+                ("g5", 1, 3), ("g5", 0, 3), ("g5", 0, 4)):
+        gw.store.drop_block(key)  # broken columns, one double-loss row
+    reqs = generate_requests(
+        WorkloadConfig(num_objects=18, num_requests=120, arrival_rate=2000.0,
+                       seed=31)
+    )
+    report = gw.serve(reqs, [])
+    assert len(report.completed) == 120
+    st = gw.coalescer.stats
+    assert st.decode_shapes >= 3
+    by_kind = gw.coalescer.jit_entries_by_kind()
+    assert set(by_kind) == {"V", "H"}
+    assert all(n <= 2 for n in by_kind.values()), by_kind
+    assert 0.0 <= st.padded_byte_ratio < 0.5
+
+
 # ---------------------------------------------------------------------------
 # stats contract
 # ---------------------------------------------------------------------------
@@ -304,7 +330,7 @@ def test_batch_histogram_is_bounded_and_consistent():
     forever); the histogram keys by batch size, so a long run's memory
     stays O(distinct sizes) while max_batch / coalescing_ratio hold."""
     rng = np.random.default_rng(21)
-    co = DecodeCoalescer(interpret=True, mode=RAGGED)
+    co = DecodeCoalescer(interpret=True)
     for _ in range(6):
         window, store = _random_window(rng, 6, lengths=(256,))
         co.execute(window, lambda key: store[key])
@@ -320,10 +346,10 @@ def test_batch_histogram_is_bounded_and_consistent():
     assert st.launches_per_window == st.decode_calls / 6
 
 
-def test_gateway_ragged_and_bucketed_serve_identical_bytes():
-    """End to end through the gateway: coalesce="ragged" vs "bucketed"
-    changes WHEN decodes are billed, never WHAT is served — per-request
-    payload digests must match on a degraded trace."""
+def test_gateway_ragged_serves_identical_bytes():
+    """End to end through the gateway: the ragged dataplane decides WHEN
+    decodes are billed, never WHAT is served — on a degraded trace every
+    GET's payload digest equals the digest of the object's own bytes."""
     from repro.core.product_code import CoreCode
     from repro.gateway import (
         GatewayConfig,
@@ -335,46 +361,26 @@ def test_gateway_ragged_and_bucketed_serve_identical_bytes():
     from repro.storage.netmodel import ClusterProfile
 
     code = CoreCode(9, 6, 3)
-    reports = {}
-    for coalesce in ("ragged", "bucketed"):
-        gw = ObjectGateway(
-            code, ClusterProfile.network_critical(), 60,
-            GatewayConfig(batch_window=0.01, coalesce=coalesce,
-                          record_payloads=True),
-        )
-        rng = np.random.default_rng(9)
-        gw.load_objects(rng.integers(0, 256, (12, code.k, 2048), dtype=np.uint8))
-        reqs = generate_requests(
-            WorkloadConfig(num_objects=12, num_requests=150,
-                           arrival_rate=3000.0, seed=4)
-        )
-        # fail nodes that provably hold data blocks of live objects
-        # (placement is process-stable, so both runs fail the same nodes)
-        victims = [gw.store.node_of(("g0", 0, 0)), gw.store.node_of(("g1", 0, 2))]
-        failures = [
-            FailureEvent(time=0.005 + 0.01 * i, node=n)
-            for i, n in enumerate(victims)
-        ]
-        reports[coalesce] = gw.serve(reqs, failures)
-    rag, buck = reports["ragged"].records, reports["bucketed"].records
-    assert len(rag) == len(buck) == 150
-    for a, b in zip(rag, buck):
-        assert (a.time, a.object_id, a.kind, a.degraded) == (
-            b.time, b.object_id, b.kind, b.degraded,
-        )
-        assert a.payload_digest == b.payload_digest
-    assert any(r.degraded for r in rag)
-
-
-def test_gateway_rejects_unknown_coalesce_mode():
-    from repro.core.product_code import CoreCode
-    from repro.gateway import GatewayConfig, ObjectGateway
-    from repro.storage.netmodel import ClusterProfile
-
-    with pytest.raises(ValueError):
-        ObjectGateway(
-            CoreCode(9, 6, 3), ClusterProfile.network_critical(), 60,
-            GatewayConfig(coalesce="mega"),
-        )
-    with pytest.raises(ValueError):
-        DecodeCoalescer(mode="mega")
+    gw = ObjectGateway(
+        code, ClusterProfile.network_critical(), 60,
+        GatewayConfig(batch_window=0.01, record_payloads=True),
+    )
+    rng = np.random.default_rng(9)
+    objects = rng.integers(0, 256, (12, code.k, 2048), dtype=np.uint8)
+    gw.load_objects(objects)
+    reqs = generate_requests(
+        WorkloadConfig(num_objects=12, num_requests=150,
+                       arrival_rate=3000.0, seed=4)
+    )
+    # fail nodes that provably hold data blocks of live objects
+    victims = [gw.store.node_of(("g0", 0, 0)), gw.store.node_of(("g1", 0, 2))]
+    failures = [
+        FailureEvent(time=0.005 + 0.01 * i, node=n)
+        for i, n in enumerate(victims)
+    ]
+    records = gw.serve(reqs, failures).records
+    assert len(records) == 150
+    for r in records:
+        want = hashlib.sha256(objects[r.object_id].tobytes()).hexdigest()
+        assert r.payload_digest == want
+    assert any(r.degraded for r in records)

@@ -245,8 +245,6 @@ def test_decode_cost_per_tile_validation():
         build(decode_cost_per_tile=-0.1)
     with pytest.raises(ValueError):
         build(decode_cost=0.01, decode_cost_per_tile=0.01)
-    with pytest.raises(ValueError):
-        build(decode_cost_per_tile=0.01, coalesce="bucketed")
     gw = build(decode_cost_per_tile=0.01)
     assert gw.config.decode_cost_per_tile == 0.01
 

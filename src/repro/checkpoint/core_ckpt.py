@@ -98,6 +98,7 @@ class CoreCheckpointer:
                 data, rep = fixer.degraded_read(gid, r)
                 agg.blocks_fetched += rep.blocks_fetched
                 agg.bytes_fetched += rep.bytes_fetched
+                agg.source_copied_bytes += rep.source_copied_bytes
                 agg.network_time += rep.network_time
                 agg.compute_time += rep.compute_time
                 rows.append(data)
@@ -117,6 +118,7 @@ class CoreCheckpointer:
             rep = fixer.fix_group(gid)
             agg.blocks_fetched += rep.blocks_fetched
             agg.bytes_fetched += rep.bytes_fetched
+            agg.source_copied_bytes += rep.source_copied_bytes
             agg.blocks_repaired += rep.blocks_repaired
             agg.network_time = max(agg.network_time, rep.network_time)
             agg.compute_time += rep.compute_time

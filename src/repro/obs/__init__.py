@@ -118,8 +118,10 @@ gives each name the window time in which it is the innermost span.
   ``stage.scatter``     a ragged launch's scatter into output rows
   ``repair.sweep``      the crc32 check of a group's survivors before
                         its rebuild (children: ``store.crc32``)
-  ``repair.gather``     BlockFixer's stack of a step's sources
-  ``repair.h2d``        BlockFixer's host-to-device copy
+  ``repair.gather``     BlockFixer's collection of a step's sources
+                        from the store, by reference
+  ``repair.h2d``        BlockFixer's host-to-device copy, block by
+                        block, and the stack on the device
   ``repair.d2h``        BlockFixer's device-to-host copy
   ``repair.writeback``  put_block of a rebuilt block (children:
                         ``store.crc32``)

@@ -38,8 +38,8 @@ SPANS: dict[str, str] = {
     "stage.d2h": "device-to-host copy of one ragged launch's result",
     "stage.scatter": "scatter of one ragged launch's result tiles into the output rows",
     "repair.sweep": "crc32 check of a group's surviving blocks before its rebuild",
-    "repair.gather": "stack of one repair step's source blocks",
-    "repair.h2d": "host-to-device copy of one repair step's operands",
+    "repair.gather": "one repair step's source blocks, collected from the store by reference",
+    "repair.h2d": "host-to-device copy of one repair step's sources, block by block, and their stack on the device",
     "repair.d2h": "device-to-host copy of one repair step's rebuilt blocks",
     "repair.writeback": "put_block of one rebuilt block, with its new digest",
 }

@@ -54,16 +54,21 @@ class RepairReport:
     # or "global" (a GF(256) decode): blocks rebuilt, source blocks read
     rebuilt_by_plan: dict[str, int] = field(default_factory=dict)
     read_by_plan: dict[str, int] = field(default_factory=dict)
+    # bytes of source blocks copied on the host to stage a step: only a
+    # block that is not C-contiguous is (the rest go to the device as
+    # the store holds them), so 1 - this / bytes_fetched is the share
+    # staged in place
+    source_copied_bytes: int = 0
 
     @property
     def total_time(self) -> float:
         return self.network_time + self.compute_time
 
-    def record(self, plan: str, blocks: np.ndarray, rebuilt: int) -> None:
-        """Count one repair step: its stacked source ``blocks`` read and
+    def record(self, plan: str, blocks: list[np.ndarray], rebuilt: int) -> None:
+        """Count one repair step: its source ``blocks`` read and
         ``rebuilt`` blocks written back, under ``plan``."""
         self.blocks_fetched += len(blocks)
-        self.bytes_fetched += int(blocks.nbytes)
+        self.bytes_fetched += sum(b.nbytes for b in blocks)
         self.blocks_repaired += rebuilt
         self.rebuilt_by_plan[plan] = self.rebuilt_by_plan.get(plan, 0) + rebuilt
         self.read_by_plan[plan] = self.read_by_plan.get(plan, 0) + len(blocks)
@@ -248,11 +253,30 @@ class BlockFixer:
         return max(0.0, end - start)
 
     # -- timed codec ops ------------------------------------------------------
-    def _measure(self, fn, *args):
-        """``fn(*args)`` on the device: host copies in, the timed launch,
-        the rebuilt blocks copied back."""
+    def _gather(self, keys: list, report: RepairReport) -> list[np.ndarray]:
+        """A step's source blocks: the store's own arrays, by reference.
+        Only a block that is not C-contiguous is copied, counted in
+        ``report.source_copied_bytes``."""
+        blocks = []
+        with span("repair.gather", group=keys[0][0]):
+            for key in keys:
+                blk = self.store.get(key)
+                if not blk.flags.c_contiguous:
+                    blk = np.ascontiguousarray(blk)
+                    report.source_copied_bytes += blk.nbytes
+                blocks.append(blk)
+        return blocks
+
+    def _measure(self, fn, sources: list[np.ndarray], *lead) -> np.ndarray:
+        """``fn(*lead, stacked sources)`` on the device: each source block
+        copied up once as it lies and stacked there, the timed launch, the
+        rebuilt blocks copied back."""
         with span("repair.h2d"):
-            args = jax.block_until_ready([jnp.asarray(a) for a in args])
+            args = [jnp.asarray(a) for a in lead]
+            up = jax.device_put(sources)
+            args.append(jnp.stack(up))
+            del up  # the stacked copy is all the launch needs on the device
+            jax.block_until_ready(args)
         with span("kernel.run"):
             t0 = time.perf_counter()
             out = fn(*args)
@@ -261,16 +285,14 @@ class BlockFixer:
         with span("repair.d2h"):
             return np.asarray(out)
 
-    def _vertical_repair(self, sources: np.ndarray) -> np.ndarray:
+    def _vertical_repair(self, sources: list[np.ndarray]) -> np.ndarray:
         return self._measure(_xor_jit, sources)
 
     def _horizontal_repair(
-        self, avail_cols: np.ndarray, blocks: np.ndarray, missing_cols: np.ndarray
+        self, avail_cols: np.ndarray, blocks: list[np.ndarray], missing_cols: np.ndarray
     ) -> np.ndarray:
         row_ids, coeffs = self.code.horizontal.repair_matrix(avail_cols, missing_cols)
-        pos = {int(a): i for i, a in enumerate(avail_cols)}
-        sel = np.asarray([pos[int(r)] for r in row_ids])
-        return self._measure(_gf_matmul_jit, coeffs, blocks[sel])
+        return self._measure(_gf_matmul_jit, _select(avail_cols, blocks, row_ids), coeffs)
 
     # -- main entry ------------------------------------------------------------
     def fix_group(self, group_id: str, rows: int | None = None) -> RepairReport:
@@ -316,10 +338,7 @@ class BlockFixer:
         # source; its bytes exist only once its own fetches landed
         repaired_ready: dict[int, float] = {}
         for kind, sources, repaired in plan:
-            with span("repair.gather", group=group_id):
-                blocks = np.stack(
-                    [self.store.get((group_id, 0, c)) for c in sources]
-                )
+            blocks = self._gather([(group_id, 0, c) for c in sources], report)
             dst = self._dst_node(group_id, 0, repaired[0])
             ready = 0.0
             for c in sources:
@@ -367,15 +386,13 @@ class BlockFixer:
         return report
 
     def _family_global_repair(
-        self, sources: np.ndarray, blocks: np.ndarray, missing: np.ndarray
+        self, sources: np.ndarray, blocks: list[np.ndarray], missing: np.ndarray
     ) -> np.ndarray:
         """GF(256) repair through the family's own generator (LRC's
         global parities are not the plain RS rows, so this cannot reuse
         ``code.horizontal``)."""
         row_ids, coeffs = self.family.code.repair_matrix(sources, missing)
-        pos = {int(a): i for i, a in enumerate(sources)}
-        sel = np.asarray([pos[int(r)] for r in row_ids])
-        return self._measure(_gf_matmul_jit, coeffs, blocks[sel])
+        return self._measure(_gf_matmul_jit, _select(sources, blocks, row_ids), coeffs)
 
     # -- HDFS-RAID modes --------------------------------------------------------
     def _fix_raid(self, group_id: str, rows: int, cols: int, optimized: bool) -> RepairReport:
@@ -405,10 +422,7 @@ class BlockFixer:
                     fetch_cols = avail[: self.code.k]  # Opt1: exactly k
                 else:
                     fetch_cols = avail  # classic: ALL remaining blocks
-                with span("repair.gather", group=group_id):
-                    blocks = np.stack(
-                        [self._get(group_id, r, c, repaired_cells) for c in fetch_cols]
-                    )
+                blocks = self._gather([(group_id, r, c) for c in fetch_cols], report)
                 dst = self._dst_node(group_id, r, batch[0])
                 ready = 0.0
                 for c in fetch_cols:
@@ -501,8 +515,7 @@ class BlockFixer:
         report: RepairReport,
     ) -> None:
         srcs = [(r, c) for (r, c) in step.sources]
-        with span("repair.gather", group=group_id):
-            blocks = np.stack([self.store.get((group_id, r, c)) for r, c in srcs])
+        blocks = self._gather([(group_id, r, c) for r, c in srcs], report)
         dst_cell = step.repairs[0]
         dst = self._dst_node(group_id, *dst_cell)
         ctx = self._obs_ctx()
@@ -593,13 +606,13 @@ class BlockFixer:
             if len(avail_row) < k:
                 raise UnrecoverableError(f"row {row} of {group_id} lost")
             fetch = avail_row[:k]
-            blocks = np.stack([self.store.get((group_id, row, c)) for c in fetch])
+            blocks = self._gather([(group_id, row, c) for c in fetch], report)
             for c in fetch:
                 sim.transfer(
                     Transfer(self.store.node_of((group_id, row, c)), -1, blocks[0].nbytes)
                 )
             report.blocks_fetched += len(fetch)
-            report.bytes_fetched += int(blocks.nbytes)
+            report.bytes_fetched += sum(b.nbytes for b in blocks)
             data = self._measure(_decode_jit_factory(self.code, tuple(fetch)), blocks)
         else:
             got: dict[int, np.ndarray] = {}
@@ -612,13 +625,13 @@ class BlockFixer:
                     report.bytes_fetched += b.nbytes
             for c in missing:
                 srcs = [r for r in range(self.code.rows) if r != row]
-                blocks = np.stack([self.store.get((group_id, r, c)) for r in srcs])
+                blocks = self._gather([(group_id, r, c) for r in srcs], report)
                 for r in srcs:
                     sim.transfer(
                         Transfer(self.store.node_of((group_id, r, c)), -1, blocks[0].nbytes)
                     )
                 report.blocks_fetched += len(srcs)
-                report.bytes_fetched += int(blocks.nbytes)
+                report.bytes_fetched += sum(b.nbytes for b in blocks)
                 got[c] = self._vertical_repair(blocks)
             data = np.stack([got[c] for c in range(k)])
         report.network_time = sim.makespan
@@ -626,9 +639,6 @@ class BlockFixer:
         return data, report
 
     # -- helpers ----------------------------------------------------------------
-    def _get(self, group_id: str, r: int, c: int, repaired: set[int]) -> np.ndarray:
-        return self.store.get((group_id, r, c))
-
     def _dst_node(self, group_id: str, r: int, c: int) -> int:
         used = {
             self.store.placement[key]
@@ -639,6 +649,13 @@ class BlockFixer:
             if node not in used:
                 return node
         return self.store.alive_nodes()[0]
+
+
+def _select(cols, blocks: list[np.ndarray], row_ids) -> list[np.ndarray]:
+    """The blocks of ``row_ids`` in that order, ``blocks`` being those of
+    ``cols``: references, no bytes copied."""
+    pos = {int(c): i for i, c in enumerate(cols)}
+    return [blocks[pos[int(r)]] for r in row_ids]
 
 
 # -- jitted codec math (shared, cached) ------------------------------------------

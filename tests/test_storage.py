@@ -264,3 +264,139 @@ def test_gateway_wires_rack_aware_placement():
     wl = WorkloadConfig(num_objects=6, num_requests=40, arrival_rate=500.0, seed=9)
     rep = gw.serve(generate_requests(wl), [])
     assert len(rep.completed) == 40
+
+
+# -- repair sources staged by reference ---------------------------------------
+
+SRC_Q = 16384  # 16 KiB blocks
+
+
+def _host_encoded_group(code: CoreCode, family: str, store: BlockStore):
+    """Store one group and return it as the code's own host encode:
+    ``gf256.np_matmul`` by the generator, CORE's XOR row over the t
+    objects. ``family`` "core" is the product code, else the row family
+    of that name; returns the family object (None for CORE) and the
+    (rows, n, q) matrix."""
+    from repro.coding import gf256
+    from repro.gateway.planner import make_family
+
+    rng = np.random.default_rng(11)
+    if family == "core":
+        fam, gen = None, code.horizontal.gen
+        objects = rng.integers(0, 256, size=(code.t, code.k, SRC_Q), dtype=np.uint8)
+    else:
+        fam = make_family(code, family)
+        gen = fam.code.gen
+        objects = rng.integers(0, 256, size=(1, code.k, SRC_Q), dtype=np.uint8)
+    rows = [gf256.np_matmul(gen, obj) for obj in objects]
+    if family == "core":
+        rows.append(np.bitwise_xor.reduce(np.stack(rows), axis=0))
+    matrix = np.stack(rows)
+    store.put_group("g0", matrix)
+    return fam, matrix
+
+
+# (name, (n, k, t), family, mode, scheduler, lost cells,
+#  blocks fetched = bytes fetched in blocks, read_by_plan)
+REPAIR_PATHS = [
+    ("core_v", (9, 6, 3), "core", "core", "rgs", [(1, 3)], 3, {"local": 3}),
+    ("core_h", (9, 6, 3), "core", "core", "row_first", [(1, 3)], 6, {"global": 6}),
+    ("rs_col0", (9, 6, 3), "rs", "core", "rgs", [(0, 0)], 6, {"global": 6}),
+    ("rs_two_in_a_step", (9, 6, 3), "rs", "core", "rgs", [(0, 0), (0, 7)], 6, {"global": 6}),
+    ("xorbas_local", (16, 10, 5), "xorbas", "core", "rgs", [(0, 0)], 5, {"local": 5}),
+    ("xorbas_global", (16, 10, 5), "xorbas", "core", "rgs",
+     [(0, 0), (0, 1), (0, 5), (0, 6)], 10, {"global": 10}),
+    ("hdfs_raid", (9, 6, 3), "core", "hdfs_raid", "rgs", [(1, 3), (1, 5)], 15, {"global": 15}),
+    ("hdfs_raid_opt", (9, 6, 3), "core", "hdfs_raid_opt", "rgs", [(1, 3), (1, 5)], 6,
+     {"global": 6}),
+]
+
+
+@pytest.mark.parametrize(
+    "name,nkt,family,mode,scheduler,lost,fetched,read_by_plan",
+    REPAIR_PATHS,
+    ids=[p[0] for p in REPAIR_PATHS],
+)
+def test_repair_stages_sources_in_place_and_rebuilds_exactly(
+    name, nkt, family, mode, scheduler, lost, fetched, read_by_plan
+):
+    """Every repair path hands the store's own source blocks to the
+    device, copying none on the host, and rebuilds each lost block byte
+    for byte as the host encode has it; the fetch counters read what
+    they read when the sources were stacked on the host."""
+    code = CoreCode(*nkt)
+    store = BlockStore(num_nodes=120)
+    fam, matrix = _host_encoded_group(code, family, store)
+    for r, c in lost:
+        store.drop_block(("g0", r, c))
+    fixer = BlockFixer(
+        store, code, ClusterProfile.network_critical(),
+        mode=mode, scheduler=scheduler, family=fam,
+    )
+    rep = fixer.fix_group("g0")
+    assert rep.recovered and rep.blocks_repaired == len(lost)
+    assert (rep.blocks_fetched, rep.bytes_fetched) == (fetched, fetched * SRC_Q)
+    assert rep.read_by_plan == read_by_plan
+    assert rep.source_copied_bytes == 0
+    for r, c in lost:
+        np.testing.assert_array_equal(store.get(("g0", r, c)), matrix[r, c])
+
+
+def test_non_contiguous_source_is_copied_counted_and_rebuilds_exactly():
+    code = CoreCode(9, 6, 3)
+    store = BlockStore(num_nodes=60)
+    _, matrix = _host_encoded_group(code, "core", store)
+    wide = np.zeros((SRC_Q, 2), dtype=np.uint8)
+    wide[:, 0] = matrix[0, 3]
+    store.blocks[("g0", 0, 3)] = wide[:, 0]  # same bytes, strided
+    assert not store.blocks[("g0", 0, 3)].flags.c_contiguous
+    store.drop_block(("g0", 1, 3))
+    rep = BlockFixer(store, code, ClusterProfile.network_critical()).fix_group("g0")
+    assert rep.recovered and rep.read_by_plan == {"local": 3}
+    assert (rep.bytes_fetched, rep.source_copied_bytes) == (3 * SRC_Q, SRC_Q)
+    np.testing.assert_array_equal(store.get(("g0", 1, 3)), matrix[1, 3])
+
+
+def test_global_repair_takes_a_sparse_selection_by_reference():
+    """Sources given with a dependent one among them (S2, the XOR of
+    data columns 5-9, after those five): the repair matrix skips it, so
+    the selection is not a prefix of the sources, and the rebuilt block
+    is still exact."""
+    code = CoreCode(16, 10, 5)
+    store = BlockStore(num_nodes=120)
+    fam, matrix = _host_encoded_group(code, "xorbas", store)
+    order = np.asarray([5, 6, 7, 8, 9, 15, 1, 2, 3, 4, 14, 10])
+    row_ids, _ = fam.code.repair_matrix(order, np.asarray([0]))
+    assert 15 not in row_ids.tolist() and len(row_ids) == code.k
+    fixer = BlockFixer(store, code, ClusterProfile.network_critical(), family=fam)
+    blocks = [store.get(("g0", 0, int(c))) for c in order]
+    got = fixer._family_global_repair(order, blocks, np.asarray([0]))
+    np.testing.assert_array_equal(got[0], matrix[0, 0])
+
+
+class _NumpyWithoutStack:
+    """numpy, but ``stack`` raises: stands in for the repair module's
+    ``np`` so that a host stack of repair sources fails the test."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def stack(*args, **kwargs):
+        raise AssertionError("repair sources were stacked on the host")
+
+
+@pytest.mark.parametrize("family,lost", [("core", [(1, 3), (2, 3)]), ("rs", [(0, 0), (0, 7)])])
+def test_fix_group_makes_no_host_stack(monkeypatch, family, lost):
+    from repro.storage import repair
+
+    code = CoreCode(9, 6, 3)
+    store = BlockStore(num_nodes=60)
+    fam, matrix = _host_encoded_group(code, family, store)
+    for r, c in lost:
+        store.drop_block(("g0", r, c))
+    monkeypatch.setattr(repair, "np", _NumpyWithoutStack())
+    rep = BlockFixer(store, code, ClusterProfile.network_critical(), family=fam).fix_group("g0")
+    assert rep.recovered and rep.blocks_repaired == len(lost)
+    for r, c in lost:
+        np.testing.assert_array_equal(store.get(("g0", r, c)), matrix[r, c])

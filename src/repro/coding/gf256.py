@@ -111,6 +111,20 @@ def _xtime(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(x >= 0x80, jnp.bitwise_xor(shifted, jnp.uint8(_POLY & 0xFF)), shifted)
 
 
+def _matmul_block_slices(a: jnp.ndarray, xs: list) -> list:
+    """(M, K) x K slices of W bytes -> M slices, by the shift-and-add of
+    ``_matmul_slice`` on each slice as it lies (no (K, W) stack)."""
+    m, kk = a.shape
+    acc = [jnp.zeros_like(xs[0]) for _ in range(m)]
+    for bit in range(8):
+        mask = jnp.uint8(0) - jnp.bitwise_and(jnp.right_shift(a, jnp.uint8(bit)), jnp.uint8(1))
+        for i in range(m):
+            for k in range(kk):
+                acc[i] = jnp.bitwise_xor(acc[i], jnp.bitwise_and(xs[k], mask[i, k]))
+        xs = [_xtime(x) for x in xs]
+    return acc
+
+
 def _matmul_slice(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """(M, K) x (..., K, W) -> (..., M, W) by shift-and-add over the bits
     of ``a``: a*x = XOR over bits i of a of x*2^i."""
@@ -135,7 +149,8 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     a: (M, K) uint8, b: (..., K, N) uint8 -> (..., M, N) uint8 (batched
     over b's leading dims). Pure-jnp implementation (the Pallas kernel in
     repro.kernels is the TPU-optimized version; this is the oracle and
-    the load/verify/repair path). N is walked in MATMUL_SLICE-byte
+    the whole-matrix encode and verify path; the load and repair paths
+    take ``matmul_blocks``). N is walked in MATMUL_SLICE-byte
     slices, so beyond its operands and result it holds
     O(M * K * MATMUL_SLICE) bytes, never an (..., M, K, N) product.
     """
@@ -158,6 +173,38 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
             _matmul_slice(a, b[..., full * slice_n :])
         )
     return out
+
+
+@jax.jit
+def matmul_blocks(a: jnp.ndarray, blocks) -> tuple:
+    """GF(2^8) product of a (M, K) matrix with K blocks: a tuple of M
+    (N,) uint8 blocks, ``out[i] = XOR_k a[i, k] * blocks[k]``.
+
+    ``blocks`` is a sequence of K (N,) uint8 arrays (a (K, N) array is
+    read row by row). They are never stacked: each is read where it
+    lies, one MATMUL_SLICE-byte slice at a time, so the device holds no
+    (K, N) copy and no 2-D tiled layout of the bytes, and beyond its
+    operands and results it holds O((M + K) * MATMUL_SLICE) bytes.
+    """
+    a = a.astype(jnp.uint8)
+    blocks = [jnp.asarray(b, jnp.uint8) for b in blocks]
+    n = blocks[0].shape[0]
+    slice_n = max(1, min(MATMUL_SLICE, n))
+    full = n // slice_n
+
+    def body(i, outs):
+        parts = [jax.lax.dynamic_slice_in_dim(b, i * slice_n, slice_n) for b in blocks]
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(o, r, i * slice_n, axis=0)
+            for o, r in zip(outs, _matmul_block_slices(a, parts))
+        )
+
+    outs = tuple(jnp.zeros(n, jnp.uint8) for _ in range(a.shape[0]))
+    outs = jax.lax.fori_loop(0, full, body, outs)
+    if n > full * slice_n:
+        tail = _matmul_block_slices(a, [b[full * slice_n :] for b in blocks])
+        outs = tuple(o.at[full * slice_n :].set(r) for o, r in zip(outs, tail))
+    return outs
 
 
 def _xor_reduce(x: jnp.ndarray, axis: int) -> jnp.ndarray:

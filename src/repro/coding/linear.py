@@ -66,6 +66,15 @@ class LinearCode:
         gen = jnp.asarray(self.gen)  # (n, k)
         return gf256.matmul(gen, data)  # (..., n, q) via broadcasting
 
+    def encode_parity(self, blocks) -> tuple:
+        """The k data blocks, a sequence of (q,) uint8 arrays, -> the
+        n - k blocks a systematic code computes, on the device (its
+        first k blocks are the data blocks themselves)."""
+        k = self.k
+        if not np.array_equal(self.gen[:k], np.eye(k, dtype=np.uint8)):
+            raise ValueError("encode_parity needs a systematic generator")
+        return gf256.matmul_blocks(self.gen[k:], blocks)
+
     def decodable(self, available: np.ndarray) -> bool:
         """Can the k message blocks be recovered from ``available`` rows?"""
         avail_rows = self.gen[np.asarray(available, dtype=np.int64)]

@@ -13,6 +13,7 @@ two-dimensional.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -68,6 +69,15 @@ def _xor_rows(m: jnp.ndarray) -> jnp.ndarray:
     return gf256.xor_reduce(m, axis=0)
 
 
+@jax.jit
+def _parity_and_xor_row(pgen: jnp.ndarray, objects: list) -> tuple:
+    """t rows of k data blocks -> (t rows of m parity blocks, the XOR
+    row's n blocks), every block a (q,) array."""
+    parity = [gf256.matmul_blocks(pgen, row) for row in objects]
+    rows = [[*d, *p] for d, p in zip(objects, parity)]
+    return parity, [functools.reduce(jnp.bitwise_xor, col) for col in zip(*rows)]
+
+
 @dataclass(frozen=True)
 class CoreCodec:
     """Encode / repair engine for a CORE product code over block arrays."""
@@ -86,6 +96,20 @@ class CoreCodec:
         horiz = self.code.horizontal.encode(objects)  # (t, n, q)
         parity_row = _xor_rows(horiz)  # (n, q)
         return jnp.concatenate([horiz, parity_row[None]], axis=0)
+
+    def encode_computed(self, objects) -> tuple[list, list]:
+        """objects: (at most t, k, q) uint8 -> the blocks the code
+        computes, on the device, each a (q,) array: t rows of m parity
+        blocks and the XOR row's n blocks. Each data block is uploaded
+        as it lies; a missing object is a zero row (a short group's
+        padding)."""
+        c = self.code
+        num, k, q = objects.shape
+        if num > c.t or k != c.k:
+            raise ValueError(f"expected at most {c.t} objects of {c.k} blocks, got {objects.shape}")
+        rows = [list(obj) for obj in objects]
+        rows += [[jnp.zeros(q, jnp.uint8)] * k] * (c.t - num)
+        return _parity_and_xor_row(rs.parity_matrix(c.n, c.k), rows)
 
     def decode_object(self, row_blocks: jnp.ndarray, available: np.ndarray) -> jnp.ndarray:
         """Recover one object's (k, q) data from >=k available blocks of its row."""

@@ -94,6 +94,7 @@ import zlib
 from collections import deque
 from dataclasses import dataclass, field, replace
 
+import jax
 import numpy as np
 
 from repro.coding import rs
@@ -903,6 +904,11 @@ class ObjectGateway:
         # make a block contiguous
         self.handoff_bytes = 0
         self.handoff_copied_bytes = 0
+        # bytes load_objects copied back from the device (the blocks the
+        # code computes) and data bytes it stored as views of the
+        # caller's objects, cumulative
+        self.load_d2h_bytes = 0
+        self.load_aliased_bytes = 0
 
     # -- scale-out plumbing ----------------------------------------------------
     @property
@@ -971,27 +977,44 @@ class ObjectGateway:
     def load_objects(self, objects: np.ndarray) -> None:
         """objects: (num_objects, k, q) uint8. Packs objects_per_group
         objects per group (t for CORE, 1 for the row families, zero-
-        padding the last group) and places all groups."""
+        padding the last group) and places all groups.
+
+        The store keeps each object's data blocks as read-only views of
+        ``objects``, which the caller must leave unchanged. Only the
+        blocks the code computes (parity columns, CORE's XOR row) are
+        encoded on the device and copied back: ``load_d2h_bytes`` counts
+        those, ``load_aliased_bytes`` the data blocks kept in place."""
         num, k, q = objects.shape
         if k != self.code.k:
             raise ValueError(f"objects must have k={self.code.k} blocks")
         self._block_bytes = int(q)
         t = self.family.objects_per_group
-        for g0 in range(0, num, t):
-            chunk = objects[g0 : g0 + t]
-            if chunk.shape[0] < t:
-                pad = np.zeros((t - chunk.shape[0], k, q), dtype=np.uint8)
-                chunk = np.concatenate([chunk, pad], axis=0)
-            gid = f"g{g0 // t}"
-            matrix = np.asarray(self.family.encode_group(chunk))
-            self.store.put_group(gid, matrix)
-            members = []
-            for r in range(min(t, num - g0)):
-                oid = g0 + r
-                self._objects[oid] = (gid, r)
-                self._expected[oid] = np.asarray(objects[oid])
-                members.append(oid)
-            self._groups[gid] = members
+        with span("gw.load", objects=num):
+            for g0 in range(0, num, t):
+                chunk = objects[g0 : g0 + t]
+                gid = f"g{g0 // t}"
+                with span("gw.load.encode", group=gid):
+                    parity, rows = jax.block_until_ready(
+                        self.family.encode_computed(chunk)
+                    )
+                with span("gw.load.d2h", group=gid):
+                    parity = [[np.asarray(b) for b in row] for row in parity]
+                    rows = [[np.asarray(b) for b in row] for row in rows]
+                self.load_d2h_bytes += sum(b.nbytes for row in parity + rows for b in row)
+                self.load_aliased_bytes += chunk.nbytes
+                data = list(chunk)
+                # a short group's padding objects are zero rows
+                data += list(np.zeros((t - len(data), k, q), dtype=np.uint8))
+                self.store.put_group(
+                    gid, [[*d, *p] for d, p in zip(data, parity)] + rows
+                )
+                members = []
+                for r in range(len(chunk)):
+                    oid = g0 + r
+                    self._objects[oid] = (gid, r)
+                    self._expected[oid] = np.asarray(objects[oid])
+                    members.append(oid)
+                self._groups[gid] = members
 
     # -- serving ----------------------------------------------------------------
     def serve(

@@ -142,6 +142,15 @@ class CodeFamily:
         """objects (objects_per_group, k, q) -> group matrix (rows, n, q)."""
         raise NotImplementedError
 
+    def encode_computed(self, objects):
+        """objects (at most objects_per_group, k, q) -> the blocks the
+        code computes, on the device, each a (q,) array: ``(parity,
+        rows)``, the n - k parity blocks of each of the
+        objects_per_group object rows, and the group's wholly computed
+        rows of n blocks. The data blocks are the objects themselves (a
+        short group's missing ones zero)."""
+        raise NotImplementedError
+
     def group_recoverable(self, available) -> bool:
         """Whole-group durability check for the audit plane.
 
@@ -214,6 +223,10 @@ class CoreFamily(CodeFamily):
 
     def encode_group(self, objects):
         return self._codec.encode(objects)
+
+    def encode_computed(self, objects):
+        parity, xor_row = self._codec.encode_computed(objects)
+        return parity, [xor_row]
 
     def group_recoverable(self, available) -> bool:
         # Row-wise horizontal sufficiency matches ``tolerance``: the
@@ -354,6 +367,10 @@ class RowCodeFamily(CodeFamily):
 
     def encode_group(self, objects):
         return self.code.encode(objects)  # (1, k, q) -> (1, n, q)
+
+    def encode_computed(self, objects):
+        (obj,) = objects
+        return [self.code.encode_parity(list(obj))], []
 
     def group_recoverable(self, available) -> bool:
         avail = [c for c in range(self.n) if available((0, c))]

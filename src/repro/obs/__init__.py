@@ -104,6 +104,10 @@ gives each name the window time in which it is the innermost span.
   ``gw.decode``         the window's decode, around coalescer.execute
   ``gw.handoff``        the payload's k blocks, in place, to verify
                         and its sha256
+  ``gw.load``           ObjectGateway.load_objects, the bulk load
+  ``gw.load.encode``    one group's upload and the encode of the blocks
+                        the code computes (parities, CORE's XOR row)
+  ``gw.load.d2h``       those blocks' copy back to the host
   ``fabric.transfer``   NetSimulator.transfer (host cost of the
                         simulated fabric)
   ``store.crc32``       BlockStore.digest, the one crc32 site, with the
@@ -121,7 +125,7 @@ gives each name the window time in which it is the innermost span.
   ``repair.gather``     BlockFixer's collection of a step's sources
                         from the store, by reference
   ``repair.h2d``        BlockFixer's host-to-device copy, block by
-                        block, and the stack on the device
+                        block (never stacked)
   ``repair.d2h``        BlockFixer's device-to-host copy
   ``repair.writeback``  put_block of a rebuilt block (children:
                         ``store.crc32``)

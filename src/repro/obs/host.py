@@ -29,6 +29,9 @@ SPANS: dict[str, str] = {
     "gw.fetch": "a GET window's store reads, replans and hedges",
     "gw.decode": "a GET window's decode through the coalescer, as a whole; attr plan: its ops' plan kinds",
     "gw.handoff": "the payload hand-off: its k blocks, read in place, to verify and its sha256",
+    "gw.load": "ObjectGateway.load_objects, the whole bulk load of the set-up",
+    "gw.load.encode": "one group's upload and its encode of the blocks the code computes, to the end of the device's work",
+    "gw.load.d2h": "device-to-host copy of one group's computed blocks (its data blocks stay the caller's)",
     "fabric.transfer": "one simulated-fabric transfer's bookkeeping (host cost of the simulation)",
     "store.crc32": "one crc32 digest of a block, with its chunks' pool work (the integrity plane)",
     "stage.gather": "zero-fill and gather of one ragged launch's staging buffers",
@@ -39,7 +42,7 @@ SPANS: dict[str, str] = {
     "stage.scatter": "scatter of one ragged launch's result tiles into the output rows",
     "repair.sweep": "crc32 check of a group's surviving blocks before its rebuild",
     "repair.gather": "one repair step's source blocks, collected from the store by reference",
-    "repair.h2d": "host-to-device copy of one repair step's sources, block by block, and their stack on the device",
+    "repair.h2d": "host-to-device copy of one repair step's sources, block by block, never stacked",
     "repair.d2h": "device-to-host copy of one repair step's rebuilt blocks",
     "repair.writeback": "put_block of one rebuilt block, with its new digest",
 }

@@ -256,13 +256,18 @@ class BlockStore:
                 self.placement[(group_id, r, c)] = node
 
     # -- block API ------------------------------------------------------------
-    def put_group(self, group_id: str, matrix: np.ndarray) -> None:
-        """Store a full (rows, cols, q) group."""
-        rows, cols = matrix.shape[:2]
+    def put_group(self, group_id: str, matrix) -> None:
+        """Store a full group: a (rows, cols, q) array, or rows of
+        (q,) blocks. Each block is kept as handed in (a view, not a
+        copy) and marked read-only: the store writes no block in place
+        (corruption and repair store fresh arrays), so a write through
+        the store can never reach the buffer a block views."""
+        rows, cols = len(matrix), len(matrix[0])
         self._place_group(group_id, rows, cols)
         for r in range(rows):
             for c in range(cols):
-                blk = np.asarray(matrix[r, c])
+                blk = np.asarray(matrix[r][c])
+                blk.setflags(write=False)
                 self.blocks[(group_id, r, c)] = blk
                 self.checksums[(group_id, r, c)] = self.digest(blk)
 

@@ -22,6 +22,7 @@ math and scaled by the cluster profile.
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Callable
@@ -267,15 +268,13 @@ class BlockFixer:
                 blocks.append(blk)
         return blocks
 
-    def _measure(self, fn, sources: list[np.ndarray], *lead) -> np.ndarray:
-        """``fn(*lead, stacked sources)`` on the device: each source block
-        copied up once as it lies and stacked there, the timed launch, the
-        rebuilt blocks copied back."""
+    def _measure(self, fn, sources: list[np.ndarray], *lead) -> list[np.ndarray]:
+        """``fn(*lead, sources)`` on the device: each source block copied
+        up once as it lies (never stacked), the timed launch, the rebuilt
+        blocks copied back, one (q,) array each."""
         with span("repair.h2d"):
             args = [jnp.asarray(a) for a in lead]
-            up = jax.device_put(sources)
-            args.append(jnp.stack(up))
-            del up  # the stacked copy is all the launch needs on the device
+            args.append(jax.device_put(sources))
             jax.block_until_ready(args)
         with span("kernel.run"):
             t0 = time.perf_counter()
@@ -283,14 +282,14 @@ class BlockFixer:
             jax.block_until_ready(out)
             self._timed += (time.perf_counter() - t0) * self.profile.compute_scale
         with span("repair.d2h"):
-            return np.asarray(out)
+            return [np.asarray(b) for b in out]
 
-    def _vertical_repair(self, sources: list[np.ndarray]) -> np.ndarray:
+    def _vertical_repair(self, sources: list[np.ndarray]) -> list[np.ndarray]:
         return self._measure(_xor_jit, sources)
 
     def _horizontal_repair(
         self, avail_cols: np.ndarray, blocks: list[np.ndarray], missing_cols: np.ndarray
-    ) -> np.ndarray:
+    ) -> list[np.ndarray]:
         row_ids, coeffs = self.code.horizontal.repair_matrix(avail_cols, missing_cols)
         return self._measure(_gf_matmul_jit, _select(avail_cols, blocks, row_ids), coeffs)
 
@@ -357,7 +356,7 @@ class BlockFixer:
                     ),
                 )
             if kind == "local":
-                rep = self._vertical_repair(blocks)[None]
+                rep = self._vertical_repair(blocks)
             else:
                 rep = self._family_global_repair(
                     np.asarray(sources), blocks, np.asarray(repaired)
@@ -387,7 +386,7 @@ class BlockFixer:
 
     def _family_global_repair(
         self, sources: np.ndarray, blocks: list[np.ndarray], missing: np.ndarray
-    ) -> np.ndarray:
+    ) -> list[np.ndarray]:
         """GF(256) repair through the family's own generator (LRC's
         global parities are not the plain RS rows, so this cannot reuse
         ``code.horizontal``)."""
@@ -547,7 +546,7 @@ class BlockFixer:
                 blocks=len(srcs),
             )
         if step.kind == "V":
-            rep = self._vertical_repair(blocks)[None]
+            rep = self._vertical_repair(blocks)
         else:
             avail_cols = np.asarray([c for (_, c) in srcs])
             missing_cols = np.asarray([c for (_, c) in step.repairs])
@@ -613,7 +612,7 @@ class BlockFixer:
                 )
             report.blocks_fetched += len(fetch)
             report.bytes_fetched += sum(b.nbytes for b in blocks)
-            data = self._measure(_decode_jit_factory(self.code, tuple(fetch)), blocks)
+            data = np.stack(self._measure(_decode_jit_factory(self.code, tuple(fetch)), blocks))
         else:
             got: dict[int, np.ndarray] = {}
             for c in range(k):
@@ -632,7 +631,7 @@ class BlockFixer:
                     )
                 report.blocks_fetched += len(srcs)
                 report.bytes_fetched += sum(b.nbytes for b in blocks)
-                got[c] = self._vertical_repair(blocks)
+                (got[c],) = self._vertical_repair(blocks)
             data = np.stack([got[c] for c in range(k)])
         report.network_time = sim.makespan
         report.compute_time = self._timed
@@ -661,14 +660,20 @@ def _select(cols, blocks: list[np.ndarray], row_ids) -> list[np.ndarray]:
 # -- jitted codec math (shared, cached) ------------------------------------------
 
 
-@jax.jit
-def _xor_jit(blocks: jnp.ndarray) -> jnp.ndarray:
-    return gf256.xor_reduce(blocks, axis=0)
+# Each takes a step's source blocks as a sequence of (q,) arrays (a
+# (K, q) array is read row by row) and returns its rebuilt blocks as a
+# tuple of (q,) arrays: no (K, q) stack and no 2-D tiled layout of the
+# bytes is made on the device, whatever the block size.
 
 
 @jax.jit
-def _gf_matmul_jit(coeffs: jnp.ndarray, blocks: jnp.ndarray) -> jnp.ndarray:
-    return gf256.matmul(coeffs, blocks)
+def _xor_jit(blocks) -> tuple:
+    return (functools.reduce(jnp.bitwise_xor, blocks),)
+
+
+@jax.jit
+def _gf_matmul_jit(coeffs: jnp.ndarray, blocks) -> tuple:
+    return gf256.matmul_blocks(coeffs, blocks)
 
 
 _DECODE_CACHE: dict = {}
@@ -679,13 +684,12 @@ def _decode_jit_factory(code: CoreCode, fetch_cols: tuple[int, ...]):
     if key not in _DECODE_CACHE:
         row_ids, inverse = code.horizontal.decode_matrix(np.asarray(fetch_cols))
         pos = {int(a): i for i, a in enumerate(fetch_cols)}
-        sel = np.asarray([pos[int(r)] for r in row_ids])
+        sel = [pos[int(r)] for r in row_ids]
         inv = jnp.asarray(inverse)
-        sel_j = jnp.asarray(sel)
 
         @jax.jit
         def _decode(blocks):
-            return gf256.matmul(inv, blocks[sel_j])
+            return gf256.matmul_blocks(inv, [blocks[i] for i in sel])
 
         _DECODE_CACHE[key] = _decode
     return _DECODE_CACHE[key]

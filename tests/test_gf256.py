@@ -97,6 +97,25 @@ def test_sliced_matmul_matches_full_product_formula(q):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
 
 
+@pytest.mark.parametrize(
+    "q", [gf256.MATMUL_SLICE - 100, 2 * gf256.MATMUL_SLICE + 100]
+)
+def test_block_matmul_matches_the_stacked_matmul(q):
+    """``matmul_blocks`` over K separate (q,) blocks gives, block for
+    block, the rows of ``matmul`` over their (K, q) stack, inside one
+    slice and past two whole slices with a ragged tail; a (K, q) array
+    is read row by row."""
+    rng = np.random.default_rng(q + 1)
+    a = rng.integers(0, 256, size=(2, 10), dtype=np.uint8)
+    a[0, :3] = (0, 1, 255)
+    blocks = [rng.integers(0, 256, size=q, dtype=np.uint8) for _ in range(10)]
+    want = np.asarray(gf256.matmul(jnp.asarray(a), jnp.asarray(np.stack(blocks))))
+    for arg in (blocks, np.stack(blocks)):
+        got = gf256.matmul_blocks(a, arg)
+        assert len(got) == 2 and all(b.shape == (q,) for b in got)
+        np.testing.assert_array_equal(np.stack([np.asarray(b) for b in got]), want)
+
+
 def test_np_inv_matrix_roundtrip():
     rng = np.random.default_rng(3)
     for n in (1, 2, 5, 12):

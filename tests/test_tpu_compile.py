@@ -20,13 +20,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.coding import gf256
-from repro.core.product_code import CoreCode, CoreCodec
+from repro.coding import gf256, rs
+from repro.core import product_code
+from repro.core.product_code import CoreCode
 from repro.kernels import ragged_decode, ragged_encode
 from repro.kernels.ragged_decode import CHUNK_BIG, CHUNK_SMALL, tile_block_for
 
 V5E_HBM_BYTES = 16 * 2**30
 BLOCK_BYTES = 64 * 2**20  # the paper's HDFS block
+WAREHOUSE_BLOCK_BYTES = 256 * 2**20  # Facebook's warehouse HDFS-RAID block
 
 
 @pytest.fixture(scope="module")
@@ -110,21 +112,61 @@ def test_split_tile_block_compiles_for_v5e(one_chip, entry):
     assert tb < CHUNK_BIG, tb
 
 
-def test_load_encode_of_one_core_group_fits_v5e_hbm(one_chip):
-    """``load_objects`` encodes one CoreCode(9,6,3) group at a time: at
-    64 MiB blocks that program must compile and fit in v5e's HBM."""
-    code = CoreCode(9, 6, 3)
-    objects = _spec((code.t, code.k, BLOCK_BYTES), one_chip)
-    compiled = jax.jit(CoreCodec(code).encode).lower(objects).compile()
+def _hbm_bytes(compiled) -> int:
     mem = compiled.memory_analysis()
-    total = (
+    return (
         mem.argument_size_in_bytes
         + mem.output_size_in_bytes
         + mem.temp_size_in_bytes
         - mem.alias_size_in_bytes
     )
-    assert mem.output_size_in_bytes == code.rows * code.n * BLOCK_BYTES
-    assert total < V5E_HBM_BYTES, mem
+
+
+def _assert_blocks_out(compiled, nbytes: int) -> None:
+    """The results are ``nbytes`` of (q,) blocks: no tiled padding, only
+    the tuple's small table of pointers beside them."""
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert nbytes <= out <= nbytes + 4096, (out, nbytes)
+
+
+def test_load_encode_of_one_core_group_fits_v5e_hbm(one_chip):
+    """``load_objects`` encodes one CoreCode(9,6,3) group at a time, the
+    blocks the code computes only: at 64 MiB blocks that program must
+    compile and fit in v5e's HBM."""
+    code = CoreCode(9, 6, 3)
+    pgen = _spec((code.m, code.k), one_chip)
+    rows = [[_spec((BLOCK_BYTES,), one_chip)] * code.k] * code.t  # each block as it lies
+    compiled = product_code._parity_and_xor_row.lower(pgen, rows).compile()
+    computed = code.t * code.m + code.n  # parity columns, the XOR row
+    _assert_blocks_out(compiled, computed * BLOCK_BYTES)
+    assert _hbm_bytes(compiled) < V5E_HBM_BYTES, compiled.memory_analysis()
+
+
+def test_load_encode_of_one_warehouse_stripe_fits_v5e_hbm(one_chip):
+    """The RS(14,10) stripe of the warehouse configuration at 256 MiB
+    blocks: its 4 parities from 10 data blocks, in v5e's HBM."""
+    blocks = [_spec((WAREHOUSE_BLOCK_BYTES,), one_chip)] * 10
+    compiled = jax.jit(rs.make_rs(14, 10).encode_parity).lower(blocks).compile()
+    _assert_blocks_out(compiled, 4 * WAREHOUSE_BLOCK_BYTES)
+    assert _hbm_bytes(compiled) < V5E_HBM_BYTES / 2, compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("kk, q, rebuilt", [(6, BLOCK_BYTES, 1), (10, WAREHOUSE_BLOCK_BYTES, 2)])
+def test_repair_step_holds_its_sources_and_results_only(one_chip, kk, q, rebuilt):
+    """BlockFixer's GF(256) step at RS(9,6)'s 64 MiB and the warehouse
+    RS(14,10)'s 256 MiB (two blocks rebuilt at once, as after a
+    corruption): the sources go in as blocks, never stacked, and the
+    program holds little beyond them and its results."""
+    from repro.storage import repair
+
+    coeffs = _spec((rebuilt, kk), one_chip)
+    blocks = [_spec((q,), one_chip)] * kk
+    compiled = repair._gf_matmul_jit.lower(coeffs, blocks).compile()
+    _assert_blocks_out(compiled, rebuilt * q)
+    mem = compiled.memory_analysis()
+    # a few slices per block, whatever the block size
+    assert mem.temp_size_in_bytes <= 8 * (kk + rebuilt) * gf256.MATMUL_SLICE, mem
+    assert _hbm_bytes(compiled) < V5E_HBM_BYTES / 2, mem
 
 
 def test_reference_matmul_temporaries_do_not_grow_with_block(one_chip):
